@@ -1,11 +1,11 @@
 """Shared benchmark machinery: workload generators (YCSB-style), store
 builders, timing, and the byte-cost model.
 
-Scale note: this container executes the accelerator path on XLA:CPU, so
-absolute ops/s are NOT the paper's Mops/s — what the benchmarks reproduce
-is the paper's *relative* structure (read-heavy gains, write-heavy
-penalty, every ablation trend) plus the analytic bytes-per-operation model
-(which IS hardware-independent and reproduces the 5x bytes claim).
+Not a device measurement: these sections time whatever backend JAX runs
+on, and off a TPU that is XLA:CPU — a number taken there says how fast
+the CPU path is, not the chip.  What they reproduce is the analytic
+bytes-per-operation model (hardware-independent; it reproduces the 5x
+bytes claim) and the meters (sync bytes, DMA counts, cache hits).
 TDP constants for cost-performance come from the paper (Section 6.3):
 127 W CPU-only server, +40 W FPGA board -> 157.9 W for Honeycomb.
 """

@@ -45,6 +45,8 @@ import json
 import time
 from pathlib import Path
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bytes_model, cache_lb, cloud_storage, common, key_size,
                latency, log_block, mvcc_cost, roofline, scan_size,
                service_smoke, ycsb)
@@ -204,6 +206,7 @@ def main() -> None:
                     help="exit non-zero if any requested section errored "
                          "(CI gates on this; the default keeps sweeping)")
     args = ap.parse_args()
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     shards = tuple(int(s) for s in args.shards.split(","))
     pipeline = tuple(m for m in args.pipeline.split(",") if m)
     replicas = tuple(int(r) for r in args.replicas.split(",") if r)

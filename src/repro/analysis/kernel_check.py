@@ -18,11 +18,12 @@ execution, no compilation) and audits the jaxpr:
     EXACTLY one ``pallas_call``: the whole point of PR 8's fusion is one
     launch per batch, and a refactor that splits the traversal back into
     per-level calls must fail loudly.
-  * ``kernel-vmem-budget`` — per-kernel VMEM block footprint (the sum of
-    every non-ANY BlockSpec block, which Pallas materializes in VMEM)
-    stays under a configurable budget (default 4 MiB, override with
-    ``HONEYCOMB_VMEM_BUDGET_BYTES``): ~16 MB is the whole core's VMEM
-    and the cache tier must leave room for double buffering.
+  * ``kernel-vmem-budget`` — per-kernel VMEM footprint (every VMEM
+    BlockSpec window, counted twice because the pipeline double-buffers
+    it, plus every VMEM scratch buffer) stays under a configurable budget
+    (default 4 MiB, override with ``HONEYCOMB_VMEM_BUDGET_BYTES``): ~16 MB
+    is the whole core's VMEM.  ANY (HBM) operands, SMEM blocks and
+    scratch, and semaphores take no VMEM.
 
 CLI::
 
@@ -69,19 +70,34 @@ def pallas_eqns(jaxpr):
     return [e for e in iter_eqns(jaxpr) if e.primitive.name == "pallas_call"]
 
 
+def _in_vmem(aval) -> bool:
+    """Block windows default to VMEM; ANY, SMEM and semaphore memory are
+    named explicitly."""
+    ms = getattr(aval, "memory_space", None)
+    return ms is None or "vmem" in str(ms).lower()
+
+
+def _block_elems(block_shape) -> int:
+    """Elements of one block window (squeezed dims count 1)."""
+    n = 1
+    for d in block_shape:
+        size = getattr(d, "block_size", d)
+        n *= size if isinstance(size, int) else 1
+    return n
+
+
 def vmem_block_bytes(eqn) -> int:
-    """VMEM bytes the pallas_call's block windows occupy: every block
-    mapping whose memory space is not ANY gets a VMEM-resident window of
-    ``prod(block_shape)`` elements (None entries are squeezed dims)."""
+    """VMEM bytes the pallas_call occupies: two buffers per VMEM block
+    window (the pipeline double-buffers them) plus its VMEM scratch."""
     gm = eqn.params["grid_mapping"]
     total = 0
     for bm in gm.block_mappings:
-        ms = str(getattr(bm.block_aval, "memory_space", None)).lower()
-        if "any" in ms:
-            continue
-        shape = [d for d in bm.block_shape if isinstance(d, int)]
-        dtype = bm.array_shape_dtype.dtype
-        total += math.prod(shape) * dtype.itemsize
+        if _in_vmem(bm.block_aval):
+            total += (2 * _block_elems(bm.block_shape)
+                      * bm.array_aval.dtype.itemsize)
+    for aval in gm.scratch_avals:
+        if _in_vmem(aval):
+            total += math.prod(aval.shape) * aval.dtype.itemsize
     return total
 
 

@@ -53,23 +53,25 @@ class CpuOrderedStore:
         self.levels: list[list[bytes]] = []   # separators per interior level
         self.children: list[list] = []        # children per interior level
         self.leaves: list[_Leaf] = [root]
+        # each leaf's minimum key (b"" for an empty leaf), kept in step
+        # with ``leaves`` by every write
+        self._mins: list[bytes] = [b""]
         self.stats = CpuStoreStats()
 
     # simple two-level structure: a sorted list of leaf minimums
     # (fanout-free "interior"), which is what Masstree's upper trie
     # amortizes to for random keys; adequate as a throughput baseline.
-    def _find_leaf(self, key: bytes) -> _Leaf:
+    def _locate(self, key: bytes) -> int:
         self.stats.node_visits += 1
-        idx = bisect.bisect_right(self._mins, key) - 1
-        return self.leaves[max(idx, 0)]
+        return max(bisect.bisect_right(self._mins, key) - 1, 0)
 
-    @property
-    def _mins(self) -> list[bytes]:
-        return [lf.keys[0] if lf.keys else b"" for lf in self.leaves]
+    def _find_leaf(self, key: bytes) -> _Leaf:
+        return self.leaves[self._locate(key)]
 
     def put(self, key: bytes, val: bytes):
         self.stats.puts += 1
-        lf = self._find_leaf(key)
+        pos = self._locate(key)
+        lf = self.leaves[pos]
         i = bisect.bisect_left(lf.keys, key)
         self.stats.bytes_touched += sum(map(len, lf.keys)) \
             + sum(map(len, lf.vals))
@@ -78,32 +80,35 @@ class CpuOrderedStore:
         else:
             lf.keys.insert(i, key)
             lf.vals.insert(i, val)
+            self._mins[pos] = lf.keys[0]
             if len(lf.keys) > self.node_cap:
-                self._split(lf)
+                self._split(pos)
 
     update = put
 
-    def _split(self, lf: _Leaf):
+    def _split(self, pos: int):
+        lf = self.leaves[pos]
         mid = len(lf.keys) // 2
         right = _Leaf()
         right.keys, right.vals = lf.keys[mid:], lf.vals[mid:]
         lf.keys, lf.vals = lf.keys[:mid], lf.vals[:mid]
         right.next, lf.next = lf.next, right
-        pos = self.leaves.index(lf)
         self.leaves.insert(pos + 1, right)
+        self._mins.insert(pos + 1, right.keys[0])
 
     def delete(self, key: bytes):
         self.stats.deletes += 1
-        lf = self._find_leaf(key)
+        pos = self._locate(key)
+        lf = self.leaves[pos]
         i = bisect.bisect_left(lf.keys, key)
         self.stats.bytes_touched += sum(map(len, lf.keys))
         if i < len(lf.keys) and lf.keys[i] == key:
             del lf.keys[i], lf.vals[i]
+            self._mins[pos] = lf.keys[0] if lf.keys else b""
             if not lf.keys and len(self.leaves) > 1:
-                pos = self.leaves.index(lf)
                 if pos > 0:
                     self.leaves[pos - 1].next = lf.next
-                del self.leaves[pos]
+                del self.leaves[pos], self._mins[pos]
 
     def get(self, key: bytes) -> bytes | None:
         self.stats.gets += 1
@@ -120,10 +125,10 @@ class CpuOrderedStore:
         """Floor-start scan with Honeycomb-compatible semantics."""
         self.stats.scans += 1
         out: list[tuple[bytes, bytes]] = []
-        lf = self._find_leaf(lo)
+        pos = self._locate(lo)
+        lf = self.leaves[pos]
         # floor: the largest key <= lo (may sit in an earlier leaf)
         floor = None
-        pos = self.leaves.index(lf)
         for j in range(pos, -1, -1):
             cand = [k for k in self.leaves[j].keys if k <= lo]
             self.stats.bytes_touched += sum(

@@ -104,6 +104,8 @@ class PipelineStats:
     flips: int = 0              # epoch publishes
     dispatched_lanes: int = 0   # real requests inside device batches
     padded_lanes: int = 0       # bucket_pow2 device lanes those occupied
+    host_scans: int = 0         # device SCAN requests the host tree had to
+    #   answer (leaf/item budget exhausted — paper Section 6.3)
 
     def merge(self, other: "PipelineStats"):
         """Accumulate another meter (router aggregation over shards)."""

@@ -103,15 +103,13 @@ from .heap import LOG_DELETE, LOG_INSERT, LOG_UPDATE
 from .read_path import NODE_FIELDS, TreeSnapshot, attach_cache_image
 from .schema import NodeImageLayout
 from .shard import (LogPayload, StagedSync, StoreShard, SyncStats,
-                    _DELTA_BACKEND, _jit_apply_delta)
+                    _jit_apply_delta, sync_backend)
 from .telemetry import CLOCK, merge_stats, samples_from
 
 _now = CLOCK            # THE injectable monotonic clock (core/telemetry.py)
 
 # wire op kind -> heap log op code (the decode half of the feed)
 _LOG_CODES = {"put": LOG_INSERT, "update": LOG_UPDATE, "delete": LOG_DELETE}
-
-_LOG_BACKEND = _DELTA_BACKEND     # TPU -> compiled Pallas, else jnp oracle
 
 
 @functools.partial(jax.jit, static_argnames=("offs", "backend"))
@@ -161,12 +159,14 @@ def _snapshot_nbytes(snap) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(snap))
 
 
-def _image_feed_cost(snap) -> tuple[int, int]:
-    """(DMA invocations, node-image bytes) of device-copying a whole
-    snapshot into a follower: the packed layout moves ONE contiguous image
-    (core/schema.py); legacy moves one array per field — same bytes."""
+def _image_feed_cost(snap, cfg) -> tuple[int, int]:
+    """(DMA invocations, node-image payload bytes) of device-copying a
+    whole snapshot into a follower: the packed layout moves ONE contiguous
+    image (core/schema.py); legacy moves one array per field — same
+    bytes."""
     if isinstance(snap, TreeSnapshot):
-        return 1, snap.image.nbytes
+        layout = NodeImageLayout.for_config(cfg)
+        return 1, snap.image.shape[0] * layout.node_image_bytes
     return len(NODE_FIELDS), sum(getattr(snap, f).nbytes
                                  for f in NODE_FIELDS)
 
@@ -205,7 +205,7 @@ class FollowerReplica:
             # (one image-row DMA per dirty node on the packed layout — the
             # delta type carries the layout, so the replay is layout-free)
             self._standby = _jit_apply_delta(base, payload.delta,
-                                             backend=_DELTA_BACKEND,
+                                             backend=sync_backend(),
                                              cfg=self.cfg)
             stats.delta_syncs += 1
             stats.delta_rows += payload.delta_rows
@@ -221,7 +221,7 @@ class FollowerReplica:
             nbytes = (payload.nbytes if payload.kind == "full"
                       else _snapshot_nbytes(payload.snapshot))
             stats.bytes_synced += nbytes
-            dmas, ibytes = _image_feed_cost(payload.snapshot)
+            dmas, ibytes = _image_feed_cost(payload.snapshot, self.cfg)
             stats.image_dma_count += dmas
             stats.image_bytes += ibytes
             self.in_sync = True
@@ -250,7 +250,7 @@ class FollowerReplica:
         else:
             rows, slots, entries, offs = marshalled
             image = _jit_log_replay(base.image, rows, slots, entries, offs,
-                                    _LOG_BACKEND)
+                                    sync_backend())
         snap = base._replace(
             image=image, read_version=jnp.int32(lp.read_version))
         if self.cfg is not None:
@@ -450,7 +450,7 @@ class ReplicaGroup:
         f.sync_stats.full_syncs += 1
         nbytes = _snapshot_nbytes(snap)
         f.sync_stats.bytes_synced += nbytes
-        dmas, ibytes = _image_feed_cost(snap)
+        dmas, ibytes = _image_feed_cost(snap, self.primary.cfg)
         f.sync_stats.image_dma_count += dmas
         f.sync_stats.image_bytes += ibytes
         # an admin resync is a primary-direct full catch-up on the feed

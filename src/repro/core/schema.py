@@ -22,8 +22,12 @@ Layout contract (pinned by tests/test_layout.py golden offsets):
     bitcast (NULL = -1 survives), unsigned key/value lanes pass through.
 
 With the paper's geometry (64-cap nodes, 16 log entries, 8 shortcuts,
-32 B keys / 16 B inline values) the image row is 1273 words = 5092 B —
-the reproduction's analogue of the paper's 8 KB node buffer.
+32 B keys / 16 B inline values) the fields fill 1273 words = 5092 B — the
+reproduction's analogue of the paper's 8 KB node buffer.  The device row
+is padded with zero words to ``image_words`` = 1280, a multiple of the
+TPU's 128-lane tile: Mosaic only DMAs HBM windows whose width is
+tile-aligned, and the node image is what the kernels DMA.  The pad words
+carry nothing, so the sync byte meters count the 1273 field words.
 """
 from __future__ import annotations
 
@@ -37,6 +41,7 @@ from .config import HoneycombConfig
 from .keys import pack_key
 
 _NULL = -1   # matches heap.NULL: "no slot / no sibling / no old version"
+ROW_ALIGN = 128   # image rows pad to the TPU lane tile (see module docstring)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,7 +140,10 @@ class NodeImageLayout:
             slots[spec.name] = FieldSlot(spec, off, words, shape)
             off += words
         self.slots = slots
-        self.image_words = off          # u32 words per node image row
+        self.field_words = off          # u32 words the fields occupy
+        # u32 words per device image row: the fields plus zero padding up
+        # to the lane tile
+        self.image_words = -(-off // ROW_ALIGN) * ROW_ALIGN
 
     @staticmethod
     @functools.lru_cache(maxsize=None)
@@ -144,9 +152,10 @@ class NodeImageLayout:
 
     @property
     def node_image_bytes(self) -> int:
-        """Bytes of one node's contiguous image row (the DMA unit — the
-        reproduction's analogue of the paper's 8 KB node buffer)."""
-        return self.image_words * 4
+        """Payload bytes of one node's image row (the DMA unit — the
+        reproduction's analogue of the paper's 8 KB node buffer); the
+        row's tile padding carries nothing and is not metered."""
+        return self.field_words * 4
 
     def offsets(self) -> dict[str, tuple[int, int]]:
         """{field: (word_offset, words)} — what the golden test pins."""
@@ -158,9 +167,9 @@ class NodeImageLayout:
         u32 (D = all rows when ``rows`` is None).  Narrows wide host dtypes
         to int32 and bit-preserves signedness, exactly like the per-field
         legacy publish; the result is a fresh buffer, so later host
-        mutations can never reach a staged snapshot."""
+        mutations can never reach a staged snapshot.  Pad words are 0."""
         n = heap.capacity if rows is None else len(rows)
-        img = np.empty((n, self.image_words), np.uint32)
+        img = np.zeros((n, self.image_words), np.uint32)
         for name, slot in self.slots.items():
             arr = getattr(heap, name)
             arr = arr if rows is None else arr[rows]

@@ -73,19 +73,24 @@ _jit_get = jax.jit(batched_get, static_argnames="cfg")
 _jit_scan = jax.jit(batched_scan, static_argnames="cfg")
 # the fused read path (ONE traversal dispatch per batch, cache tier pinned
 # in VMEM — kernels/fused_read.py): compiled Pallas on TPU, the jnp oracle
-# everywhere else (XLA:CPU lowers it; interpret-mode parity is tested)
-_READ_KERNEL_BACKEND = "pallas" if jax.default_backend() == "tpu" else "ref"
+# everywhere else (XLA:CPU lowers it; interpret-mode parity is tested).
+# The kernel backend is resolved when the first batch traces
+# (kernels/ops.py), never at import.
 _jit_get_fused = jax.jit(kernel_ops.batched_get_fused,
                          static_argnames=("cfg", "lb_fraction", "backend"))
 _jit_scan_fused = jax.jit(kernel_ops.batched_scan_fused,
                           static_argnames=("cfg", "lb_fraction", "backend"))
 # the delta-sync scatter; NOT donated — old snapshots held by in-flight
-# batches must keep answering at their read version.  On TPU the node-field
-# scatters fuse into ONE Pallas multi-field kernel call; elsewhere the jnp
-# oracle path lowers through XLA (kernels/ops.py dispatch).
-_DELTA_BACKEND = "pallas" if jax.default_backend() == "tpu" else None
+# batches must keep answering at their read version.
 _jit_apply_delta = jax.jit(apply_snapshot_delta,
                            static_argnames=("backend", "cfg"))
+
+
+def sync_backend() -> str | None:
+    """Backend of the sync scatters, decided at dispatch: the compiled
+    Pallas kernels on a TPU (kernels/delta_scatter.py), elsewhere None —
+    the jnp scatter XLA lowers (``apply_snapshot_delta``)."""
+    return "pallas" if kernel_ops.default_backend() == "pallas" else None
 
 # snapshot fields narrowed to int32 on device (host keeps 64-bit authority)
 # — derived from the one layout schema, not hand-kept
@@ -515,7 +520,7 @@ class StoreShard:
             # pack() marshals every field into contiguous node images — the
             # whole publish is one image transfer (plus the page table)
             img = layout.pack(h)
-            stats.bytes_synced += img.nbytes
+            stats.bytes_synced += h.capacity * layout.node_image_bytes
             stats.image_dma_count += 1
             snap = TreeSnapshot(
                 image=jnp.asarray(img),
@@ -593,7 +598,7 @@ class StoreShard:
                 **fields)
         stats.bytes_synced += nbytes
         self._staged_delta = delta   # replayable by follower replicas
-        return _jit_apply_delta(base, delta, backend=_DELTA_BACKEND,
+        return _jit_apply_delta(base, delta, backend=sync_backend(),
                                 cfg=self.cfg)
 
     @staticmethod
@@ -673,8 +678,7 @@ class StoreShard:
             if rb == "fused":
                 res, meters = _jit_get_fused(
                     snap, jnp.asarray(lanes), jnp.asarray(lens),
-                    cfg=self.cfg, lb_fraction=self.cfg.lb_fraction,
-                    backend=_READ_KERNEL_BACKEND)
+                    cfg=self.cfg, lb_fraction=self.cfg.lb_fraction)
                 self._note_read_meters(meters)
             else:
                 res = _jit_get(
@@ -730,8 +734,7 @@ class StoreShard:
                 res, meters = _jit_scan_fused(
                     snap, jnp.asarray(lo_l), jnp.asarray(lo_n),
                     jnp.asarray(hi_l), jnp.asarray(hi_n), cfg=self.cfg,
-                    lb_fraction=self.cfg.lb_fraction,
-                    backend=_READ_KERNEL_BACKEND)
+                    lb_fraction=self.cfg.lb_fraction)
                 self._note_read_meters(meters)
             else:
                 res = _jit_scan(
@@ -748,6 +751,7 @@ class StoreShard:
         out = []
         for b, (lo, hi) in enumerate(ranges):
             if trunc[b]:
+                self.pipeline_stats.host_scans += 1
                 out.append(self.tree.scan(lo, hi, read_version=fallback_rv))
                 continue
             items = []

@@ -3,33 +3,45 @@
 One ``pallas_call`` executes the WHOLE per-request traversal — multi-level
 descend over the packed node image, leaf resolve, order-hint log merge,
 MVCC version resolution — where the reference path (core/read_path.py)
-issues one gather storm per level.  The grid iterates over the request
-batch (one program per request, ``PrefetchScalarGridSpec`` carrying the
-root LID + read version as scalars), so a read batch costs ONE device
-dispatch regardless of tree height or scan budget.
+issues one gather storm per level.  A read batch costs ONE device dispatch
+regardless of tree height or scan budget.
 
-The paper's cache tiers run for real here: the snapshot's contiguous
-``[cache_slots, image_words]`` cache array (root + top interior levels,
-packed at export — core/cache.py / ``attach_cache_image``) arrives through
-a plain VMEM BlockSpec, pinning it on-core for every program; a descend
-level whose LID is in the cache resolves from that block under a
-``lax.cond`` — the heap-image load, pagetable lookup and MVCC walk are
-genuinely not executed — while levels below the cached frontier fall
-through to dynamic row loads against the heap image (``pltpu.ANY`` +
-``pl.ds``, the ``log_replay_scatter`` addressing idiom).  The compile-time
-``lb_fraction`` knob deterministically routes a slice of cache-HIT
-programs down the heap pipe anyway (Section 5's dual-pipe load balancer);
-per-program ``[vmem_hits, heap_gathers, lb_routed]`` meters come back as
-an output block.
+Structure.  The grid runs over blocks of ``GROUP`` = 8 requests (the TPU
+sublane tile: the per-request key and length blocks are [8, key_words] and
+[8, 1]); each program walks its eight requests one after another.  A
+traversal is pointer chasing — page-table entry, node row, old-version
+pointer, child LID, sibling LID — so it runs on the TPU's scalar unit out
+of SMEM, where any word of a node is addressable:
 
-Field decoding inside the body reuses ``NodeImageLayout.field_views`` on
-single ``[1, image_words]`` rows and the search/merge helpers from
-core/read_path.py (``_shortcut_floor``/``_segment_floor``/
-``_resolve_leaf``) on the resulting one-row views — the kernel and the
-jnp oracle (``kernels/ref.py`` ``batched_*_fused_ref``) share the actual
-search arithmetic, so interpret-mode parity pins only the traversal
-plumbing.  The oracle is what XLA:CPU lowers; ``interpret=True`` is the
-CPU-testable kernel path, compiled Mosaic the TPU one.
+  * node fetch: the heap image and the page table stay in HBM (``pl.ANY``)
+    and are read by explicit DMA.  HBM is tiled 8 rows x 128 lanes and a
+    DMA window must be tile-aligned, so a fetch copies the 8-row window
+    holding the node (``image_words`` is padded to a multiple of 128 lanes,
+    core/schema.py) into an SMEM scratch window and addresses the row
+    inside it;
+  * cache tier: the snapshot's ``[cache_slots, image_words]`` cache array
+    (root + top interior levels, packed at export — core/cache.py /
+    ``attach_cache_image``) arrives through a plain VMEM BlockSpec, pinned
+    on-core for every program.  The cached-LID probe is one vector compare
+    against the VMEM-resident LID vector; a hit copies its window VMEM ->
+    SMEM, and the heap DMA, page-table lookup and MVCC walk are genuinely
+    not executed.  The compile-time ``lb_fraction`` knob deterministically
+    routes a slice of cache-HIT requests down the heap pipe anyway
+    (Section 5's dual-pipe load balancer); per-request
+    ``[vmem_hits, heap_gathers, lb_routed]`` meters come back as an output
+    block;
+  * leaf resolve: the RSU order-hint log sort (one shift-register step per
+    log entry), the merge of log entries into the sorted block by back
+    pointer, and the newest-visible-version shadow resolution walk the
+    merged leaf once in key order; only the used slots are visited.
+
+The body re-implements the search arithmetic of core/read_path.py
+(``_shortcut_floor``/``_segment_floor``/``_resolve_leaf``/
+``scan_from_leaf``) as scalar loops with identical semantics, ties and
+edge cases included; interpret-mode parity against the jnp oracle
+(``kernels/ref.py`` ``batched_*_fused_ref``) pins it bit for bit.  The
+oracle is what XLA:CPU lowers; ``interpret=True`` is the CPU-testable
+kernel path, compiled Mosaic the TPU one.
 """
 from __future__ import annotations
 
@@ -41,214 +53,541 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import read_path as _rp
-from repro.core.heap import LEAF, NULL
-from repro.core.keys import jax_key_cmp
+from repro.core.heap import LEAF, LOG_DELETE, NULL
 from repro.core.schema import NodeImageLayout
 
+from .delta_scatter import GROUP, as_i32, pad_rows, window
 
-def _row_view(layout, row, rv):
-    """One-row SnapshotFields over a [1, image_words] image row: the same
-    static-offset decode the reference path applies to the whole image."""
-    return _rp.SnapshotFields(read_version=rv, **layout.field_views(row))
+PT_LANES = 128     # the page table is viewed as [*, 128] lane rows
+_SIGN = -2 ** 31   # xor with this maps u32 order onto i32 order
+_INT_MIN = -2 ** 31
 
 
-def _fused_kernel(cfg, routed_k: int, mode: str):
+def _sign(d):
+    return jnp.where(d > 0, 1, jnp.where(d < 0, -1, 0))
+
+
+def _key_cmp(a, alen, b, blen):
+    """``core/keys.jax_key_cmp`` on scalars: ``a``/``b`` are lists of
+    key_words i32 scalars holding u32 lanes.  The sign of the first
+    differing lane (unsigned), else the sign of the length difference."""
+    res = jnp.int32(0)
+    for x, y in zip(reversed(a), reversed(b)):
+        lt = (x ^ _SIGN) < (y ^ _SIGN)
+        res = jnp.where(x != y, jnp.where(lt, -1, 1), res)
+    return jnp.where(res != 0, res, _sign(alen - blen))
+
+
+def _fused_kernel(cfg, routed_k: int, mode: str, n_req: int, n_cache: int):
     """Build the megakernel body.  ``mode`` is "get" or "scan"; both share
     the descend + floor + forward-scan spine (GET is SCAN(K, K) plus the
-    equality post-pass, exactly as in the reference path)."""
+    equality post-pass, exactly as in the reference path).  ``n_req`` is
+    the unpadded batch size: padded lanes do no work and meter nothing."""
     layout = NodeImageLayout.for_config(cfg)
-    IW = layout.image_words
-    M = cfg.max_scan_items
-    T = cfg.node_cap + cfg.log_cap
+    off = {name: s.offset for name, s in layout.slots.items()}
+    N, L, NSC = cfg.node_cap, cfg.log_cap, cfg.n_shortcuts
+    SEG, M = cfg.segment_items, cfg.max_scan_items
     KW, VW = cfg.key_words, cfg.val_words
+    H, MSL = cfg.max_height, cfg.max_scan_leaves
+    n_out = 7 if mode == "scan" else 4
 
     def kernel(scal_ref, lo_ref, lolen_ref, hi_ref, hilen_ref, clids_ref,
-               cimg_ref, pt_ref, img_ref, *out_refs):
-        rv = scal_ref[1]
-        lo = lo_ref[...]                       # [1, KW]
-        lolen = lolen_ref[...]                 # [1]
-        hi = hi_ref[...]
-        hilen = hilen_ref[...]
-        clids = clids_ref[...]                 # [C]
-        cimg = cimg_ref[...]                   # [C, IW] — the VMEM pin
-        lane = pl.program_id(0)
-        routed = (lane % 16) < routed_k
-        z = jnp.zeros((1,), jnp.int32)
-        rows1 = jnp.arange(1)
+               cimg_ref, pt_ref, img_ref, *refs):
+        outs = refs[:n_out]
+        (node, ptw, lpos, lrank, lord, seq, runid, runmax, okeys, oklens,
+         ovals, ovlens, fitem, sem) = refs[n_out:]
+        root, rv = scal_ref[0], scal_ref[1]
+        clids = clids_ref[...]                          # [1, C] (VMEM)
+        cache_iota = jax.lax.broadcasted_iota(jnp.int32, clids.shape, 1)
 
-        def load_row(phys):                    # dynamic heap-image row load
-            return pl.load(img_ref, (pl.ds(jnp.maximum(phys, 0), 1),
-                                     slice(None)))
+        # ---- memory: windowed DMAs into SMEM ------------------------------
+        def dma(src, dst):
+            cp = pltpu.make_async_copy(src, dst, sem)
+            cp.start()
+            cp.wait()
 
-        def view1(row):
-            return _row_view(layout, row, rv)
+        def load_heap(p):
+            dma(window(img_ref, p), node)
+            return p % GROUP
 
-        def fetch_heap(lid):
-            """pagetable lookup + MVCC old-version walk + row load — the
-            slow pipe (only executed below the cached frontier or for
-            lb-routed lanes, via lax.cond)."""
-            p0 = pl.load(pt_ref, (pl.ds(jnp.maximum(lid, 0), 1),))[0]
+        def load_cache(slot):
+            dma(window(cimg_ref, slot), node)
+            return slot % GROUP
 
-            def step(_, p):
-                v = view1(load_row(p))
-                too_new = (v.version[0] > rv) & (v.oldptr[0] != NULL)
-                return jnp.where(too_new, v.oldptr[0], p)
+        def rd(r, k):                 # one image word, as its i32 pattern
+            return node[r, k].astype(jnp.int32)      # same-width: bits kept
 
-            p = jax.lax.fori_loop(0, cfg.max_version_chain, step,
-                                  jnp.maximum(p0, 0))
-            return load_row(p)
+        def word(r, name, k=0):
+            return rd(r, off[name] + k)
 
-        # ---- descend: cache tier first, heap fall-through ----------------
-        def level(_, state):
-            lid, row, done, vh, hg, lr = state
-            eq = clids == lid
-            hit = eq.any() & (lid != NULL)
-            slot = jnp.argmax(eq).astype(jnp.int32)
-            use_cache = hit & ~routed
+        def node_key(r, base):
+            return [rd(r, base + w) for w in range(KW)]
 
-            def from_cache():
-                return jax.lax.dynamic_slice(cimg, (slot, 0), (1, IW))
+        def fetch_lid(lid):
+            """Page-table lookup + MVCC old-version walk + row fetch — the
+            heap pipe.  Returns (resolved phys, window row)."""
+            lid = jnp.maximum(lid, 0)
+            dma(window(pt_ref, lid // PT_LANES), ptw)
+            p0 = jnp.maximum(ptw[lid // PT_LANES % GROUP, lid % PT_LANES], 0)
 
-            new_row = jax.lax.cond(
-                done, lambda: row,
-                lambda: jax.lax.cond(use_cache, from_cache,
-                                     lambda: fetch_heap(lid)))
-            live = ~done
-            vh = vh + (use_cache & live).astype(jnp.int32)
-            hg = hg + (~use_cache & live).astype(jnp.int32)
-            lr = lr + (hit & routed & live).astype(jnp.int32)
-            v = view1(new_row)
-            is_leaf = v.ntype[0] == LEAF
-            seg = _rp._shortcut_floor(v, z, lo, lolen)
-            idx = _rp._segment_floor(v, z, seg, lo, lolen, cfg)
-            child = jnp.where(
-                idx[0] >= 0,
-                v.svals[0, jnp.maximum(idx[0], 0), 0].astype(jnp.int32),
-                v.left_child[0])
-            new_done = done | is_leaf
-            new_lid = jnp.where(new_done, lid, child)
-            return new_lid, new_row, new_done, vh, hg, lr
+            # the predicate is carried, not read in the loop condition:
+            # a while-loop condition must not read refs
+            def too_new(r):
+                return (word(r, "version") > rv) & (word(r, "oldptr") != NULL)
 
-        zi = jnp.zeros((), jnp.int32)
-        root = scal_ref[0]
-        _, leaf_row, _, vh, hg, lr = jax.lax.fori_loop(
-            0, cfg.max_height, level,
-            (root, load_row(jnp.zeros((), jnp.int32)),
-             jnp.zeros((), bool), zi, zi, zi))
+            def cond(st):
+                return (st[0] < cfg.max_version_chain) & st[3]
 
-        # ---- floor pre-pass: walk left until a visible key <= lo ---------
-        def floor_step(_, state):
-            row, fkeys, fklens, fvals, fvlens, have = state
-            keys, klens, vals, vlens, live = _rp._resolve_leaf(
-                view1(row), z, cfg)
-            leq = live & (jax_key_cmp(keys, klens, lo[:, None, :],
-                                      lolen[:, None]) <= 0)
-            idx = jnp.where(leq, jnp.arange(T)[None, :], -1).max(axis=1)
-            found = idx >= 0
-            sel = jnp.maximum(idx, 0)
-            upd = found & ~have
-            fkeys = jnp.where(upd[:, None], keys[rows1, sel], fkeys)
-            fklens = jnp.where(upd, klens[rows1, sel], fklens)
-            fvals = jnp.where(upd[:, None], vals[rows1, sel], fvals)
-            fvlens = jnp.where(upd, vlens[rows1, sel], fvlens)
-            have = have | found
-            nxt = view1(row).lsib[0]
-            can_move = (~have[0]) & (nxt != NULL)
-            new_row = jax.lax.cond(can_move, lambda: fetch_heap(nxt),
-                                   lambda: row)
-            return new_row, fkeys, fklens, fvals, fvlens, have
+            def older(st):
+                hops, _, r, _ = st
+                p = jnp.maximum(word(r, "oldptr"), 0)
+                r = load_heap(p)
+                return hops + 1, p, r, too_new(r)
 
-        _, fkeys, fklens, fvals, fvlens, have_floor = jax.lax.fori_loop(
-            0, cfg.max_scan_leaves, floor_step,
-            (leaf_row, jnp.zeros((1, KW), jnp.uint32), z,
-             jnp.zeros((1, VW), jnp.uint32), z, jnp.zeros((1,), bool)))
+            r0 = load_heap(p0)
+            _, p, r, _ = jax.lax.while_loop(
+                cond, older, (jnp.int32(0), p0, r0, too_new(r0)))
+            return p, r
 
-        emit_floor = have_floor & (jax_key_cmp(fkeys, fklens, hi,
-                                               hilen) <= 0)
-        out_keys = jnp.zeros((1, M, KW), jnp.uint32) \
-            .at[:, 0].set(jnp.where(emit_floor[:, None], fkeys, 0))
-        out_klens = jnp.zeros((1, M), jnp.int32) \
-            .at[:, 0].set(jnp.where(emit_floor, fklens, 0))
-        out_vals = jnp.zeros((1, M, VW), jnp.uint32) \
-            .at[:, 0].set(jnp.where(emit_floor[:, None], fvals, 0))
-        out_vlens = jnp.zeros((1, M), jnp.int32) \
-            .at[:, 0].set(jnp.where(emit_floor, fvlens, 0))
-        count = emit_floor.astype(jnp.int32)
+        # ---- descend: cache tier first, heap fall-through -----------------
+        def child_of(r, q, qlen):
+            """Shortcut floor, then segment floor, then the child LID."""
+            nsc = word(r, "n_shortcuts")
 
-        # ---- forward scan across sibling leaves --------------------------
-        def leaf_step(_, state):
-            (row, out_keys, out_klens, out_vals, out_vlens, count, trunc,
-             done) = state
-            keys, klens, vals, vlens, live = _rp._resolve_leaf(
-                view1(row), z, cfg)
-            gt_lo = jax_key_cmp(keys, klens, lo[:, None, :],
-                                lolen[:, None]) > 0
-            leq_hi = jax_key_cmp(keys, klens, hi[:, None, :],
-                                 hilen[:, None]) <= 0
-            emit = live & gt_lo & leq_hi & ~done[:, None]
-            local = jnp.cumsum(emit, axis=1) - 1
-            slot = count[:, None] + local
-            ok = emit & (slot < M)
-            slot_c = jnp.where(ok, jnp.clip(slot, 0, M - 1), M)
-            br = rows1[:, None]
-            out_keys = out_keys.at[br, slot_c].set(keys, mode="drop")
-            out_klens = out_klens.at[br, slot_c].set(klens, mode="drop")
-            out_vals = out_vals.at[br, slot_c].set(vals, mode="drop")
-            out_vlens = out_vlens.at[br, slot_c].set(vlens, mode="drop")
-            count = count + ok.sum(axis=1)
-            trunc = trunc | (emit & ~ok).any(axis=1)
-            past_hi = (live & ~leq_hi).any(axis=1)
-            nxt = view1(row).rsib[0]
-            done = done | past_hi | (nxt == NULL) | trunc
-            new_row = jax.lax.cond(done[0], lambda: row,
-                                   lambda: fetch_heap(nxt))
-            return (new_row, out_keys, out_klens, out_vals, out_vlens,
-                    count, trunc, done)
+            def sc_step(s, idx):
+                c = _key_cmp(node_key(r, off["sc_keys"] + s * KW),
+                             word(r, "sc_keylen", s), q, qlen)
+                return jnp.where((s < nsc) & (c <= 0), s, idx)
 
-        state = (leaf_row, out_keys, out_klens, out_vals, out_vlens, count,
-                 jnp.zeros((1,), bool), jnp.zeros((1,), bool))
-        (_, out_keys, out_klens, out_vals, out_vlens, count, trunc,
-         done) = jax.lax.fori_loop(0, cfg.max_scan_leaves, leaf_step, state)
-        trunc = trunc | ~done
+            seg = jnp.maximum(
+                jax.lax.fori_loop(0, NSC, sc_step, jnp.int32(-1)), 0)
+            base = word(r, "sc_pos", seg)
+            n = word(r, "nitems")
 
-        if mode == "scan":
-            (count_ref, keys_ref, klens_ref, vals_ref, vlens_ref, trunc_ref,
-             meters_ref) = out_refs
-            count_ref[...] = count[:, None]
-            keys_ref[...] = out_keys
-            klens_ref[...] = out_klens
-            vals_ref[...] = out_vals
-            vlens_ref[...] = out_vlens
-            trunc_ref[...] = trunc.astype(jnp.int32)[:, None]
-        else:
-            eq = (jax_key_cmp(out_keys, out_klens, lo[:, None, :],
-                              lolen[:, None]) == 0) \
-                & (jnp.arange(M)[None, :] < count[:, None])
-            found = eq.any(axis=1)
-            idx = jnp.argmax(eq, axis=1)
-            found_ref, vals_ref, vlens_ref, meters_ref = out_refs
-            found_ref[...] = found.astype(jnp.int32)[:, None]
-            vals_ref[...] = out_vals[rows1, idx]
-            vlens_ref[...] = out_vlens[rows1, idx][:, None]
-        meters_ref[...] = jnp.stack([vh, hg, lr])[None, :]
+            def seg_step(t, local):
+                o = base + t
+                oc = jnp.minimum(o, N - 1)
+                oc = jnp.where(oc < 0, oc + N, oc)
+                c = _key_cmp(node_key(r, off["skeys"] + oc * KW),
+                             word(r, "skeylen", oc), q, qlen)
+                return jnp.where((o < n) & (c <= 0), t, local)
+
+            local = jax.lax.fori_loop(0, SEG, seg_step, jnp.int32(-1))
+            idx = jnp.where(local >= 0, base + local, -1)
+            return jnp.where(
+                idx >= 0, word(r, "svals", jnp.maximum(idx, 0) * VW),
+                word(r, "left_child"))
+
+        def descend(lane, q, qlen):
+            """Returns (leaf source is_cache, cache slot or heap phys, window
+            row, [vmem_hits, heap_gathers, lb_routed])."""
+            routed = (lane % 16) < routed_k
+
+            def cond(st):
+                return (st[0] < H) & (st[1] == 0)
+
+            def level(st):
+                lvl, _, lid, _, _, _, vh, hg, lr = st
+                eq = clids == lid
+                hit = (jnp.max(eq.astype(jnp.int32)) > 0) & (lid != NULL)
+                slot = jnp.min(jnp.where(eq, cache_iota, n_cache))
+                use_cache = hit & ~routed
+
+                def from_cache():
+                    return slot, load_cache(slot)
+
+                src, r = jax.lax.cond(use_cache, from_cache,
+                                      lambda: fetch_lid(lid))
+                done = (word(r, "ntype") == LEAF).astype(jnp.int32)
+                child = child_of(r, q, qlen)
+                return (lvl + 1, done, jnp.where(done == 1, lid, child),
+                        use_cache.astype(jnp.int32), src, r,
+                        vh + use_cache.astype(jnp.int32),
+                        hg + (~use_cache).astype(jnp.int32),
+                        lr + (hit & routed).astype(jnp.int32))
+
+            z = jnp.int32(0)
+            st = jax.lax.while_loop(cond, level,
+                                    (z, z, root, z, z, z, z, z, z))
+            return st[3], st[4], st[5], (st[6], st[7], st[8])
+
+        # ---- leaf resolve: merged, shadow-resolved walk in key order ------
+        def item_addr(r, t):
+            """(key base, key len, value base, value len, is-log, log slot)
+            of merged item ``t`` (< N sorted block, else log slot t - N)."""
+            is_log = t >= N
+            j = jnp.maximum(t - N, 0)
+            kb = jnp.where(is_log, off["log_keys"] + j * KW,
+                           off["skeys"] + t * KW)
+            klen = jnp.where(is_log, word(r, "log_keylen", j),
+                             word(r, "skeylen", jnp.minimum(t, N - 1)))
+            vb = jnp.where(is_log, off["log_vals"] + j * VW,
+                           off["svals"] + t * VW)
+            vlen = jnp.where(is_log, word(r, "log_vallen", j),
+                             word(r, "svallen", jnp.minimum(t, N - 1)))
+            return kb, klen, vb, vlen, is_log, j
+
+        def walk_leaf(r, consume, carry):
+            """Feed the leaf's items to ``consume(r, carry, item, live)`` in
+            merged key order — exactly the order and liveness
+            ``read_path._resolve_leaf`` produces for the used slots (unused
+            slots are never live, so they are not visited)."""
+            nit = jnp.clip(word(r, "nitems"), 0, N)
+            nlg = jnp.clip(word(r, "nlog"), 0, L)
+            nv = word(r, "version")
+
+            # RSU log sort: one shift-register step per entry (Fig. 8)
+            def sort_step(j, _):
+                h = word(r, "log_hint", j)
+
+                def shift(k, __):
+                    lpos[k] = lpos[k] + (lpos[k] >= h).astype(jnp.int32)
+                    return 0
+
+                jax.lax.fori_loop(0, j, shift, 0)
+                lpos[j] = h
+                return 0
+
+            jax.lax.fori_loop(0, nlg, sort_step, 0)
+
+            def rank_step(j, _):
+                lrank[j] = word(r, "log_backptr", j) * (L + 1) + lpos[j]
+                return 0
+
+            jax.lax.fori_loop(0, nlg, rank_step, 0)
+
+            def order_step(j, _):          # stable rank order of the log
+                def before(k, n):
+                    rk = lrank[k]
+                    return n + ((rk < lrank[j])
+                                | ((rk == lrank[j]) & (k < j))
+                                ).astype(jnp.int32)
+
+                lord[jax.lax.fori_loop(0, nlg, before, 0)] = j
+                return 0
+
+            jax.lax.fori_loop(0, nlg, order_step, 0)
+
+            # merge by rank: a sorted item wins ties (lower concat index)
+            def merge_step(p, st):
+                i, q = st
+                # clamped: once the log is exhausted the slot read is stale
+                # (unused) and must still address inside ``lrank``
+                lq = jnp.clip(lord[jnp.minimum(q, L - 1)], 0, L - 1)
+                take = (i < nit) & ((q >= nlg)
+                                    | (i * (L + 1) + L <= lrank[lq]))
+                seq[p] = jnp.where(take, i, N + lq)
+                return (i + take.astype(jnp.int32),
+                        q + (~take).astype(jnp.int32))
+
+            U = nit + nlg
+            jax.lax.fori_loop(0, U, merge_step, (jnp.int32(0), jnp.int32(0)))
+
+            def vmask_of(t):
+                kb, klen, vb, vlen, is_log, j = item_addr(r, t)
+                vers = jnp.where(is_log, nv + word(r, "log_vdelta", j), nv)
+                vis = ~is_log | (vers <= rv)
+                return (jnp.where(vis, vers, _INT_MIN), vis,
+                        is_log & (word(r, "log_op", j) == LOG_DELETE))
+
+            # runs of equal adjacent keys and each run's newest visible
+            def run_step(p, run):
+                t = seq[p]
+                kb, klen = item_addr(r, t)[:2]
+                pkb, pklen = item_addr(r, seq[jnp.maximum(p - 1, 0)])[:2]
+                same = (p > 0) & (_key_cmp(node_key(r, kb), klen,
+                                           node_key(r, pkb), pklen) == 0)
+                run = jnp.where(same, run, run + 1)
+                vm = vmask_of(t)[0]
+                runmax[run] = jnp.where(same, jnp.maximum(runmax[run], vm),
+                                        vm)
+                runid[p] = run
+                return run
+
+            jax.lax.fori_loop(0, U, run_step, jnp.int32(-1))
+
+            def emit_step(p, carry):
+                t = seq[p]
+                vm, vis, isdel = vmask_of(t)
+                live = vis & (vm == runmax[runid[p]]) & ~isdel
+                return consume(r, carry, t, live)
+
+            return jax.lax.fori_loop(0, U, emit_step, carry)
+
+        # an item record is [key words, key len, value words, value len]
+        def copy_item(r, t, put):
+            kb, klen, vb, vlen = item_addr(r, t)[:4]
+            for w in range(KW):
+                put(w, rd(r, kb + w))
+            put(KW, klen)
+            for w in range(VW):
+                put(KW + 1 + w, rd(r, vb + w))
+            put(KW + 1 + VW, vlen)
+
+        def put_fitem(k, v):
+            fitem[k] = v
+
+        def put_slot(m):
+            def put(k, v):
+                if k < KW:
+                    okeys[m * KW + k] = v
+                elif k == KW:
+                    oklens[m] = v
+                elif k < KW + 1 + VW:
+                    ovals[m * VW + k - KW - 1] = v
+                else:
+                    ovlens[m] = v
+            return put
+
+        # ---- one request ---------------------------------------------------
+        def request(j, lane):
+            lo = [lo_ref[j, w] for w in range(KW)]
+            lolen = lolen_ref[j, 0]
+            if mode == "scan":
+                hi = [hi_ref[j, w] for w in range(KW)]
+                hilen = hilen_ref[j, 0]
+            else:
+                hi, hilen = lo, lolen
+
+            def zero_slot(m, _):
+                for w in range(KW):
+                    okeys[m * KW + w] = jnp.int32(0)
+                for w in range(VW):
+                    ovals[m * VW + w] = jnp.int32(0)
+                oklens[m] = jnp.int32(0)
+                ovlens[m] = jnp.int32(0)
+                return 0
+
+            jax.lax.fori_loop(0, M, zero_slot, 0)
+            is_cache, src, r0, meters = descend(lane, lo, lolen)
+
+            # floor pre-pass: walk left until a visible key <= lo
+            def floor_consume(r, cand, t, live):
+                kb, klen = item_addr(r, t)[:2]
+                c = _key_cmp(node_key(r, kb), klen, lo, lolen)
+                return jnp.where(live & (c <= 0), t, cand)
+
+            def floor_cond(st):
+                return st[3] == 0
+
+            def floor_step(st):
+                step, have, moved, _, r = st
+                cand = walk_leaf(r, floor_consume, jnp.int32(-1))
+                found = cand >= 0
+
+                @pl.when(found & (have == 0))
+                def _():
+                    copy_item(r, cand, put_fitem)
+
+                have = jnp.maximum(have, found.astype(jnp.int32))
+                nxt = word(r, "lsib")
+                move = (have == 0) & (nxt != NULL) & (step < MSL - 1)
+
+                def go():
+                    return fetch_lid(nxt)[1]
+
+                r = jax.lax.cond(move, go, lambda: r)
+                return (step + 1, have, moved | move.astype(jnp.int32),
+                        (~move).astype(jnp.int32), r)
+
+            z = jnp.int32(0)
+            _, have, moved, _, _ = jax.lax.while_loop(
+                floor_cond, floor_step, (z, z, z, z, r0))
+            fkey = [fitem[w] for w in range(KW)]
+            emit_floor = (have == 1) & (
+                _key_cmp(fkey, fitem[KW], hi, hilen) <= 0)
+
+            @pl.when(emit_floor)
+            def _():
+                put = put_slot(0)
+                for k in range(KW + VW + 2):
+                    put(k, fitem[k])
+
+            count0 = emit_floor.astype(jnp.int32)
+
+            # forward scan across sibling leaves, from the descend's leaf
+            @pl.when(moved == 1)
+            def _():
+                jax.lax.cond(is_cache == 1, lambda: load_cache(src),
+                             lambda: load_heap(src))
+
+            def fwd_consume(r, carry, t, live):
+                count, trunc, past = carry
+                kb, klen = item_addr(r, t)[:2]
+                key = node_key(r, kb)
+                c_lo = _key_cmp(key, klen, lo, lolen)
+                c_hi = c_lo if mode == "get" else _key_cmp(key, klen, hi,
+                                                           hilen)
+                emit = live & (c_lo > 0) & (c_hi <= 0)
+                ok = emit & (count < M)
+
+                @pl.when(ok)
+                def _():
+                    copy_item(r, t, put_slot(count))
+
+                return (count + ok.astype(jnp.int32),
+                        trunc | (emit & ~ok).astype(jnp.int32),
+                        past | (live & (c_hi > 0)).astype(jnp.int32))
+
+            def fwd_cond(st):
+                return (st[0] < MSL) & (st[1] == 0)
+
+            def fwd_step(st):
+                step, _, count, trunc, r = st
+                count, trunc, past = walk_leaf(r, fwd_consume,
+                                               (count, trunc, jnp.int32(0)))
+                nxt = word(r, "rsib")
+                done = (past == 1) | (nxt == NULL) | (trunc == 1)
+
+                def go():
+                    return fetch_lid(nxt)[1]
+
+                r = jax.lax.cond(~done & (step < MSL - 1), go, lambda: r)
+                return step + 1, done.astype(jnp.int32), count, trunc, r
+
+            _, done, count, trunc, _ = jax.lax.while_loop(
+                fwd_cond, fwd_step, (z, z, count0, z, r0))
+            trunc = trunc | (1 - done)
+            return count, trunc, meters
+
+        # ---- the block's requests -------------------------------------------
+        blk = pl.program_id(0)
+
+        def lane_step(j, _):
+            lane = blk * GROUP + j
+
+            def run():
+                return request(j, lane)
+
+            def skip():
+                z = jnp.int32(0)
+                return z, z, (z, z, z)
+
+            count, trunc, meters = jax.lax.cond(lane < n_req, run, skip)
+            meters_ref = outs[-1]
+            for k in range(3):
+                meters_ref[j, k] = meters[k]
+            if mode == "scan":
+                count_ref, keys_ref, klens_ref, vals_ref, vlens_ref, \
+                    trunc_ref, _ = outs
+                count_ref[j, 0] = count
+                trunc_ref[j, 0] = trunc
+
+                def copy_slot(m, __):
+                    live = (lane < n_req).astype(jnp.int32)
+                    for w in range(KW):
+                        keys_ref[j, m, w] = okeys[m * KW + w] * live
+                    for w in range(VW):
+                        vals_ref[j, m, w] = ovals[m * VW + w] * live
+                    klens_ref[j, m] = oklens[m] * live
+                    vlens_ref[j, m] = ovlens[m] * live
+                    return 0
+
+                jax.lax.fori_loop(0, M, copy_slot, 0)
+            else:
+                found_ref, vals_ref, vlens_ref, _ = outs
+                lo = [lo_ref[j, w] for w in range(KW)]
+                lolen = lolen_ref[j, 0]
+
+                def first_eq(m, idx):
+                    key = [okeys[m * KW + w] for w in range(KW)]
+                    eq = (m < count) & (
+                        _key_cmp(key, oklens[m], lo, lolen) == 0)
+                    return jnp.where((idx < 0) & eq, m, idx)
+
+                idx = jax.lax.fori_loop(0, M, first_eq, jnp.int32(-1))
+                live = (lane < n_req).astype(jnp.int32)
+                found_ref[j, 0] = (idx >= 0).astype(jnp.int32)
+                sel = jnp.maximum(idx, 0)
+                for w in range(VW):
+                    vals_ref[j, w] = ovals[sel * VW + w] * live
+                vlens_ref[j, 0] = ovlens[sel] * live
+            return 0
+
+        jax.lax.fori_loop(0, GROUP, lane_step, 0)
 
     return kernel
 
 
-def _common_specs(KW, C, IW):
-    """in_specs shared by both megakernels: per-request key blocks, the
-    cache tier resident in VMEM, page table + heap image in ANY (addressed
-    dynamically by the body)."""
-    return [
-        pl.BlockSpec((1, KW), lambda i, s: (i, 0)),      # lo key
-        pl.BlockSpec((1,), lambda i, s: (i,)),           # lo len
-        pl.BlockSpec((1, KW), lambda i, s: (i, 0)),      # hi key
-        pl.BlockSpec((1,), lambda i, s: (i,)),           # hi len
-        pl.BlockSpec((C,), lambda i, s: (0,)),           # cache lids (VMEM)
-        pl.BlockSpec((C, IW), lambda i, s: (0, 0)),      # cache image (VMEM)
-        pl.BlockSpec(memory_space=pltpu.ANY),            # page table
-        pl.BlockSpec(memory_space=pltpu.ANY),            # heap image
-    ]
+def _fused_call(mode, image, pagetable, root_lid, read_version, cache_lids,
+                cache_image, lo, lolen, hi, hilen, cfg, lb_fraction,
+                interpret):
+    """Shared launcher: pads the request batch to whole 8-request blocks,
+    the cache tier to whole 8-row windows and the page table to whole
+    [8, 128] windows.  The heap image passes through untouched (u32; the
+    kernel reinterprets each word it loads)."""
+    B = lo.shape[0]
+    IW = image.shape[1]
+    M, KW, VW = cfg.max_scan_items, cfg.key_words, cfg.val_words
+    lo = pad_rows(as_i32(lo))
+    hi = pad_rows(as_i32(hi))
+    lolen = pad_rows(as_i32(lolen).reshape(-1, 1))
+    hilen = pad_rows(as_i32(hilen).reshape(-1, 1))
+    Bp = lo.shape[0]
+    img = pad_rows(image, fill=0)
+    clids = pad_rows(as_i32(cache_lids), fill=NULL)
+    C = clids.shape[0]
+    cimg = pad_rows(cache_image, fill=0)
+    pt = pad_rows(as_i32(pagetable), GROUP * PT_LANES, NULL)
+    pt = pt.reshape(-1, PT_LANES)
+    scal = jnp.stack([root_lid.astype(jnp.int32),
+                      read_version.astype(jnp.int32)])
+    T = cfg.node_cap + cfg.log_cap
+    L = cfg.log_cap
+
+    def smem(shape):
+        nd = len(shape)
+        return pl.BlockSpec((GROUP,) + shape,
+                            lambda i, s: (i,) + (0,) * nd,
+                            memory_space=pltpu.SMEM)
+
+    if mode == "scan":
+        out_shapes = [(1,), (M, KW), (M,), (M, VW), (M,), (1,), (3,)]
+    else:
+        out_shapes = [(1,), (VW,), (1,), (3,)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Bp // GROUP,),
+        in_specs=[
+            smem((KW,)), smem((1,)), smem((KW,)), smem((1,)),
+            pl.BlockSpec((1, C), lambda i, s: (0, 0),     # cache lids
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((C, IW), lambda i, s: (0, 0),    # cache image
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),            # page table
+            pl.BlockSpec(memory_space=pl.ANY),            # heap image
+        ],
+        out_specs=[smem(s) for s in out_shapes],
+        scratch_shapes=[
+            pltpu.SMEM((GROUP, IW), image.dtype),         # node window
+            pltpu.SMEM((GROUP, PT_LANES), jnp.int32),     # page-table window
+            pltpu.SMEM((L,), jnp.int32),                  # log sort positions
+            pltpu.SMEM((L,), jnp.int32),                  # log ranks
+            pltpu.SMEM((L,), jnp.int32),                  # log rank order
+            pltpu.SMEM((T,), jnp.int32),                  # merged item order
+            pltpu.SMEM((T,), jnp.int32),                  # run id per item
+            pltpu.SMEM((T,), jnp.int32),                  # run newest version
+            pltpu.SMEM((M * KW,), jnp.int32),             # result keys
+            pltpu.SMEM((M,), jnp.int32),                  # result key lens
+            pltpu.SMEM((M * VW,), jnp.int32),             # result values
+            pltpu.SMEM((M,), jnp.int32),                  # result value lens
+            pltpu.SMEM((KW + VW + 2,), jnp.int32),        # floor item
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    return pl.pallas_call(
+        _fused_kernel(cfg, int(round(lb_fraction * 16)), mode, B, C),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((Bp,) + s, jnp.int32)
+                   for s in out_shapes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(scal, lo, lolen, hi, hilen, clids.reshape(1, C), cimg, pt, img)
+
+
+def _u32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "lb_fraction",
@@ -259,42 +598,12 @@ def batched_scan_fused(image, pagetable, root_lid, read_version, cache_lids,
     """Fused SCAN(K_l, K_u): ONE dispatch for the whole batch.  Returns
     (ScanResult, meters i32[3]) matching ``ref.batched_scan_fused_ref``."""
     B = lo.shape[0]
-    S, IW = image.shape
-    C = cache_lids.shape[0]
-    M, KW, VW = cfg.max_scan_items, cfg.key_words, cfg.val_words
-    scal = jnp.stack([root_lid.astype(jnp.int32),
-                      read_version.astype(jnp.int32)])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B,),
-        in_specs=_common_specs(KW, C, IW),
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),       # count
-            pl.BlockSpec((1, M, KW), lambda i, s: (i, 0, 0)),
-            pl.BlockSpec((1, M), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, M, VW), lambda i, s: (i, 0, 0)),
-            pl.BlockSpec((1, M), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),       # truncated
-            pl.BlockSpec((1, 3), lambda i, s: (i, 0)),       # meters
-        ],
-    )
-    count, keys, klens, vals, vlens, trunc, meters = pl.pallas_call(
-        _fused_kernel(cfg, int(round(lb_fraction * 16)), "scan"),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, M, KW), jnp.uint32),
-            jax.ShapeDtypeStruct((B, M), jnp.int32),
-            jax.ShapeDtypeStruct((B, M, VW), jnp.uint32),
-            jax.ShapeDtypeStruct((B, M), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 3), jnp.int32),
-        ],
-        interpret=interpret,
-    )(scal, lo, lolen, hi, hilen, cache_lids, cache_image, pagetable, image)
-    res = _rp.ScanResult(count[:, 0], keys, klens, vals, vlens,
-                         trunc[:, 0] != 0)
-    return res, meters.sum(axis=0)
+    count, keys, klens, vals, vlens, trunc, meters = _fused_call(
+        "scan", image, pagetable, root_lid, read_version, cache_lids,
+        cache_image, lo, lolen, hi, hilen, cfg, lb_fraction, interpret)
+    res = _rp.ScanResult(count[:B, 0], _u32(keys[:B]), klens[:B],
+                         _u32(vals[:B]), vlens[:B], trunc[:B, 0] != 0)
+    return res, meters[:B].sum(axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "lb_fraction",
@@ -305,32 +614,8 @@ def batched_get_fused(image, pagetable, root_lid, read_version, cache_lids,
     """Fused GET(K): ONE dispatch for the whole batch.  Returns
     (GetResult, meters i32[3]) matching ``ref.batched_get_fused_ref``."""
     B = key.shape[0]
-    S, IW = image.shape
-    C = cache_lids.shape[0]
-    KW, VW = cfg.key_words, cfg.val_words
-    scal = jnp.stack([root_lid.astype(jnp.int32),
-                      read_version.astype(jnp.int32)])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B,),
-        in_specs=_common_specs(KW, C, IW),
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),       # found
-            pl.BlockSpec((1, VW), lambda i, s: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, s: (i, 0)),       # vallen
-            pl.BlockSpec((1, 3), lambda i, s: (i, 0)),       # meters
-        ],
-    )
-    found, vals, vlens, meters = pl.pallas_call(
-        _fused_kernel(cfg, int(round(lb_fraction * 16)), "get"),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, VW), jnp.uint32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 3), jnp.int32),
-        ],
-        interpret=interpret,
-    )(scal, key, klen, key, klen, cache_lids, cache_image, pagetable, image)
-    res = _rp.GetResult(found[:, 0] != 0, vals, vlens[:, 0])
-    return res, meters.sum(axis=0)
+    found, vals, vlens, meters = _fused_call(
+        "get", image, pagetable, root_lid, read_version, cache_lids,
+        cache_image, key, klen, key, klen, cfg, lb_fraction, interpret)
+    res = _rp.GetResult(found[:B, 0] != 0, _u32(vals[:B]), vlens[:B, 0])
+    return res, meters[:B].sum(axis=0)

@@ -5,7 +5,12 @@
   "interpret" Pallas kernel body executed in Python on CPU (correctness)
   "pallas"    compiled Pallas kernel — real TPUs
 
-The default follows the runtime: TPU -> pallas, else ref.
+The default follows the runtime: TPU -> pallas, else ref.  It is decided
+at dispatch, never at import: importing the store must not initialise a
+JAX backend (that claims the chip for the importing process).  On a TPU
+the store's read and sync kernels (delta/image/log-replay scatters, fused
+GET/SCAN) run compiled Pallas only — asking them for "ref" or "interpret"
+there is an error, and a lowering error surfaces as is.
 """
 from __future__ import annotations
 
@@ -24,6 +29,16 @@ from . import ref as _ref
 
 def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _store_backend(backend: str | None) -> str:
+    """Resolve a store read/sync kernel backend; the CPU paths exist for
+    the tests only and are refused on a TPU."""
+    backend = backend or default_backend()
+    if backend != "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(f"store kernels run compiled Pallas on a TPU; "
+                         f"backend={backend!r} is a CPU test path")
+    return backend
 
 
 # ---------------------------------------------------------------- dispatch
@@ -131,7 +146,7 @@ def snapshot_delta_scatter(dst, rows, upd, backend: str | None = None, **kw):
     (host->device snapshot patch).  ``dst``/``upd`` are [S, W]/[D, W] with
     trailing dims flattened; see ``repro.core.read_path.apply_snapshot_delta``
     for the whole-snapshot jnp path the store uses off-TPU."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.snapshot_delta_scatter_ref(dst, rows, upd)
     return _ds.snapshot_delta_scatter(dst, rows, upd,
@@ -146,7 +161,7 @@ def snapshot_image_scatter(image, rows, upd, backend: str | None = None,
     cfg.layout="packed").  ``image``/``upd`` are [S, IW]/[D, IW] u32; see
     ``repro.core.read_path.apply_snapshot_delta`` for the store wiring and
     the jnp oracle kept as the parity reference."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.snapshot_image_scatter_ref(image, rows, upd)
     return _ds.snapshot_image_scatter(image, rows, upd,
@@ -163,7 +178,7 @@ def log_replay_scatter(image, rows, slots, entries, *, offs,
     of a whole ``image_words`` row DMA per dirty node.  ``slots`` are the
     per-entry log indices (monotone per row within an epoch; padding
     repeats the last record)."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.log_replay_scatter_ref(image, rows, slots, entries,
                                            offs=offs)
@@ -179,7 +194,7 @@ def snapshot_multi_scatter(dsts, rows, upd, backend: str | None = None,
     [S, W_f]/[D, W_f] arrays with trailing dims flattened; see
     ``repro.core.read_path.apply_snapshot_delta`` for the store wiring and
     the jnp oracle kept as the parity reference."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.snapshot_multi_scatter_ref(dsts, rows, upd)
     return _ds.snapshot_multi_scatter(dsts, rows, upd,
@@ -195,7 +210,7 @@ def batched_get_fused(snap, key, klen, *, cfg, lb_fraction: float = 0.0,
     VMEM-pinned cache tier.  ``snap`` is a packed ``TreeSnapshot`` with
     cache fields attached.  Returns (GetResult, meters i32[3] =
     [vmem_hits, heap_gathers, lb_routed])."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.batched_get_fused_ref(snap, key, klen, cfg=cfg,
                                           lb_fraction=lb_fraction)
@@ -210,7 +225,7 @@ def batched_scan_fused(snap, lo, lolen, hi, hilen, *, cfg,
                        backend: str | None = None):
     """Fused device-resident SCAN — see ``batched_get_fused``.  Returns
     (ScanResult, meters i32[3])."""
-    backend = backend or default_backend()
+    backend = _store_backend(backend)
     if backend == "ref":
         return _ref.batched_scan_fused_ref(snap, lo, lolen, hi, hilen,
                                            cfg=cfg, lb_fraction=lb_fraction)

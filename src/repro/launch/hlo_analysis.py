@@ -88,8 +88,6 @@ class Roofline:
 
 def roofline(cost: dict, coll: dict, model_flops_per_device: float
              ) -> Roofline:
-    if isinstance(cost, (list, tuple)):   # older jax: [{...}] per computation
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     cb = float(coll["total_bytes"])

@@ -8,16 +8,10 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist in
-# newer JAX; on older installs a plain Mesh has the same Auto semantics.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

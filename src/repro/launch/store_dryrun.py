@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import (Get, HoneycombConfig, HoneycombService, Put,
                         ReplicationConfig, ShardedHoneycombStore,
                         TelemetryConfig, Update, uniform_int_boundaries)

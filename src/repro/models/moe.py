@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from .config import ArchConfig
 from .schema import ParamDef
 
-from repro.compat import shard_map
+from jax import shard_map
 
 F32 = jnp.float32
 

@@ -1,112 +1,6 @@
-"""Test harness glue.
-
-This environment cannot install ``hypothesis``; the property tests in
-test_btree / test_keys / test_read_path only use a small strategy subset, so
-when the real package is missing we register a deterministic seeded-PRNG
-shim under the same import name.  Each ``@given`` test runs ``max_examples``
-times against values drawn from a PRNG seeded by the test name, which keeps
-failures reproducible run-to-run while exercising the same invariants.
-"""
+"""Test harness glue."""
 from __future__ import annotations
-
-import functools
-import sys
-import types
-import zlib
-
-import pytest
 
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running test")
-
-
-# --------------------------------------------------------------------------
-# minimal hypothesis stand-in (only built when hypothesis is absent)
-# --------------------------------------------------------------------------
-
-def _build_hypothesis_shim() -> types.ModuleType:
-    import numpy as np
-
-    class Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-        def flatmap(self, f):
-            return Strategy(lambda rng: f(self.draw(rng)).draw(rng))
-
-        def map(self, f):
-            return Strategy(lambda rng: f(self.draw(rng)))
-
-    def integers(min_value, max_value):
-        return Strategy(
-            lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-    def just(value):
-        return Strategy(lambda rng: value)
-
-    def binary(min_size=0, max_size=64):
-        def draw(rng):
-            n = int(rng.integers(min_size, max_size + 1))
-            return rng.integers(0, 256, n, dtype=np.uint8).tobytes()
-        return Strategy(draw)
-
-    def lists(elements, min_size=0, max_size=16):
-        def draw(rng):
-            n = int(rng.integers(min_size, max_size + 1))
-            return [elements.draw(rng) for _ in range(n)]
-        return Strategy(draw)
-
-    def tuples(*strategies):
-        return Strategy(lambda rng: tuple(s.draw(rng) for s in strategies))
-
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.SearchStrategy = Strategy
-    strategies.integers = integers
-    strategies.just = just
-    strategies.binary = binary
-    strategies.lists = lists
-    strategies.tuples = tuples
-
-    def settings(max_examples=20, deadline=None, **_kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(*gstrategies):
-        def deco(fn):
-            n_examples = getattr(fn, "_shim_max_examples", 20)
-
-            @functools.wraps(fn)
-            def runner(*args, **kwargs):
-                # zlib.crc32, not hash(): str hashing is salted per process
-                name = (fn.__module__ + "." + fn.__name__).encode()
-                seed = zlib.crc32(name)
-                rng = np.random.default_rng(seed)
-                for _ in range(n_examples):
-                    drawn = tuple(s.draw(rng) for s in gstrategies)
-                    fn(*args, *drawn, **kwargs)
-            # pytest resolves fixtures through __wrapped__'s signature; the
-            # drawn parameters must not look like fixtures
-            del runner.__wrapped__
-            return runner
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = strategies
-    mod.__shim__ = True
-    return mod, strategies
-
-
-try:  # pragma: no cover - exercised implicitly by collection
-    import hypothesis  # noqa: F401
-except ImportError:
-    _mod, _strategies = _build_hypothesis_shim()
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _strategies

@@ -268,7 +268,7 @@ def test_kernel_check_flags_vmem_budget_overrun():
 def test_kernel_check_flags_f64():
     import jax
     import jax.numpy as jnp
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jax.ShapeDtypeStruct((8,), jnp.float32))
     fs = kernel_check.check_jaxpr("leaky.f64", "x.py", jaxpr.jaxpr)

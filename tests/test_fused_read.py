@@ -78,6 +78,110 @@ def test_fused_scan_interpret_matches_ref(lb_fraction):
     np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
 
 
+def _churned_snapshot(seed):
+    """A snapshot with multi-level interior nodes, leaf logs, deletes and
+    MVCC old versions still on the heap, plus the read versions seen
+    along the way (older read versions make newer nodes and log entries
+    invisible, so the kernel's version walk and log filter both run)."""
+    rng = np.random.default_rng(seed)
+    s = StoreShard(dataclasses.replace(SMALL, sync_policy="explicit"),
+                   heap_capacity=512)
+    rvs = []
+    for rnd in range(4):
+        for k in rng.integers(0, 300, 90):
+            k = int(k)
+            if rng.random() < 0.2:
+                s.delete(int_key(k))
+            else:
+                s.put(int_key(k), b"r%d_%05d" % (rnd, k))
+        s.export_snapshot()
+        rvs.append(s.serving_version)
+    return s._snapshot, rvs
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_fused_interpret_matches_ref_under_churn(seed):
+    """Interpret ≡ ref on a churned tree, at the current and at older read
+    versions, for a batch that is not a multiple of the 8-request block:
+    GETs of present and absent keys, SCANs from empty to truncated."""
+    cfg = SMALL
+    snap, rvs = _churned_snapshot(seed)
+    rng = np.random.default_rng(100 + seed)
+    keys = [int_key(int(k)) for k in rng.integers(0, 320, 13)]
+    los = rng.integers(0, 300, 11)
+    spans = rng.choice([0, 3, 40, 400], 11)
+    lo = _packed([int_key(int(a)) for a in los], cfg)
+    hi = _packed([int_key(int(a + b)) for a, b in zip(los, spans)], cfg)
+    for rv in (rvs[-1], rvs[1]):
+        sn = snap._replace(read_version=jnp.int32(rv))
+        for lb in (0.0, 0.5):
+            want, wm = ops.batched_get_fused(sn, *_packed(keys, cfg),
+                                             cfg=cfg, lb_fraction=lb,
+                                             backend="ref")
+            got, gm = ops.batched_get_fused(sn, *_packed(keys, cfg),
+                                            cfg=cfg, lb_fraction=lb,
+                                            backend="interpret")
+            for f in want._fields:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(want, f)),
+                    np.asarray(getattr(got, f)), err_msg=f)
+            np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+            want, wm = ops.batched_scan_fused(sn, *lo, *hi, cfg=cfg,
+                                              lb_fraction=lb, backend="ref")
+            got, gm = ops.batched_scan_fused(sn, *lo, *hi, cfg=cfg,
+                                             lb_fraction=lb,
+                                             backend="interpret")
+            for f in want._fields:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(want, f)),
+                    np.asarray(getattr(got, f)), err_msg=f)
+            np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+    assert bool(np.asarray(want.truncated).any())     # budgets exercised
+    assert int(np.asarray(wm)[1]) > 0                 # heap pipe exercised
+
+
+@pytest.mark.parametrize("tree", ["loaded", "churned"])
+@pytest.mark.parametrize("op", ["get", "scan"])
+def test_fused_tpu_interpreter_matches_ref(op, tree):
+    """The TPU interpreter models DMAs, memory spaces and semaphores, fills
+    scratch with garbage and raises on out-of-bounds reads — what a compiled
+    kernel would read on the chip and the plain interpreter forgives.  Both
+    pipes (lb 0.5), on a freshly loaded tree and on a churned tree at an
+    older read version."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import fused_read, ref
+    cfg = SMALL
+    if tree == "loaded":
+        sn = _loaded_shard(cfg).export_snapshot()
+    else:
+        snap, rvs = _churned_snapshot(0)
+        sn = snap._replace(read_version=jnp.int32(rvs[1]))
+    args = (sn.image, sn.pagetable, sn.root_lid, sn.read_version,
+            sn.cache_lids, sn.cache_image)
+    rng = np.random.default_rng(100)
+    los = rng.integers(0, 300, 11)
+    lo = _packed([int_key(int(a)) for a in los], cfg)
+    if op == "get":
+        want, wm = ref.batched_get_fused_ref(sn, *lo, cfg=cfg,
+                                             lb_fraction=0.5)
+        got, gm = fused_read.batched_get_fused(
+            *args, *lo, cfg=cfg, lb_fraction=0.5,
+            interpret=pltpu.InterpretParams())
+    else:
+        spans = rng.choice([0, 3, 40, 400], 11)
+        hi = _packed([int_key(int(a + b)) for a, b in zip(los, spans)], cfg)
+        want, wm = ref.batched_scan_fused_ref(sn, *lo, *hi, cfg=cfg,
+                                              lb_fraction=0.5)
+        got, gm = fused_read.batched_scan_fused(
+            *args, *lo, *hi, cfg=cfg, lb_fraction=0.5,
+            interpret=pltpu.InterpretParams())
+    for f in want._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(want, f)),
+                                      np.asarray(getattr(got, f)), err_msg=f)
+    np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+    assert int(np.asarray(wm)[0]) > 0 and int(np.asarray(wm)[1]) > 0
+
+
 # -------------------------------------- fused ≡ reference, full service
 @pytest.mark.parametrize("shards", [1, 3])
 @pytest.mark.parametrize("replicas", [1, 2])

@@ -41,22 +41,25 @@ GOLDEN_DEFAULT_OFFSETS = {
 
 
 def test_golden_offsets_pinned():
-    """The default-config image layout is pinned word for word — 1273 words
-    (5092 B, the reproduction's analogue of the paper's 8 KB node)."""
+    """The default-config image layout is pinned word for word — 1273 field
+    words (5092 B, the reproduction's analogue of the paper's 8 KB node),
+    in a device row padded to 1280 words (the TPU's 128-lane tile)."""
     layout = NodeImageLayout.for_config(DEFAULT_CONFIG)
     assert layout.offsets() == GOLDEN_DEFAULT_OFFSETS
-    assert layout.image_words == 1273
+    assert layout.field_words == 1273
+    assert layout.image_words == 1280
     assert layout.node_image_bytes == 5092
-    # fields tile the row exactly: in schema order, no padding
+    # fields tile the row's head exactly: in schema order, no gaps
     assert list(layout.offsets()) == list(FIELD_NAMES)
     off = 0
     for name in FIELD_NAMES:
         o, w = layout.offsets()[name]
         assert o == off, name
         off += w
-    assert off == layout.image_words
+    assert off == layout.field_words
     # the test geometry used across the suite
-    assert NodeImageLayout.for_config(SMALL).image_words == 345
+    assert NodeImageLayout.for_config(SMALL).field_words == 345
+    assert NodeImageLayout.for_config(SMALL).image_words == 384
 
 
 def test_field_lists_derive_from_schema():

@@ -102,6 +102,34 @@ def test_honeycomb_vs_cpu_baseline_agree():
     assert hc.scan_batch(ranges) == cp.scan_batch(ranges)
 
 
+def test_cpu_baseline_leaf_minimums_track_writes():
+    """The baseline's leaf-minimum index stays in step through splits,
+    new minimums and leaves emptied by deletes: every GET and SCAN matches
+    a sorted-list oracle."""
+    import bisect
+    from repro.baselines.cpu_store import CpuOrderedStore
+    cp = CpuOrderedStore(node_cap=4)
+    oracle = {}
+    rng = np.random.default_rng(5)
+    for i in rng.permutation(120):
+        cp.put(int_key(int(i)), b"v%d" % i)
+        oracle[int_key(int(i))] = b"v%d" % i
+    for i in list(range(0, 40)) + list(range(70, 75)):   # empty leaves
+        cp.delete(int_key(i))
+        oracle.pop(int_key(i))
+    cp.put(int_key(0), b"low")                          # new global min
+    oracle[int_key(0)] = b"low"
+    assert cp._mins == [lf.keys[0] for lf in cp.leaves]
+    for i in range(125):
+        assert cp.get(int_key(i)) == oracle.get(int_key(i))
+    keys = sorted(oracle)
+    for lo, hi in [(0, 5), (39, 45), (68, 80), (100, 200)]:
+        a, b = int_key(lo), int_key(hi)
+        start = max(bisect.bisect_right(keys, a) - 1, 0)
+        want = [(k, oracle[k]) for k in keys[start:] if k <= b]
+        assert cp.scan(a, b) == want
+
+
 def test_variable_length_keys_end_to_end():
     """The paper's headline feature: variable-size keys and values, inline,
     with lexicographic order — through writes, merges, splits and the
