@@ -240,8 +240,8 @@ def run_scheduled(store, sampler, *, n_ops: int, read_frac: float,
         "dispatch_s": st.dispatch_s, "lane_occupancy": st.lane_occupancy,
         "sync": {k: end[k] - start_sync[k] for k in _SYNC_DIFF_KEYS},
         # the registry view of the same run — counters/gauges from every
-        # wired stats surface plus the latency-histogram quantiles (the
-        # run.py --metrics table reads THIS, not hand-picked fields)
+        # wired stats surface (the run.py --metrics table reads THIS, not
+        # hand-picked fields)
         "metrics": svc.metrics_snapshot(),
     }
 

@@ -27,7 +27,7 @@ table of every section's sync meters (log entries, wire bytes, sync bytes,
 replica amplification) prints after the sweep; ``--metrics`` adds a second
 table sourced from the telemetry REGISTRY snapshots the scheduled sections
 attach (core/telemetry.py — device-cache hit rate, image-DMA counts, sync
-stall fraction, GET latency p50/p99), raises the per-request trace sample
+stall fraction, lane occupancy), raises the per-request trace sample
 rate, and writes ``experiments/metrics_snapshot.json`` plus a
 Perfetto-loadable ``experiments/bench_trace.json`` next to the results.
 
@@ -120,19 +120,11 @@ def _mval(metrics: dict, name: str, **labels) -> float:
     return tot
 
 
-def _mhist(metrics: dict, name: str) -> dict:
-    """First histogram sample named ``name`` (its quantile dict)."""
-    for k, v in metrics.items():
-        if k.partition("{")[0] == name and isinstance(v, dict):
-            return v
-    return {}
-
-
 def print_metrics_summary(results: dict) -> None:
     """One table per --metrics run sourced from the REGISTRY snapshots the
     scheduled sections attach (core/telemetry.py; not hand-picked stats
-    fields): device-cache hit rate, image-DMA count, the scheduler's sync
-    stall fraction and lane occupancy, and the GET latency p50/p99."""
+    fields): device-cache hit rate, image-DMA count, and the scheduler's
+    sync stall fraction and lane occupancy."""
     rows = []
     for section, recs in results.items():
         if not isinstance(recs, dict):
@@ -141,7 +133,6 @@ def print_metrics_summary(results: dict) -> None:
             m = rec.get("metrics") if isinstance(rec, dict) else None
             if not m:
                 continue
-            g = _mhist(m, "read_get_latency_seconds")
             rows.append((f"{section}/{key}",
                          _mval(m, "cache_device_hit_rate"),
                          int(_mval(m, "sync_image_dma_count",
@@ -149,16 +140,15 @@ def print_metrics_summary(results: dict) -> None:
                          _mval(m, "pipeline_stall_fraction",
                                src="scheduler"),
                          _mval(m, "pipeline_lane_occupancy",
-                               src="scheduler"),
-                         g.get("p50", 0.0) * 1e6, g.get("p99", 0.0) * 1e6))
+                               src="scheduler")))
     if not rows:
         return
     print("# --- registry metrics summary ---")
     print(f"# {'run':<44} {'dev_hit':>7} {'img_dmas':>8} {'stall_fr':>8} "
-          f"{'lane_occ':>8} {'get_p50us':>10} {'get_p99us':>10}")
-    for name, hit, dmas, stall, occ, p50, p99 in rows:
+          f"{'lane_occ':>8}")
+    for name, hit, dmas, stall, occ in rows:
         print(f"# {name:<44} {hit:>7.3f} {dmas:>8} {stall:>8.3f} "
-              f"{occ:>8.3f} {p50:>10.1f} {p99:>10.1f}")
+              f"{occ:>8.3f}")
 
 
 def main() -> None:
@@ -195,8 +185,8 @@ def main() -> None:
                          "layout-aware sections (e.g. packed,legacy)")
     ap.add_argument("--metrics", action="store_true",
                     help="print a registry metrics summary table (hit "
-                         "rates, DMA counts, stall fraction, read "
-                         "p50/p99) after the sweep, raise the trace "
+                         "rates, DMA counts, stall fraction, lane "
+                         "occupancy) after the sweep, raise the trace "
                          "sample rate, and write the last section's "
                          "metrics snapshot + a Perfetto trace next to "
                          "bench_results.json")

@@ -103,9 +103,11 @@ for label, smoke in (("sharded", sh), ("replicated", rp)):
     pv = parse_prometheus(tele["prometheus"])
     for meter in ("hc_sync_bytes_synced", "hc_sync_image_dma_count",
                   "hc_tree_puts", "hc_cache_vmem_hits",
-                  "hc_pipeline_flips", "hc_read_batches",
-                  "hc_read_get_latency_seconds_count"):
+                  "hc_pipeline_flips", "hc_read_batches"):
         assert prom_value(pv, meter) > 0, (label, meter, tele["prometheus"])
+    # the read-dispatch split: host time blocked on the device's answers
+    assert prom_value(pv, "hc_pipeline_fetch_s", src="store") > 0, \
+        (label, tele["prometheus"])
     assert tele["sampled_traces"] > 0, (label, tele)
 assert prom_value(parse_prometheus(rp["telemetry"]["prometheus"]),
                   "hc_replication_log_feed_epochs") > 0, rp["telemetry"]

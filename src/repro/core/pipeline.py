@@ -76,13 +76,16 @@ Meters
 ======
 
 ``PipelineStats`` carries per-stage wall time and occupancy:
-``sync_stall_s`` (host time blocked on sync completion before the first
-read dispatch — the quantity pipelining exists to remove),
-``admit_s``/``export_s``/``dispatch_s`` stage timings, flip/stage counts,
-and device-lane occupancy (real requests vs ``bucket_pow2``-padded lanes).
-Shards meter their staging/flip side, the router aggregates them, and the
-scheduler meters the stage loop; benchmarks report both
-(``benchmarks/ycsb.py --pipeline``, ``benchmarks/latency.py``).
+``sync_stall_s`` (host time in the sync barrier — serial mode's
+``block_until_ready``, pipelined mode's flip — the quantity pipelining
+exists to remove), ``admit_s``/``export_s``/``dispatch_s`` stage timings,
+the read-dispatch split ``pack_s``/``fetch_s``/``decode_s``, flip/stage
+counts, and device-lane occupancy (real requests vs ``bucket_pow2``-padded
+lanes).  Shards meter their staging/flip side and the read split, the
+router aggregates them, and the scheduler meters the stage loop;
+benchmarks report both (``benchmarks/ycsb.py --pipeline``,
+``benchmarks/latency.py``).  Every stage time is also a program span on
+the profiler's clock (``telemetry.span``, named ``hc.<stage>``).
 """
 from __future__ import annotations
 
@@ -98,8 +101,14 @@ class PipelineStats:
     admit_s: float = 0.0        # host write-apply stage wall time
     export_s: float = 0.0       # standby staging wall time (host side)
     dispatch_s: float = 0.0     # read-batch dispatch stage wall time
-    sync_stall_s: float = 0.0   # time blocked on sync completion before
-    #   any read of the epoch could dispatch (serial barrier; ~0 pipelined)
+    sync_stall_s: float = 0.0   # sync barrier wall time before any read
+    #   of the epoch could dispatch (serial block_until_ready; the
+    #   pipelined flip, ~0)
+    pack_s: float = 0.0         # read batches: padding, key packing and
+    #   lane uploads (shard)
+    fetch_s: float = 0.0        # read batches: device->host copies of the
+    #   results and meters, the wait for the device included (shard)
+    decode_s: float = 0.0       # read batches: decoding results (shard)
     staged_exports: int = 0     # begin_export calls that staged a standby
     flips: int = 0              # epoch publishes
     dispatched_lanes: int = 0   # real requests inside device batches

@@ -43,7 +43,7 @@ in core/pipeline.py):
     facade ``export_snapshot()`` covering every dirty shard, then reads.
     The blocking PCIe barrier the serial design implies is modeled with
     ``jax.block_until_ready`` on the synced snapshots and metered as
-    ``stats.sync_stall_s``.
+    ``stats.sync_stall_s`` (the barrier alone, span ``hc.sync.barrier``).
   * ``"pipelined"`` — double-buffered epochs: every dirty shard's delta is
     STAGED into its standby buffer (asynchronous scatter enqueue), each
     shard flips independently, and read batches dispatch immediately —
@@ -68,7 +68,7 @@ import jax
 from .api import (NOT_FOUND, OK, OPS_BY_KIND, WRITE_KINDS, Op, Response,
                   Routing, Scan)
 from .pipeline import PIPELINE_MODES, PipelineStats
-from .telemetry import CLOCK
+from .telemetry import CLOCK, span
 from ..analysis import epochsan as _epochsan
 
 _now = CLOCK            # THE injectable monotonic clock (core/telemetry.py)
@@ -123,27 +123,20 @@ class OutOfOrderScheduler:
         self.pipeline = pipeline
         self.stats = PipelineStats()
         # observability (core/telemetry.py): when wired, the scheduler
-        # registers its stage meters, records per-request device-latency
-        # histograms at dispatch, and drives the sampled lifecycle tracer
-        # (submit -> admit -> export_stage -> flip -> dispatch -> resolve).
-        # telemetry=None (or disabled) leaves only `is None` branches on
-        # the hot path — behaviour is byte-identical to pre-telemetry.
+        # registers its stage meters and drives the sampled lifecycle
+        # tracer (submit -> admit -> export_stage -> flip -> dispatch ->
+        # resolve).  telemetry=None (or disabled) leaves only `is None`
+        # branches on the hot path.  The stage spans (hc.admit, hc.export,
+        # hc.sync.barrier, hc.dispatch) run either way.
         self.telemetry = (telemetry if telemetry is not None
                           and telemetry.enabled else None)
         self._tracer = (self.telemetry.tracer
                         if self.telemetry is not None else None)
         if self.telemetry is not None:
             self.telemetry.wire_scheduler(self)
-            self._lat_hist = {
-                "get": self.telemetry.histogram("read_get_latency_seconds",
-                                                layer="scheduler"),
-                "scan": self.telemetry.histogram("read_scan_latency_seconds",
-                                                 layer="scheduler"),
-            }
             self._req_hist = self.telemetry.histogram(
                 "request_latency_seconds", layer="scheduler")
         else:
-            self._lat_hist = None
             self._req_hist = None
         # store-provided wiring (store.routing() — core/api.py): key ->
         # owning shard, the replica read-spreading pick, and the response
@@ -227,25 +220,25 @@ class OutOfOrderScheduler:
         sync in between (that is the whole point) — each shard's own
         "every_k" policy is deferred for the duration of the burst.  Write
         responses are stamped with the host-tree version at which the
-        write became visible."""
-        t0 = _now()
+        write became visible.  Span ``hc.admit`` (``stats.admit_s``)."""
         out: dict[int, Response] = {}
         rt = self._resolve_routing(store) if self._writes else None
         tr = self._tracer
-        with store.deferred_sync():
-            for r in self._writes:
-                if tr is not None and tr.is_live(r.rid):
-                    a0 = _now()
-                    r.op.apply(store)
-                    tr.span(r.rid, "admit", a0, _now(), shard=r.shard)
-                else:
-                    r.op.apply(store)
-                out[r.rid] = Response(
-                    status=OK, shard=r.shard,
-                    serving_version=(rt.live_version(r.shard) if rt else 0))
-        self.applied_writes += len(self._writes)
-        self._writes.clear()
-        self.stats.admit_s += _now() - t0
+        with span("admit", self.stats, "admit_s"):
+            with store.deferred_sync():
+                for r in self._writes:
+                    if tr is not None and tr.is_live(r.rid):
+                        a0 = _now()
+                        r.op.apply(store)
+                        tr.span(r.rid, "admit", a0, _now(), shard=r.shard)
+                    else:
+                        r.op.apply(store)
+                    out[r.rid] = Response(
+                        status=OK, shard=r.shard,
+                        serving_version=(rt.live_version(r.shard)
+                                         if rt else 0))
+            self.applied_writes += len(self._writes)
+            self._writes.clear()
         return out
 
     def stage_export(self, store) -> None:
@@ -256,26 +249,27 @@ class OutOfOrderScheduler:
         Serial mode exports and publishes through the facade's
         ``export_snapshot()`` and then BLOCKS until the scatters complete
         (the modeled sync barrier: reads may not be issued until the DMA is
-        done); the wait is metered as ``sync_stall_s``.  Pipelined mode
-        stages every dirty shard's standby buffer — the scatters are only
-        ENQUEUED, and a replicated shard's group hook enqueues one scatter
-        per replica lane CONCURRENTLY before any flip — then flips each
-        shard independently; read batches dispatch while the scatters
-        drain, so the only stall is host staging time."""
+        done).  Pipelined mode stages every dirty shard's standby buffer —
+        the scatters are only ENQUEUED, and a replicated shard's group hook
+        enqueues one scatter per replica lane CONCURRENTLY before any flip
+        — then flips each shard independently; read batches dispatch while
+        the scatters drain, so the only stall is host staging time.
+
+        Span ``hc.export`` covers the whole stage (``stats.export_s``);
+        span ``hc.sync.barrier`` covers the serial ``block_until_ready``
+        or the pipelined flip alone (``stats.sync_stall_s``)."""
         before = store.sync_stats.snapshots
-        t0 = _now()
-        if self.pipeline == "serial":
-            snaps = store.export_snapshot()
-            t_mid = _now()
-            jax.block_until_ready(snaps)
-        else:
-            store.begin_export()
-            t_mid = _now()
-            store.flip()
-        t1 = _now()
-        dt = t1 - t0
-        self.stats.sync_stall_s += dt   # no reads dispatched yet this epoch
-        self.stats.export_s += dt
+        with span("export", self.stats, "export_s") as stage:
+            if self.pipeline == "serial":
+                snaps = store.export_snapshot()
+                with span("sync.barrier", self.stats,
+                          "sync_stall_s") as barrier:
+                    jax.block_until_ready(snaps)
+            else:
+                store.begin_export()
+                with span("sync.barrier", self.stats,
+                          "sync_stall_s") as barrier:
+                    store.flip()
         self.syncs += store.sync_stats.snapshots - before
         if self._tracer is not None and self._tracer.live_count:
             # the export covers the whole epoch, so attach both stage
@@ -283,8 +277,8 @@ class OutOfOrderScheduler:
             # the staging+publish, flip the modeled blocking barrier
             # (block_until_ready); pipelined: export_stage stages the
             # standby, flip is the atomic per-shard publish.
-            self._tracer.span_all("export_stage", t0, t_mid)
-            self._tracer.span_all("flip", t_mid, t1)
+            self._tracer.span_all("export_stage", stage.t0, barrier.t0)
+            self._tracer.span_all("flip", barrier.t0, barrier.t1)
         san = _epochsan.get()
         if san is not None:   # stage_export's contract: staged => flipped
             san.check_exported(store)
@@ -299,13 +293,18 @@ class OutOfOrderScheduler:
         occupancy is accumulated from the STORE's meters (the shard is
         where ``bucket_pow2`` padding actually happens, including the
         router's per-shard sub-batches and floor back-fill probes), so it
-        reflects real device lanes, not the scheduler-level batch sizes."""
-        t0 = _now()
+        reflects real device lanes, not the scheduler-level batch sizes.
+        Span ``hc.dispatch`` (``stats.dispatch_s``); the shard's
+        ``hc.read.*`` spans split it."""
+        with span("dispatch", self.stats, "dispatch_s"):
+            return self._dispatch(store, flush)
+
+    def _dispatch(self, store, flush: bool) -> dict[int, Response]:
         ps = store.pipeline_stats
         lanes0, padded0 = ps.dispatched_lanes, ps.padded_lanes
         rt = self._resolve_routing(store)
         out: dict[int, Response] = {}
-        tm, tr = self.telemetry, self._tracer
+        tr = self._tracer
         for kind, batch in self.ready_batches(flush=flush):
             self.dispatched_batches += 1
             self.dispatched_requests += len(batch)
@@ -314,24 +313,19 @@ class OutOfOrderScheduler:
             # read-spreading policy is wired (plain stores take no replica)
             kw = ({"replica": batch[0].replica}
                   if self._replica_of is not None else {})
-            b0 = _now() if tm is not None else 0.0
+            b0 = _now() if tr is not None else 0.0
             if kind == "get":
                 res = store.get_batch([r.key for r in batch], **kw)
             else:
                 res = store.scan_batch([(r.key, r.hi) for r in batch], **kw)
             served, rv = (rt.report(shard) if rt is not None
                           else (batch[0].replica, 0))
-            if tm is not None:
+            if tr is not None and tr.live_count:
                 b1 = _now()
-                # spread the batch's device time over its requests: one
-                # weighted record per batch keeps the histogram O(1)
-                self._lat_hist[kind].record((b1 - b0) / len(batch),
-                                            n=len(batch))
-                if tr is not None and tr.live_count:
-                    for r in batch:
-                        if tr.is_live(r.rid):
-                            tr.span(r.rid, "dispatch", b0, b1, shard=shard,
-                                    replica=served, serving_version=rv)
+                for r in batch:
+                    if tr.is_live(r.rid):
+                        tr.span(r.rid, "dispatch", b0, b1, shard=shard,
+                                replica=served, serving_version=rv)
             for r, v in zip(batch, res):
                 if kind == "get":
                     out[r.rid] = Response(
@@ -345,7 +339,6 @@ class OutOfOrderScheduler:
         ps = store.pipeline_stats
         self.stats.dispatched_lanes += ps.dispatched_lanes - lanes0
         self.stats.padded_lanes += ps.padded_lanes - padded0
-        self.stats.dispatch_s += _now() - t0
         return out
 
     # ---------------------------------------------------------- the epoch

@@ -60,7 +60,7 @@ from .read_path import (NODE_FIELDS, GetResult, LegacySnapshotDelta,
                         TreeSnapshot, apply_snapshot_delta,
                         attach_cache_image, batched_get, batched_scan)
 from .schema import NARROWED_FIELDS, NodeImageLayout
-from .telemetry import CLOCK, samples_from
+from .telemetry import CLOCK, samples_from, span
 from repro.kernels import ops as kernel_ops
 # EpochSan seams (repro/analysis/epochsan.py): get() is None unless the
 # sanitizer is enabled, so each hook costs one call + None test
@@ -375,13 +375,12 @@ class StoreShard:
         # the interior-cache update rides along with the sync DMA: refresh
         # BEFORE publishing so the staged snapshot carries the epoch's cache
         # frontier (cache_lids) and its VMEM tier mirrors the standby
-        self.cache.refresh(t)
+        with span("sync.refresh"):
+            self.cache.refresh(t)
         bytes0 = stats.bytes_synced
         dmas0, ibytes0 = stats.image_dma_count, stats.image_bytes
         if can_delta:
-            snap = self._publish_delta(base,
-                                       np.fromiter(sorted(dirty), np.int32,
-                                                   len(dirty)))
+            snap = self._publish_delta(base, dirty)
             stats.delta_syncs += 1
             stats.delta_rows += len(dirty)
             stats.delta_fraction = frac
@@ -498,12 +497,19 @@ class StoreShard:
     def _publish_full(self):
         """Wholesale republish: the whole store crosses the bus — ONE
         contiguous [S, image_words] image DMA on the packed layout, one
-        array per field on legacy (same bytes, ~24x the DMA invocations)."""
+        array per field on legacy (same bytes, ~24x the DMA invocations).
+        Spans ``hc.sync.pack`` (host images), ``hc.sync.put`` (uploads),
+        ``hc.sync.launch`` (the cache-tier program)."""
         t = self.tree
         h = t.heap
-        pt_image = t.pt.flush_to_device()
         stats = self.sync_stats
         layout = NodeImageLayout.for_config(self.cfg)
+        packed = self.cfg.layout == "packed"
+        with span("sync.pack"):
+            pt_image = t.pt.flush_to_device()
+            # pack() marshals every field into contiguous node images —
+            # the whole publish is one image transfer (plus the page table)
+            img = layout.pack(h) if packed else None
         stats.image_bytes += h.capacity * layout.node_image_bytes
 
         def dev(a, dtype=None):
@@ -516,90 +522,102 @@ class StoreShard:
             stats.bytes_synced += arr.nbytes
             return jnp.asarray(arr)
 
-        if self.cfg.layout == "packed":
-            # pack() marshals every field into contiguous node images — the
-            # whole publish is one image transfer (plus the page table)
-            img = layout.pack(h)
+        if packed:
             stats.bytes_synced += h.capacity * layout.node_image_bytes
             stats.image_dma_count += 1
-            snap = TreeSnapshot(
-                image=jnp.asarray(img),
+            with span("sync.put"):
+                snap = TreeSnapshot(
+                    image=jnp.asarray(img),
+                    pagetable=dev(pt_image),
+                    root_lid=jnp.int32(t.root_lid),
+                    read_version=jnp.int32(t.versions.read_version()),
+                    cache_lids=jnp.asarray(self.cache.device_lids()))
+            # materialize the VMEM cache tier device-side from the image
+            # just shipped — only the ~KB LID vector crossed the bus
+            with span("sync.launch"):
+                return attach_cache_image(snap, self.cfg)
+        stats.image_dma_count += len(NODE_FIELDS)
+        with span("sync.put"):
+            fields = {f: dev(getattr(h, f),
+                             np.int32 if f in _I32_FIELDS else None)
+                      for f in NODE_FIELDS}
+            return LegacyTreeSnapshot(
                 pagetable=dev(pt_image),
                 root_lid=jnp.int32(t.root_lid),
                 read_version=jnp.int32(t.versions.read_version()),
-                cache_lids=jnp.asarray(self.cache.device_lids()))
-            # materialize the VMEM cache tier device-side from the image
-            # just shipped — only the ~KB LID vector crossed the bus
-            return attach_cache_image(snap, self.cfg)
-        stats.image_dma_count += len(NODE_FIELDS)
-        fields = {f: dev(getattr(h, f),
-                         np.int32 if f in _I32_FIELDS else None)
-                  for f in NODE_FIELDS}
-        return LegacyTreeSnapshot(
-            pagetable=dev(pt_image),
-            root_lid=jnp.int32(t.root_lid),
-            read_version=jnp.int32(t.versions.read_version()),
-            **fields)
+                **fields)
 
-    def _publish_delta(self, base, rows: np.ndarray):
-        """Incremental sync: scatter dirty node rows and pending page-table
-        commands over ``base`` (the standby-in-progress, or the active
-        snapshot when none is staged).  Transfers (and meters) O(dirty)
-        bytes instead of O(store); the host-side gathers below copy out of
-        the heap eagerly, so later host mutations/GC wipes can never reach
-        a staged standby.
+    def _publish_delta(self, base, dirty: set[int]):
+        """Incremental sync: scatter the ``dirty`` node rows and pending
+        page-table commands over ``base`` (the standby-in-progress, or the
+        active snapshot when none is staged).  Transfers (and meters)
+        O(dirty) bytes instead of O(store); the host-side gathers below
+        copy out of the heap eagerly, so later host mutations/GC wipes can
+        never reach a staged standby.
 
         Packed layout: each dirty node is marshalled into ONE contiguous
         image row and issued as a single DMA (``image_dma_count`` grows by
-        exactly len(rows) — the acceptance invariant); legacy ships the
-        same bytes as one row block per field (~24 DMAs per node)."""
+        exactly len(dirty) — the acceptance invariant); legacy ships the
+        same bytes as one row block per field (~24 DMAs per node).  Spans
+        ``hc.sync.pack`` (sort, padding, host images), ``hc.sync.put``
+        (uploads), ``hc.sync.launch`` (the scatter program)."""
         t = self.tree
         h = t.heap
         stats = self.sync_stats
         layout = NodeImageLayout.for_config(self.cfg)
-        pt_lids, pt_phys = t.pt.take_pending()
-        # pending LID moves mean the tree shape changed under this epoch —
-        # a log replay cannot reproduce them, so the feed must fall back
-        self._staged_pt_cmds = len(pt_lids)
-        # pad to bucketed sizes with idempotent repeats (duplicate indices
-        # carry identical data); when empty, row/lid 0 rewrites itself with
-        # its current contents (clean rows match the device image)
-        rows_p = self._pad_index(rows, bucket_pow2(len(rows)))
-        lids_p = self._pad_index(pt_lids, bucket_pow2(len(pt_lids)))
-        phys_p = t.pt.device_image[lids_p]
+        packed = self.cfg.layout == "packed"
+        with span("sync.pack"):
+            rows = np.fromiter(sorted(dirty), np.int32, len(dirty))
+            pt_lids, pt_phys = t.pt.take_pending()
+            # pending LID moves mean the tree shape changed under this
+            # epoch — a log replay cannot reproduce them, so the feed must
+            # fall back
+            self._staged_pt_cmds = len(pt_lids)
+            # pad to bucketed sizes with idempotent repeats (duplicate
+            # indices carry identical data); when empty, row/lid 0 rewrites
+            # itself with its current contents (clean rows match the device
+            # image)
+            rows_p = self._pad_index(rows, bucket_pow2(len(rows)))
+            lids_p = self._pad_index(pt_lids, bucket_pow2(len(pt_lids)))
+            phys_p = t.pt.device_image[lids_p]
+            if packed:
+                img = layout.pack(h, rows_p)
+                cache_lids = self.cache.device_lids()
+            else:
+                host_fields = {}
+                for f in NODE_FIELDS:
+                    arr = getattr(h, f)[rows_p]
+                    if f in _I32_FIELDS:
+                        arr = arr.astype(np.int32)
+                    host_fields[f] = arr
         # both layouts move image_words * 4 bytes per UNPADDED dirty node
         # (every device field is one u32 word per element); the accounting
         # is identical by construction — only the DMA count differs
         node_bytes = len(rows) * layout.node_image_bytes
         nbytes = pt_lids.nbytes + pt_phys.nbytes + node_bytes
         stats.image_bytes += node_bytes
-        if self.cfg.layout == "packed":
-            stats.image_dma_count += len(rows)       # ONE DMA per dirty node
-            delta = SnapshotDelta(
-                rows=jnp.asarray(rows_p),
-                image=jnp.asarray(layout.pack(h, rows_p)),
-                pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
-                root_lid=jnp.int32(t.root_lid),
-                read_version=jnp.int32(t.versions.read_version()),
-                cache_lids=jnp.asarray(self.cache.device_lids()))
-        else:
-            stats.image_dma_count += len(rows) * len(NODE_FIELDS)
-            fields = {}
-            for f in NODE_FIELDS:
-                arr = getattr(h, f)[rows_p]
-                if f in _I32_FIELDS:
-                    arr = arr.astype(np.int32)
-                fields[f] = jnp.asarray(arr)
-            delta = LegacySnapshotDelta(
-                rows=jnp.asarray(rows_p),
-                pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
-                root_lid=jnp.int32(t.root_lid),
-                read_version=jnp.int32(t.versions.read_version()),
-                **fields)
+        with span("sync.put"):
+            if packed:
+                stats.image_dma_count += len(rows)   # ONE DMA per dirty node
+                delta = SnapshotDelta(
+                    rows=jnp.asarray(rows_p), image=jnp.asarray(img),
+                    pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
+                    root_lid=jnp.int32(t.root_lid),
+                    read_version=jnp.int32(t.versions.read_version()),
+                    cache_lids=jnp.asarray(cache_lids))
+            else:
+                stats.image_dma_count += len(rows) * len(NODE_FIELDS)
+                delta = LegacySnapshotDelta(
+                    rows=jnp.asarray(rows_p),
+                    pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
+                    root_lid=jnp.int32(t.root_lid),
+                    read_version=jnp.int32(t.versions.read_version()),
+                    **{f: jnp.asarray(a) for f, a in host_fields.items()})
         stats.bytes_synced += nbytes
         self._staged_delta = delta   # replayable by follower replicas
-        return _jit_apply_delta(base, delta, backend=sync_backend(),
-                                cfg=self.cfg)
+        with span("sync.launch"):
+            return _jit_apply_delta(base, delta, backend=sync_backend(),
+                                    cfg=self.cfg)
 
     @staticmethod
     def _pad_index(idx: np.ndarray, size: int) -> np.ndarray:
@@ -625,7 +643,8 @@ class StoreShard:
 
     def _note_read_meters(self, meters):
         """Fold one fused dispatch's device meters into CacheStats (the
-        dispatching shard accounts follower-served batches too)."""
+        dispatching shard accounts follower-served batches too); the copy
+        back is part of the caller's ``hc.read.fetch`` span."""
         m = np.asarray(meters)
         s = self.cache.stats
         s.vmem_hits += int(m[0])
@@ -661,41 +680,44 @@ class StoreShard:
                     keys: list[bytes]) -> list[bytes | None]:
         """Execute one dense GET batch against ``snap`` — the active
         snapshot, or a follower replica's device image (core/replica.py
-        serves followers through the primary's dispatch machinery)."""
+        serves followers through the primary's dispatch machinery).
+        Spans ``hc.read.pack`` / ``launch`` / ``fetch`` / ``decode``."""
         san = _epochsan.get()
         if san is not None:   # reads may never see an unflipped standby
             san.check_read(self, snap)
-        # pad ragged batches (router sub-batches) to power-of-two buckets so
-        # each (cfg, shapes) compiles once per bucket, not per length
-        padded = keys + [keys[0]] * (bucket_pow2(len(keys)) - len(keys))
-        self.pipeline_stats.dispatched_lanes += len(keys)
-        self.pipeline_stats.padded_lanes += len(padded)
-        lanes, lens = pack_keys(padded, self.cfg.key_words)
+        ps = self.pipeline_stats
+        with span("read.pack", ps, "pack_s"):
+            # pad ragged batches (router sub-batches) to power-of-two
+            # buckets so each (cfg, shapes) compiles once per bucket, not
+            # per length
+            padded = keys + [keys[0]] * (bucket_pow2(len(keys)) - len(keys))
+            ps.dispatched_lanes += len(keys)
+            ps.padded_lanes += len(padded)
+            lanes, lens = pack_keys(padded, self.cfg.key_words)
+            lanes, lens = jnp.asarray(lanes), jnp.asarray(lens)
         rb = self._read_backend_for(snap)
         kernel_ops.record_read_dispatch("get", rb, self.cfg)
         lo, hi = self.tree.epochs.accel_begin_batch(len(keys))
         try:
-            if rb == "fused":
-                res, meters = _jit_get_fused(
-                    snap, jnp.asarray(lanes), jnp.asarray(lens),
-                    cfg=self.cfg, lb_fraction=self.cfg.lb_fraction)
-                self._note_read_meters(meters)
-            else:
-                res = _jit_get(
-                    snap, jnp.asarray(lanes), jnp.asarray(lens),
-                    cfg=self.cfg)
-            found = np.asarray(res.found)
-            vals = np.asarray(res.vals)
-            vlens = np.asarray(res.vallens)
+            with span("read.launch"):
+                if rb == "fused":
+                    res, meters = _jit_get_fused(
+                        snap, lanes, lens, cfg=self.cfg,
+                        lb_fraction=self.cfg.lb_fraction)
+                else:
+                    res, meters = _jit_get(snap, lanes, lens,
+                                           cfg=self.cfg), None
+            with span("read.fetch", ps, "fetch_s"):
+                if meters is not None:
+                    self._note_read_meters(meters)
+                found = np.asarray(res.found)
+                vals = np.asarray(res.vals)
+                vlens = np.asarray(res.vallens)
         finally:
             self.tree.epochs.accel_complete_batch(lo, hi)
-        out: list[bytes | None] = []
-        for i in range(len(keys)):
-            if not found[i]:
-                out.append(None)
-            else:
-                out.append(self._decode_value(vals[i], int(vlens[i])))
-        return out
+        with span("read.decode", ps, "decode_s"):
+            return [self._decode_value(vals[i], int(vlens[i]))
+                    if found[i] else None for i in range(len(keys))]
 
     def scan_batch(self, ranges: Sequence[tuple[bytes, bytes]]
                    ) -> list[list[tuple[bytes, bytes]]]:
@@ -716,50 +738,63 @@ class StoreShard:
                      ) -> list[list[tuple[bytes, bytes]]]:
         """Execute one dense SCAN batch against ``snap`` (active snapshot or
         a follower replica's image); truncated requests fall back to the
-        host tree at ``fallback_rv``."""
+        host tree at ``fallback_rv``.  Spans ``hc.read.pack`` / ``launch``
+        / ``fetch`` / ``decode``, and ``hc.read.host_scan`` around the
+        batch's fallbacks."""
         san = _epochsan.get()
         if san is not None:   # reads may never see an unflipped standby
             san.check_read(self, snap)
-        pad = [ranges[0]] * (bucket_pow2(len(ranges)) - len(ranges))
-        padded = ranges + pad
-        self.pipeline_stats.dispatched_lanes += len(ranges)
-        self.pipeline_stats.padded_lanes += len(padded)
-        lo_l, lo_n = pack_keys([r[0] for r in padded], self.cfg.key_words)
-        hi_l, hi_n = pack_keys([r[1] for r in padded], self.cfg.key_words)
+        ps = self.pipeline_stats
+        with span("read.pack", ps, "pack_s"):
+            pad = [ranges[0]] * (bucket_pow2(len(ranges)) - len(ranges))
+            padded = ranges + pad
+            ps.dispatched_lanes += len(ranges)
+            ps.padded_lanes += len(padded)
+            lo_l, lo_n = pack_keys([r[0] for r in padded], self.cfg.key_words)
+            hi_l, hi_n = pack_keys([r[1] for r in padded], self.cfg.key_words)
+            args = (jnp.asarray(lo_l), jnp.asarray(lo_n),
+                    jnp.asarray(hi_l), jnp.asarray(hi_n))
         rb = self._read_backend_for(snap)
         kernel_ops.record_read_dispatch("scan", rb, self.cfg)
         slo, shi = self.tree.epochs.accel_begin_batch(len(ranges))
         try:
-            if rb == "fused":
-                res, meters = _jit_scan_fused(
-                    snap, jnp.asarray(lo_l), jnp.asarray(lo_n),
-                    jnp.asarray(hi_l), jnp.asarray(hi_n), cfg=self.cfg,
-                    lb_fraction=self.cfg.lb_fraction)
-                self._note_read_meters(meters)
-            else:
-                res = _jit_scan(
-                    snap, jnp.asarray(lo_l), jnp.asarray(lo_n),
-                    jnp.asarray(hi_l), jnp.asarray(hi_n), cfg=self.cfg)
-            count = np.asarray(res.count)
-            keys = np.asarray(res.keys)
-            klens = np.asarray(res.keylens)
-            vals = np.asarray(res.vals)
-            vlens = np.asarray(res.vallens)
-            trunc = np.asarray(res.truncated)
+            with span("read.launch"):
+                if rb == "fused":
+                    res, meters = _jit_scan_fused(
+                        snap, *args, cfg=self.cfg,
+                        lb_fraction=self.cfg.lb_fraction)
+                else:
+                    res, meters = _jit_scan(snap, *args, cfg=self.cfg), None
+            with span("read.fetch", ps, "fetch_s"):
+                if meters is not None:
+                    self._note_read_meters(meters)
+                count = np.asarray(res.count)
+                keys = np.asarray(res.keys)
+                klens = np.asarray(res.keylens)
+                vals = np.asarray(res.vals)
+                vlens = np.asarray(res.vallens)
+                trunc = np.asarray(res.truncated)
         finally:
             self.tree.epochs.accel_complete_batch(slo, shi)
-        out = []
-        for b, (lo, hi) in enumerate(ranges):
-            if trunc[b]:
-                self.pipeline_stats.host_scans += 1
-                out.append(self.tree.scan(lo, hi, read_version=fallback_rv))
-                continue
-            items = []
-            for j in range(int(count[b])):
-                k = keys[b, j].astype(">u4").tobytes()[: int(klens[b, j])]
-                items.append((k, self._decode_value(vals[b, j],
-                                                    int(vlens[b, j]))))
-            out.append(items)
+        out: list = [None] * len(ranges)
+        fallbacks = []
+        with span("read.decode", ps, "decode_s"):
+            for b in range(len(ranges)):
+                if trunc[b]:
+                    fallbacks.append(b)
+                    continue
+                items = []
+                for j in range(int(count[b])):
+                    k = keys[b, j].astype(">u4").tobytes()[: int(klens[b, j])]
+                    items.append((k, self._decode_value(vals[b, j],
+                                                        int(vlens[b, j]))))
+                out[b] = items
+        if fallbacks:
+            with span("read.host_scan"):
+                for b in fallbacks:
+                    lo, hi = ranges[b]
+                    out[b] = self.tree.scan(lo, hi, read_version=fallback_rv)
+            ps.host_scans += len(fallbacks)
         return out
 
     def _decode_value(self, lanes: np.ndarray, length: int) -> bytes:

@@ -29,6 +29,30 @@ one front door:
     ``CLOCK``).  core/shard.py, core/replica.py and core/scheduler.py all
     alias it as their ``_now``, so a test freezes ONE clock
     (``CLOCK.frozen()``) instead of monkeypatching three modules.
+  * ``span`` — THE program span: a ``jax.profiler.TraceAnnotation``
+    named ``hc.<name>`` (on the profiler's clock, so a device trace can
+    pin each idle gap on the host step it falls in; an inactive
+    annotation costs well under a microsecond) that, given a stats
+    object and a field, also adds the block's ``CLOCK`` duration to that
+    field — each stage is timed once, and frozen-clock tests still read
+    zero.  Spans sit at stage and batch level, never one per request:
+
+      span               where                               feeds
+      ------------------ ----------------------------------- ---------------------
+      hc.admit           scheduler stage_admit               admit_s (scheduler)
+      hc.export          scheduler stage_export, whole stage export_s (scheduler)
+      hc.sync.barrier    serial block_until_ready /          sync_stall_s
+                         pipelined flip                      (scheduler)
+      hc.sync.refresh    begin_export: cache.refresh         trace only
+      hc.sync.pack       dirty-row sort, padding, pack       trace only
+      hc.sync.put        jnp.asarray uploads of the sync     trace only
+      hc.sync.launch     the sync program's launch           trace only
+      hc.dispatch        scheduler stage_dispatch, whole     dispatch_s (scheduler)
+      hc.read.pack       padding, pack_keys, lane uploads    pack_s (store)
+      hc.read.launch     the read program's launch           trace only
+      hc.read.fetch      device->host copies of results      fetch_s (store)
+      hc.read.decode     the decode loops                    decode_s (store)
+      hc.read.host_scan  truncated SCANs served by the tree  trace only
   * ``merge_stats`` — THE per-layer aggregation helper (moved here from
     core/router.py, which keeps ``aggregate_stats`` as the historical
     alias): merge per-shard / per-replica stats objects via their
@@ -40,10 +64,13 @@ calls ``wire_store(store)`` — which registers every stats surface the
 facade exposes (works for ``StoreShard``/``HoneycombStore``,
 ``ShardedHoneycombStore`` and bare ``ReplicaGroup`` alike, because they
 all share the meter property names) — and hands the bundle to the
-``OutOfOrderScheduler``, which records dispatch/request latency
-histograms and drives the tracer.  ``enabled=False`` skips ALL of it:
+``OutOfOrderScheduler``, which records the request latency histogram
+and drives the tracer.  Every ``Telemetry`` also turns on the
+process-wide compile listener (``watch_compiles``) behind
+``programs_built``.  ``enabled=False`` skips ALL of it:
 no registry, no histograms, no tracer, byte-identical scheduler behaviour
-to the pre-telemetry code.
+to the pre-telemetry code.  Program spans are not part of the bundle:
+the stage meters they feed run either way.
 
 Metric-name reference (the names benchmarks columns, verify.sh asserts
 and Prometheus scrapes key on — keep in sync with the ``collect()``
@@ -74,7 +101,10 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   pipeline_admit_s                counter    pipeline   host write-apply wall seconds
   pipeline_export_s               counter    pipeline   standby staging wall seconds
   pipeline_dispatch_s             counter    pipeline   read-dispatch wall seconds
-  pipeline_sync_stall_s           counter    pipeline   blocked-on-sync wall seconds
+  pipeline_sync_stall_s           counter    pipeline   sync barrier wall seconds (hc.sync.barrier)
+  pipeline_pack_s                 counter    pipeline   read key packing + upload seconds (hc.read.pack)
+  pipeline_fetch_s                counter    pipeline   read result device->host seconds (hc.read.fetch)
+  pipeline_decode_s               counter    pipeline   read result decode seconds (hc.read.decode)
   pipeline_staged_exports         counter    pipeline   begin_export standby stagings
   pipeline_flips                  counter    pipeline   epoch publishes
   pipeline_dispatched_lanes       counter    pipeline   real requests inside device batches
@@ -110,9 +140,9 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   scheduler_dispatched_requests   counter    scheduler  read requests inside them
   scheduler_applied_writes        counter    scheduler  writes admitted host-side
   scheduler_syncs                 counter    scheduler  per-shard syncs its epochs ran
-  read_get_latency_seconds        histogram  scheduler  per-request GET device latency
-  read_scan_latency_seconds       histogram  scheduler  per-request SCAN device latency
   request_latency_seconds         histogram  scheduler  submit->resolve (traced requests)
+  programs_built                  counter    jax        programs compiled or fetched from
+    (label fun_name=<jitted program>)                   the persistent cache, process-wide
   traces_sampled/traces_retained  counter/gauge tracer  sampling meters
 
 Histogram geometry: geometric buckets, ``buckets_per_decade`` per decade
@@ -133,13 +163,15 @@ import time
 from collections import deque
 from typing import Any, Callable, Iterable
 
+from jax.profiler import TraceAnnotation
+
 from .config import TelemetryConfig
 
 __all__ = [
     "CLOCK", "Clock", "Counter", "Gauge", "Histogram", "MetricSample",
-    "MetricsRegistry", "Span", "Telemetry", "Trace", "Tracer",
-    "chrome_trace_events", "merge_stats", "parse_prometheus", "prom_value",
-    "samples_from",
+    "MetricsRegistry", "SPAN_PREFIX", "Span", "Telemetry", "Trace", "Tracer",
+    "chrome_trace_events", "merge_stats", "parse_prometheus",
+    "programs_built", "prom_value", "samples_from", "span", "watch_compiles",
 ]
 
 
@@ -185,6 +217,71 @@ class Clock:
 #: The process-wide clock every timing site (shard, replica, scheduler,
 #: tracer) reads.  Freeze THIS to freeze them all.
 CLOCK = Clock()
+
+
+# ------------------------------------------------------------ program spans
+SPAN_PREFIX = "hc."
+
+
+class span:
+    """``with span("read.pack", stats, "pack_s"):`` — one program span.
+
+    Opens a ``jax.profiler.TraceAnnotation`` named ``hc.<name>`` (it
+    lands in a profiler trace only while one is recording) and, when
+    ``stats`` is given, adds the block's ``CLOCK`` duration to
+    ``stats.<field>``.  ``t0``/``t1`` keep the two clock readings for
+    callers that also hand them to the sampled ``Tracer``."""
+
+    __slots__ = ("_ann", "_stats", "_field", "t0", "t1")
+
+    def __init__(self, name: str, stats=None, field: str | None = None):
+        self._ann = TraceAnnotation(SPAN_PREFIX + name)
+        self._stats, self._field = stats, field
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = CLOCK()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = CLOCK()
+        if self._stats is not None:
+            setattr(self._stats, self._field,
+                    getattr(self._stats, self._field) + self.t1 - self.t0)
+        self._ann.__exit__(*exc)
+
+
+# ------------------------------------------------------ programs JAX built
+#: JAX's event around each backend compile (a persistent-cache fetch
+#: included), recorded with the jitted program's ``fun_name``
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PROGRAMS_BUILT: dict[str, int] = {}
+_watching_compiles = False
+
+
+def _on_duration_event(event: str, _secs: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        name = str(kw.get("fun_name", "unknown"))
+        _PROGRAMS_BUILT[name] = _PROGRAMS_BUILT.get(name, 0) + 1
+
+
+def watch_compiles() -> None:
+    """Register the process-wide compile listener (once; later calls are
+    no-ops).  Programs built before the first call are not counted."""
+    global _watching_compiles
+    if not _watching_compiles:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _watching_compiles = True
+
+
+def programs_built() -> list[tuple]:
+    """``programs_built{fun_name=...}`` counter samples, one per program
+    name the listener has seen."""
+    return [("programs_built", "counter", float(n),
+             {"layer": "jax", "fun_name": name})
+            for name, n in sorted(_PROGRAMS_BUILT.items())]
 
 
 # ------------------------------------------------------- samples & merges
@@ -279,8 +376,8 @@ class Histogram:
     """Log-bucketed latency histogram: geometric buckets over
     [``lo``, ``hi``) at ``buckets_per_decade`` resolution, plus
     underflow/overflow buckets.  See the module docstring for the accuracy
-    contract; ``record(v, n)`` is weighted so a per-batch device time can
-    be spread over the batch's requests with one call."""
+    contract; ``record(v, n)`` records ``n`` observations of ``v`` in one
+    call."""
 
     __slots__ = ("lo", "hi", "bpd", "counts", "count", "total",
                  "vmin", "vmax")
@@ -652,6 +749,8 @@ class Telemetry:
                        if self.cfg.trace_sample_rate > 0 else None)
         if self.tracer is not None:
             self.registry.register(self.tracer.collect)
+        watch_compiles()
+        self.registry.register(programs_built)
 
     @property
     def enabled(self) -> bool:

@@ -115,6 +115,7 @@ def snapshot_delta_scatter(dst, rows, upd, *, interpret: bool = False):
     )
     out = pl.pallas_call(
         _scatter_row_kernel,
+        name="delta_row_scatter",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst_p.shape, dst.dtype),
         input_output_aliases={2: 0},   # dst (arg 2, after rows & upd) -> out
@@ -224,6 +225,7 @@ def log_replay_scatter(image, rows, slots, entries, *, offs,
     )
     out = pl.pallas_call(
         _log_replay_kernel(offs),
+        name="log_replay_scatter",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(image_p.shape, image.dtype),
         input_output_aliases={3: 0},   # image (after rows, slots, entries)
@@ -281,6 +283,7 @@ def snapshot_multi_scatter(dsts, rows, upd, *, interpret: bool = False):
     )
     return pl.pallas_call(
         _multi_scatter_kernel(nf),
+        name="multi_field_scatter",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(d.shape, d.dtype) for d in dsts],
         # dst f is argument 1 + nf + f (after rows and the nf update blocks)

@@ -577,6 +577,7 @@ def _fused_call(mode, image, pagetable, root_lid, read_version, cache_lids,
     )
     return pl.pallas_call(
         _fused_kernel(cfg, int(round(lb_fraction * 16)), mode, B, C),
+        name=f"fused_read_{mode}",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((Bp,) + s, jnp.int32)
                    for s in out_shapes],

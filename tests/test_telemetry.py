@@ -7,16 +7,23 @@ equal to the old per-layer sums (the merge_stats move is a refactor, not
 a behaviour change); the sampled trace lifecycle (span ordering,
 epoch/serving-version tags matching the Response stamps on a replicated
 pipelined store, ring-buffer bound, rate-0 => nothing allocated); the
-Prometheus export round trip; and the all-six-surfaces snapshot.
+Prometheus export round trip; the all-six-surfaces snapshot; the program
+spans on the profiler's clock and the read-dispatch split they meter; and
+the per-program compile counter.
 """
+import glob
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (CLOCK, Get, Histogram, HoneycombConfig,
-                        HoneycombService, Put, ReplicationConfig,
-                        ShardedHoneycombStore, TelemetryConfig, Tracer,
-                        Update, merge_stats, parse_prometheus, prom_value,
-                        uniform_int_boundaries)
+                        HoneycombService, HoneycombStore, Put,
+                        ReplicationConfig, Scan, ShardedHoneycombStore,
+                        TelemetryConfig, Tracer, Update, merge_stats,
+                        parse_prometheus, prom_value, uniform_int_boundaries)
+from repro.core.telemetry import SPAN_PREFIX, Telemetry
 from repro.core import replica as replica_mod
 from repro.core import scheduler as scheduler_mod
 from repro.core import shard as shard_mod
@@ -161,8 +168,14 @@ def test_frozen_clock_zeroes_stage_timings():
         svc = HoneycombService(st, batch_size=8)
         _traffic(svc, 32, ops=16)
         assert svc.stats.admit_s == 0.0
+        assert svc.stats.export_s == 0.0
         assert svc.stats.sync_stall_s == 0.0
         assert svc.stats.dispatch_s == 0.0
+        ps = st.pipeline_stats
+        assert ps.dispatched_lanes > 0        # reads did dispatch
+        assert ps.pack_s == 0.0
+        assert ps.fetch_s == 0.0
+        assert ps.decode_s == 0.0
 
 
 # -------------------------------------------------- aggregation regression
@@ -224,9 +237,15 @@ def test_prometheus_round_trip(replicated_service):
         svc.telemetry.value("sync_log_entries", src="primary")
     assert prom_value(parsed, "hc_tree_puts") == \
         svc.telemetry.value("tree_puts")
+    # the read-dispatch split of the store side exports as a counter (the
+    # text exposition prints six significant digits)
+    fetch_s = prom_value(parsed, "hc_pipeline_fetch_s", src="store")
+    assert fetch_s > 0
+    assert fetch_s == pytest.approx(
+        svc.telemetry.value("pipeline_fetch_s", src="store"), rel=1e-5)
     # histograms export as summaries with quantile + sum + count series
-    assert prom_value(parsed, "hc_read_get_latency_seconds_count") > 0
-    assert "hc_read_get_latency_seconds" in parsed
+    assert prom_value(parsed, "hc_request_latency_seconds_count") > 0
+    assert "hc_request_latency_seconds" in parsed
     with pytest.raises(ValueError):
         parse_prometheus("not a metric line at all {")
 
@@ -334,14 +353,66 @@ def test_disabled_telemetry_is_absent():
 
 
 def test_latency_histograms_fill_at_dispatch(replicated_service):
-    _, svc, tickets, _, _ = replicated_service
+    """The read spans fill at dispatch: the store's pack/fetch/decode
+    split is live and lies inside the scheduler's dispatch stage; the
+    submit->resolve histogram covers every traced request."""
+    st, svc, tickets, _, _ = replicated_service
     tm = svc.telemetry
-    n_reads = sum(1 for t in tickets if not t.op.IS_WRITE)
-    h = tm.registry.histogram("read_get_latency_seconds",
-                              layer="scheduler")
-    assert h.count == n_reads             # one weighted record per batch
-    assert 0.0 < tm.quantile("read_get_latency_seconds", 50) <= \
-        tm.quantile("read_get_latency_seconds", 99.9)
+    ps = st.pipeline_stats
+    assert ps.pack_s > 0 and ps.fetch_s > 0 and ps.decode_s > 0
+    assert ps.pack_s + ps.fetch_s + ps.decode_s <= svc.stats.dispatch_s
+    assert tm.value("pipeline_decode_s", src="store") == \
+        pytest.approx(ps.decode_s)
     req = tm.registry.histogram("request_latency_seconds",
                                 layer="scheduler")
     assert req.count == len(tickets)
+
+
+# every program span a drain with writes and SCANs opens (core/telemetry.py)
+SPAN_NAMES = ("admit", "export", "sync.barrier", "sync.refresh",
+              "sync.pack", "sync.put", "sync.launch", "dispatch",
+              "read.pack", "read.launch", "read.fetch", "read.decode",
+              "read.host_scan")
+
+
+def test_program_spans_land_in_profiler_trace(tmp_path):
+    """One serial drain with writes, short SCANs and a SCAN too wide for
+    the device (served by the host tree), under the JAX profiler on the
+    CPU: every ``hc.*`` span is in the trace's host plane."""
+    from jax.profiler import ProfileData
+    cfg = HoneycombConfig()
+    st = HoneycombStore(cfg, heap_capacity=512)
+    for i in range(64):
+        st.put(int_key(i), b"v" * 8)
+    svc = HoneycombService(st, batch_size=8)
+    wide = cfg.max_scan_items + 8
+    with jax.profiler.trace(str(tmp_path)):
+        tickets = svc.submit_many(
+            [Put(int_key(100 + i), b"w" * 8) for i in range(4)]
+            + [Scan(int_key(i), int_key(i + 3), expected_items=4)
+               for i in range(8)]
+            + [Scan(int_key(0), int_key(wide), expected_items=wide)])
+        svc.drain()
+    assert all(t.done for t in tickets)
+    assert len(tickets[-1].result().items) == wide + 1
+    assert st.pipeline_stats.host_scans == 1
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    missing = {SPAN_PREFIX + n for n in SPAN_NAMES} - names
+    assert not missing, sorted(missing)
+
+
+def test_programs_built_counts_by_name():
+    tm = Telemetry()
+
+    def programs_built_probe(x):
+        return x * 3 + 1
+
+    before = tm.value("programs_built", fun_name="jit(programs_built_probe)")
+    jax.jit(programs_built_probe)(jnp.ones(3)).block_until_ready()
+    assert tm.value("programs_built",
+                    fun_name="jit(programs_built_probe)") == before + 1
+    assert "hc_programs_built" in parse_prometheus(tm.to_prometheus())
