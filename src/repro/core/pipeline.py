@@ -106,8 +106,9 @@ class PipelineStats:
     #   pipelined flip, ~0)
     pack_s: float = 0.0         # read batches: padding, key packing and
     #   lane uploads (shard)
-    fetch_s: float = 0.0        # read batches: device->host copies of the
-    #   results and meters, the wait for the device included (shard)
+    fetch_s: float = 0.0        # read batches: the one device->host copy
+    #   of each batch's packed results and meters, the wait for the device
+    #   included (shard)
     decode_s: float = 0.0       # read batches: decoding results (shard)
     staged_exports: int = 0     # begin_export calls that staged a standby
     flips: int = 0              # epoch publishes
@@ -115,6 +116,8 @@ class PipelineStats:
     padded_lanes: int = 0       # bucket_pow2 device lanes those occupied
     host_scans: int = 0         # device SCAN requests the host tree had to
     #   answer (leaf/item budget exhausted — paper Section 6.3)
+    read_batches: int = 0       # device read batches dispatched (shard)
+    read_copies: int = 0        # device->host copies those batches made
 
     def merge(self, other: "PipelineStats"):
         """Accumulate another meter (router aggregation over shards)."""

@@ -50,7 +50,8 @@ one front door:
       hc.dispatch        scheduler stage_dispatch, whole     dispatch_s (scheduler)
       hc.read.pack       padding, pack_keys, lane uploads    pack_s (store)
       hc.read.launch     the read program's launch           trace only
-      hc.read.fetch      device->host copies of results      fetch_s (store)
+      hc.read.fetch      the one packed copy of results and  fetch_s (store)
+                         meters
       hc.read.decode     the decode loops                    decode_s (store)
       hc.read.host_scan  truncated SCANs served by the tree  trace only
   * ``merge_stats`` — THE per-layer aggregation helper (moved here from
