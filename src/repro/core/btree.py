@@ -121,10 +121,24 @@ class HoneycombTree:
             return lanes.astype(">u4").tobytes()[:length]
         return self.overflow.read(int(lanes[0]))
 
-    def _defer_value(self, lanes, length):
-        """GC the overflow slot behind a value that left the live tree."""
-        if length > self.cfg.max_inline_val_bytes:
-            self.gc.defer(overflow=(int(lanes[0]),))
+    def _defer_dropped_values(self, phys: int, items) -> None:
+        """GC the overflow slots of leaf ``phys`` (sorted block and log)
+        that the merged ``items`` no longer hold: the written key's old
+        value and every value its log shadowed.  They are reclaimed once
+        the epoch window has passed them."""
+        if not self.overflow.allocs:
+            return
+        h, inline = self.heap, self.cfg.max_inline_val_bytes
+        n, m = int(h.nitems[phys]), int(h.nlog[phys])
+        old = np.concatenate([
+            h.svals[phys, :n, 0][h.svallen[phys, :n] > inline],
+            h.log_vals[phys, :m, 0][h.log_vallen[phys, :m] > inline]])
+        if not old.size:
+            return
+        kept = [lanes[0] for _, lanes, ln in items if ln > inline]
+        dropped = np.setdiff1d(old, np.asarray(kept, old.dtype))
+        if dropped.size:
+            self.gc.defer(overflow=dropped.tolist())
 
     # ------------------------------------------------------- node inspection
     def _floor_in_sorted(self, phys: int, klanes, klen) -> int:
@@ -330,6 +344,7 @@ class HoneycombTree:
         self._write(key, b"", LOG_DELETE, thread)
 
     def _write(self, key: bytes, value: bytes, op: int, thread: int = 0):
+        self.overflow.check(len(value))
         klanes, klen = self._pack(key)
         # placement record of THIS write if (and only if) it takes the log
         # fast path — (phys, slot, backptr, hint, vdelta), the sidecar the
@@ -395,8 +410,6 @@ class HoneycombTree:
                                              with_lanes=True)
         ent = {k: (lanes, ln) for k, lanes, ln in resolved}
         key = self._key_bytes(klanes, klen)
-        if key in ent:
-            self._defer_value(*ent[key])
         if op == LOG_DELETE:
             ent.pop(key, None)
         else:
@@ -404,6 +417,7 @@ class HoneycombTree:
             vlen = self._store_value(value, vlanes)
             ent[key] = (vlanes, vlen)
         items = [(k, *ent[k]) for k in sorted(ent)]
+        self._defer_dropped_values(leaf.phys, items)
 
         if len(items) > self.cfg.node_cap:
             self._split(path, items)

@@ -12,8 +12,11 @@ fixed-width slots:
   464 B shortcut block          ``n_shortcuts`` keys + segment offsets
   512 B log threshold           ``log_cap`` entries (merge when full)
   460 B max key                 ``key_words`` * 4 bytes (big-endian lanes)
-  469 B max inline value        ``val_words`` * 4 bytes, larger values go
-                                to the overflow heap (paper: out-of-node)
+  469 B max inline value        ``val_words`` * 4 bytes (16 B) inline; a
+                                longer value (paper: out-of-node) takes
+                                one ``overflow_words`` slot of the host
+                                overflow heap, served to reads from the
+                                device value image (``TreeSnapshot.values``)
   5 B version delta             32-bit delta; wrap forces a merge, same as
                                 the paper's wrap-forces-merge rule
 """
@@ -84,7 +87,8 @@ class HoneycombConfig:
     lb_fraction: float = 0.0
 
     # --- value overflow heap -----------------------------------------------
-    overflow_words: int = 128   # slot size of the out-of-node value heap
+    overflow_words: int = 128   # words per out-of-node value slot (host
+    #   overflow heap and device value image): the longest value stored
 
     # --- host->device sync (delta snapshots, paper Sections 3-4) ------------
     # "on_read": sync lazily before a device batch (default, paper-like);

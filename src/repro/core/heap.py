@@ -139,32 +139,55 @@ class NodeHeap:
 
 
 class OverflowHeap:
-    """Out-of-node value storage (paper: values > 469 B live outside the
-    node).  Values are immutable once written; slots are recycled via GC."""
+    """Out-of-node value storage (paper Section 3.1: a value too long for
+    the node lives outside it).  A value longer than the inline width
+    (``val_words`` lanes, 16 B by default) takes one slot of
+    ``cfg.overflow_words`` words; the node keeps the slot id in lane 0.
+    A slot holds the value's bytes as they are, so a slot row copied back
+    from the device value image (``TreeSnapshot.values``) is the value.
+
+    Slots are immutable once written and recycled through GC only after
+    the epoch window has passed them.  ``fresh`` holds the slots allocated
+    since the last sync (the value image's delta, as ``NodeHeap.dirty`` is
+    the node image's); growth doubles the capacity and bumps
+    ``generation``, which forces a full republish of the value image."""
 
     def __init__(self, cfg: HoneycombConfig, capacity: int = 256):
         self.cfg = cfg
+        self.slot_bytes = cfg.overflow_words * 4
         self.vals = np.zeros((capacity, cfg.overflow_words), np.uint32)
         self.lens = np.zeros((capacity,), np.int32)
         self._free = list(range(capacity - 1, -1, -1))
+        self.fresh: set[int] = set()
+        self.generation = 0
+        self.allocs = 0            # values ever stored (0: no value image)
+
+    def check(self, n: int):
+        """Refuse a value no slot can hold."""
+        if n > self.slot_bytes:
+            raise ValueError(
+                f"value of {n} B is longer than an overflow slot of "
+                f"{self.slot_bytes} B (overflow_words={self.cfg.overflow_words})")
 
     def alloc(self, data: bytes) -> int:
+        self.check(len(data))
         if not self._free:
             cap = len(self.lens)
             self.vals = np.concatenate([self.vals, np.zeros_like(self.vals)])
             self.lens = np.concatenate([self.lens, np.zeros_like(self.lens)])
             self._free.extend(range(2 * cap - 1, cap - 1, -1))
+            self.generation += 1
         slot = self._free.pop()
-        buf = data + b"\x00" * (-len(data) % 4)
-        lanes = np.frombuffer(buf, dtype=">u4").astype(np.uint32)
-        self.vals[slot, :] = 0
-        self.vals[slot, : len(lanes)] = lanes
+        row = self.vals[slot].view(np.uint8)
+        row[len(data):] = 0
+        row[:len(data)] = np.frombuffer(data, np.uint8)
         self.lens[slot] = len(data)
+        self.fresh.add(slot)
+        self.allocs += 1
         return slot
 
     def read(self, slot: int) -> bytes:
-        n = int(self.lens[slot])
-        return self.vals[slot].astype(">u4").tobytes()[:n]
+        return self.vals[slot].view(np.uint8)[:int(self.lens[slot])].tobytes()
 
     def free(self, slot: int):
         self.lens[slot] = 0

@@ -118,6 +118,8 @@ class PipelineStats:
     #   answer (leaf/item budget exhausted — paper Section 6.3)
     read_batches: int = 0       # device read batches dispatched (shard)
     read_copies: int = 0        # device->host copies those batches made
+    device_values: int = 0      # out-of-node values a device read batch
+    #   served from the device value image (``gather_values``, shard)
 
     def merge(self, other: "PipelineStats"):
         """Accumulate another meter (router aggregation over shards)."""

@@ -60,13 +60,21 @@ class TreeSnapshot(NamedTuple):
     image via ``attach_cache_image`` wherever a snapshot is (re)staged, so
     its rows are bit-identical to the version-resolved heap rows by
     construction.  ``None`` on legacy-era snapshots (fused reads fall back
-    to the reference path)."""
+    to the reference path).
+
+    ``values`` is the device value image: one row of ``overflow_words``
+    words per out-of-node value slot (core/heap.py ``OverflowHeap``), the
+    value's bytes as they are.  Read batches gather their long values from
+    it (``gather_values``, core/shard.py).  ``None`` while the store has
+    never held a value longer than the inline width, so a store of inline
+    values publishes, syncs and reads with no value program at all."""
     image: jax.Array        # u32 [S, image_words] packed node images
     pagetable: jax.Array    # i32 [LIDS]
     root_lid: jax.Array     # i32 []
     read_version: jax.Array  # i32 []
     cache_lids: jax.Array | None = None   # i32 [C], NULL-padded
     cache_image: jax.Array | None = None  # u32 [C, image_words]
+    values: jax.Array | None = None       # u32 [V, overflow_words]
 
 
 class LegacyTreeSnapshot(NamedTuple):
@@ -99,6 +107,7 @@ class LegacyTreeSnapshot(NamedTuple):
     pagetable: jax.Array    # i32 [LIDS]
     root_lid: jax.Array     # i32 []
     read_version: jax.Array  # i32 []
+    values: jax.Array | None = None   # u32 [V, overflow_words], as packed
 
 
 # per-node-row snapshot fields, in layout order — derived from the ONE
@@ -175,6 +184,16 @@ class SnapshotDelta(NamedTuple):
     root_lid: jax.Array      # i32 []
     read_version: jax.Array  # i32 []
     cache_lids: jax.Array | None = None  # i32 [C] next epoch's cache tier
+
+
+class ValueDelta(NamedTuple):
+    """One sync's new out-of-node value slots, the value image's delta:
+    ``rows[i]`` holds slot ``slots[i]``.  Slots are immutable once
+    written, so only slots allocated since the last sync travel.
+    ``slots`` is None when ``rows`` is the whole image (the first value
+    publish, or the host heap grew)."""
+    slots: jax.Array | None  # i32 [D] slots written, or None
+    rows: jax.Array          # u32 [D or V, overflow_words]
 
 
 class LegacySnapshotDelta(NamedTuple):
@@ -668,16 +687,3 @@ def get_from_scan(res: ScanResult, key: jax.Array,
     idx = jnp.argmax(eq, axis=1)
     rows = jnp.arange(key.shape[0])
     return GetResult(found, res.vals[rows, idx], res.vallens[rows, idx])
-
-
-def gather_overflow(vals: jax.Array, vallens: jax.Array,
-                    overflow_vals: jax.Array, cfg: HoneycombConfig):
-    """Expand out-of-node values: result lanes [B, OW] padded, using lane 0
-    as the overflow slot when the length exceeds the inline capacity."""
-    inline_cap = cfg.max_inline_val_bytes
-    is_ovf = vallens > inline_cap
-    slot = jnp.where(is_ovf, vals[..., 0].astype(jnp.int32), 0)
-    ow = overflow_vals.shape[-1]
-    inline = jnp.pad(vals, [(0, 0)] * (vals.ndim - 1)
-                     + [(0, ow - vals.shape[-1])])
-    return jnp.where(is_ovf[..., None], overflow_vals[slot], inline)

@@ -31,7 +31,9 @@ payloads (``StagedSync``, core/shard.py):
     drives Honeycomb's own batching, applied to the replication fan-out;
   * an epoch whose tree shape changed (split/root growth/GC moves/pending
     page-table commands, or an overflow-length value) has NO wire-replay
-    representation, so it falls back per-epoch to the image-row delta —
+    representation, so it falls back per-epoch to the image-row delta,
+    with the epoch's new out-of-node value slots (``StagedSync.values``)
+    scattered into the follower's own value image —
     metered as ``FeedStats.log_fallback_epochs`` so benchmarks report the
     fallback fraction.  ``feed="delta"`` pins every epoch to the image
     delta (the pre-log feed, kept as the byte-accounting reference);
@@ -103,7 +105,7 @@ from .heap import LOG_DELETE, LOG_INSERT, LOG_UPDATE
 from .read_path import NODE_FIELDS, TreeSnapshot, attach_cache_image
 from .schema import NodeImageLayout
 from .shard import (LogPayload, StagedSync, StoreShard, SyncStats,
-                    _jit_apply_delta, sync_backend)
+                    _jit_apply_delta, apply_value_delta, sync_backend)
 from .telemetry import CLOCK, merge_stats, samples_from
 
 _now = CLOCK            # THE injectable monotonic clock (core/telemetry.py)
@@ -203,10 +205,12 @@ class FollowerReplica:
         if payload.kind == "delta" and self.in_sync and base is not None:
             # independent device scatter per replica: O(dirty_rows) traffic
             # (one image-row DMA per dirty node on the packed layout — the
-            # delta type carries the layout, so the replay is layout-free)
-            self._standby = _jit_apply_delta(base, payload.delta,
-                                             backend=sync_backend(),
-                                             cfg=self.cfg)
+            # delta type carries the layout, so the replay is layout-free),
+            # plus the epoch's new value slots into our own value image
+            self._standby = _jit_apply_delta(
+                base._replace(values=None), payload.delta,
+                backend=sync_backend(), cfg=self.cfg)._replace(
+                    values=self._next_values(base.values, payload.values))
             stats.delta_syncs += 1
             stats.delta_rows += payload.delta_rows
             stats.bytes_synced += payload.nbytes
@@ -232,6 +236,18 @@ class FollowerReplica:
             san.note_staged(self, self._standby)
         return nbytes, was_full
 
+    @staticmethod
+    def _next_values(values, vd):
+        """Our value image after a delta staging's ``ValueDelta``: the
+        epoch's new slots scattered into our own image, or a copy of the
+        primary's whole image (the value image's first publish or a heap
+        growth), so that no image is shared between replicas."""
+        if vd is None:
+            return values
+        if vd.slots is None:
+            return jnp.copy(vd.rows)
+        return apply_value_delta(values, vd)
+
     def stage_log(self, payload: StagedSync, marshalled) -> int:
         """Replay one staging from its LOG payload: scatter the epoch's
         marshalled wire entries into our own standby image with the
@@ -251,10 +267,12 @@ class FollowerReplica:
             rows, slots, entries, offs = marshalled
             image = _jit_log_replay(base.image, rows, slots, entries, offs,
                                     sync_backend())
+        # a replayable epoch wrote no value slot: the value image stays
         snap = base._replace(
-            image=image, read_version=jnp.int32(lp.read_version))
+            image=image, read_version=jnp.int32(lp.read_version), values=None)
         if self.cfg is not None:
             snap = _jit_attach_cache(snap, cfg=self.cfg)
+        snap = snap._replace(values=base.values)
         self._standby = snap
         self._standby_rv = payload.read_version
         stats.log_replays += 1

@@ -57,7 +57,7 @@ from .keys import pack_keys
 from .pipeline import PipelineStats
 from .read_path import (NODE_FIELDS, GetResult, LegacySnapshotDelta,
                         LegacyTreeSnapshot, ScanResult, SnapshotDelta,
-                        TreeSnapshot, apply_snapshot_delta,
+                        TreeSnapshot, ValueDelta, apply_snapshot_delta,
                         attach_cache_image, batched_get, batched_scan)
 from .schema import NARROWED_FIELDS, NodeImageLayout
 from .telemetry import CLOCK, samples_from, span
@@ -112,18 +112,64 @@ def _read_fields(result_type, cfg: HoneycombConfig):
     return (((), u32), ((vw,), u32), ((), i32))
 
 
+def _field_offsets(result_type, cfg: HoneycombConfig):
+    """Word offset of each field in a packed result row, and the row's
+    width in words."""
+    sizes = [int(np.prod(shape)) for shape, _ in _read_fields(result_type,
+                                                              cfg)]
+    offs = np.cumsum([0] + sizes).tolist()
+    return offs[:-1], offs[-1]
+
+
+def _value_positions(result_type, cfg: HoneycombConfig) -> int:
+    """Values per lane of a result: one per GET, ``max_scan_items`` per
+    SCAN."""
+    return cfg.max_scan_items if result_type is ScanResult else 1
+
+
+def gather_values(packed: jax.Array, values: jax.Array, *, result_type,
+                  cfg: HoneycombConfig) -> jax.Array:
+    """Append a read batch's out-of-node values to its packed answer
+    (``_pack_read``'s buffer): one row of ``overflow_words`` words per
+    value position, lane-major (a GET lane's value, a SCAN lane's item
+    slots), holding the value image row of the slot in the value's lane 0
+    where the value is longer than the inline width, zeros elsewhere.
+    The batch still comes back in one copy; the host takes the value
+    bytes from the rows (``_fetch_read``).  Its own program
+    (``jit_gather_values``), so the trace times it apart from the read
+    kernel."""
+    offs, width = _field_offsets(result_type, cfg)
+    items = _value_positions(result_type, cfg)
+    lanes = (packed.shape[0] - 3) // width
+    rows = packed[:lanes * width].reshape(lanes, width)
+    va = offs[result_type._fields.index("vals")]
+    la = offs[result_type._fields.index("vallens")]
+    slot = rows[:, va:va + items * cfg.val_words].reshape(
+        lanes, items, cfg.val_words)[..., 0].astype(jnp.int32)
+    length = jax.lax.bitcast_convert_type(rows[:, la:la + items], jnp.int32)
+    long = length > cfg.max_inline_val_bytes
+    got = values[jnp.where(long, slot, 0)]
+    got = jnp.where(long[..., None], got, jnp.uint32(0))
+    return jnp.concatenate([packed, got.reshape(-1)])
+
+
+def scatter_values(values: jax.Array, slots: jax.Array,
+                   rows: jax.Array) -> jax.Array:
+    """The value image with one sync's new slots written.  Functional, as
+    the node image's scatter: snapshots that in-flight batches hold keep
+    their image."""
+    return values.at[slots].set(rows)
+
+
 def _unpack_read(buf: np.ndarray, result_type, cfg: HoneycombConfig):
     """Split a host copy of ``_pack_read``'s buffer into ``result_type``
     of zero-copy numpy views, and the meters (i32[3])."""
-    fields = _read_fields(result_type, cfg)
-    sizes = [int(np.prod(shape)) for shape, _ in fields]
-    width = sum(sizes)
+    offs, width = _field_offsets(result_type, cfg)
     lanes = (buf.size - 3) // width
     rows = buf[:lanes * width].reshape(lanes, width)
-    out, at = [], 0
-    for (shape, dtype), n in zip(fields, sizes):
-        out.append(rows[:, at:at + n].view(dtype).reshape((lanes,) + shape))
-        at += n
+    out = [rows[:, at:at + int(np.prod(shape))].view(dtype)
+           .reshape((lanes,) + shape)
+           for (shape, dtype), at in zip(_read_fields(result_type, cfg), offs)]
     return result_type._make(out), buf[-3:].view(np.int32)
 
 
@@ -146,6 +192,19 @@ _jit_scan_fused = jax.jit(_packed(kernel_ops.batched_scan_fused, True),
 # batches must keep answering at their read version.
 _jit_apply_delta = jax.jit(apply_snapshot_delta,
                            static_argnames=("backend", "cfg"))
+# the value image: the gather after each read program of a store that
+# holds out-of-node values, and the scatter of a sync's new value slots
+_jit_gather_values = jax.jit(gather_values,
+                             static_argnames=("result_type", "cfg"))
+_jit_scatter_values = jax.jit(scatter_values)
+
+
+def apply_value_delta(values: jax.Array | None,
+                      vd: ValueDelta) -> jax.Array:
+    """The value image after one sync's ``ValueDelta``."""
+    if vd.slots is None:
+        return vd.rows
+    return _jit_scatter_values(values, vd.slots, vd.rows)
 
 
 def sync_backend() -> str | None:
@@ -184,6 +243,9 @@ class SyncStats:
     log_replays: int = 0          # follower stagings applied by replaying
     #   the epoch's op wire stream on device (log_replay_scatter) instead
     #   of re-issuing the primary's image-row DMAs — the log-shipped feed
+    value_slots_synced: int = 0   # out-of-node value slots uploaded into
+    #   the device value image (the whole image on a full value publish)
+    value_bytes_synced: int = 0   # their bytes (also in bytes_synced)
 
     def merge(self, other: "SyncStats"):
         """Accumulate another shard's counters (router aggregation)."""
@@ -217,7 +279,10 @@ class StagedSync:
     per-replica feeding costs O(replicas x dirty_rows) can be accounted
     exactly; ``image_dmas``/``image_bytes`` are the staging's node-image
     DMA invocations and payload bytes (what each follower replay re-issues);
-    ``read_version`` is what the standby answers at once flipped.
+    ``read_version`` is what the standby answers at once flipped;
+    ``values`` is a delta staging's value-image delta (None when the
+    epoch wrote no value slot; a full staging's snapshot carries its whole
+    value image).
     """
     kind: str
     snapshot: TreeSnapshot | LegacyTreeSnapshot
@@ -232,6 +297,7 @@ class StagedSync:
     # values) and log capture is on.  None means followers must take the
     # image delta (the metered per-epoch fallback).
     log_payload: "LogPayload | None" = None
+    values: ValueDelta | None = None
 
 
 @dataclasses.dataclass
@@ -285,6 +351,7 @@ class StoreShard:
         # growth changes shapes and forces a full republish
         self._heap_gen = -1
         self._pt_gen = -1
+        self._values_gen = -1        # overflow heap generation, likewise
         # read version the resident snapshot answers at; under "explicit"
         # an accelerator epoch pins it so GC keeps old buffers alive and
         # host fallbacks stay linearizable with the stale device image
@@ -307,6 +374,7 @@ class StoreShard:
         self.on_staged: Callable[[StagedSync], None] | None = None
         self.on_flip: Callable[[], None] | None = None
         self._staged_delta: SnapshotDelta | None = None
+        self._staged_values: ValueDelta | None = None
         # log-shipped feed capture (core/replica.py sets log_capture when
         # followers ride the "log" feed; the unreplicated store pays one
         # bool check per write).  The epoch log holds (op, placement) per
@@ -480,8 +548,10 @@ class StoreShard:
             read_version=self._standby_rv,
             image_dmas=stats.image_dma_count - dmas0,
             image_bytes=stats.image_bytes - ibytes0,
-            log_payload=self._build_log_payload(staged_kind))
+            log_payload=self._build_log_payload(staged_kind),
+            values=self._staged_values)
         self._staged_delta = None
+        self._staged_values = None
         # epoch boundary for the log-shipped feed: whatever happens next
         # belongs to the next staging
         self._epoch_log = []
@@ -584,6 +654,7 @@ class StoreShard:
             stats.bytes_synced += arr.nbytes
             return jnp.asarray(arr)
 
+        values, _ = self._stage_values(None)
         if packed:
             stats.bytes_synced += h.capacity * layout.node_image_bytes
             stats.image_dma_count += 1
@@ -597,7 +668,8 @@ class StoreShard:
             # materialize the VMEM cache tier device-side from the image
             # just shipped — only the ~KB LID vector crossed the bus
             with span("sync.launch"):
-                return attach_cache_image(snap, self.cfg)
+                return attach_cache_image(snap, self.cfg)._replace(
+                    values=values)
         stats.image_dma_count += len(NODE_FIELDS)
         with span("sync.put"):
             fields = {f: dev(getattr(h, f),
@@ -607,7 +679,45 @@ class StoreShard:
                 pagetable=dev(pt_image),
                 root_lid=jnp.int32(t.root_lid),
                 read_version=jnp.int32(t.versions.read_version()),
-                **fields)
+                values=values, **fields)
+
+    def _stage_values(self, base: jax.Array | None):
+        """The value image a staged snapshot carries over ``base`` (the
+        scatter base's image; None on a full publish), and the
+        ``ValueDelta`` that made it, for followers.
+
+        Nothing moves while the store has never held an out-of-node value,
+        nor when no slot was allocated since the last sync.  The whole
+        image moves on a full publish, on the first value publish and
+        after the host heap grew (a generation change); otherwise only
+        the new slots, padded to a power-of-two bucket with idempotent
+        repeats.  Span ``hc.sync.values``."""
+        ovf = self.tree.overflow
+        if not ovf.allocs:
+            return None, None
+        whole = base is None or self._values_gen != ovf.generation
+        if not whole and not ovf.fresh:
+            return base, None
+        with span("sync.values"):
+            if whole:
+                n = len(ovf.lens)
+                # a copy: the CPU backend's asarray would alias the heap
+                vd = ValueDelta(slots=None, rows=jnp.asarray(ovf.vals.copy()))
+            else:
+                n = len(ovf.fresh)
+                slots = self._pad_index(
+                    np.fromiter(sorted(ovf.fresh), np.int32, n),
+                    bucket_pow2(n))
+                vd = ValueDelta(slots=jnp.asarray(slots),
+                                rows=jnp.asarray(ovf.vals[slots]))
+            values = apply_value_delta(base, vd)
+        stats = self.sync_stats
+        stats.value_slots_synced += n
+        stats.value_bytes_synced += n * ovf.slot_bytes
+        stats.bytes_synced += n * ovf.slot_bytes
+        ovf.fresh.clear()
+        self._values_gen = ovf.generation
+        return values, vd
 
     def _publish_delta(self, base, dirty: set[int]):
         """Incremental sync: scatter the ``dirty`` node rows and pending
@@ -677,9 +787,11 @@ class StoreShard:
                     **{f: jnp.asarray(a) for f, a in host_fields.items()})
         stats.bytes_synced += nbytes
         self._staged_delta = delta   # replayable by follower replicas
+        values, self._staged_values = self._stage_values(base.values)
         with span("sync.launch"):
-            return _jit_apply_delta(base, delta, backend=sync_backend(),
-                                    cfg=self.cfg)
+            nxt = _jit_apply_delta(base._replace(values=None), delta,
+                                   backend=sync_backend(), cfg=self.cfg)
+        return nxt._replace(values=values)
 
     @staticmethod
     def _pad_index(idx: np.ndarray, size: int) -> np.ndarray:
@@ -703,16 +815,42 @@ class StoreShard:
             return "fused"
         return "reference"
 
-    def _fetch_read(self, packed: jax.Array, result_type):
-        """A batch's one device->host copy (``_pack_read``'s buffer, inside
-        the caller's ``hc.read.fetch`` span): the result as zero-copy
-        views of it; the meters are folded into CacheStats."""
-        res, meters = _unpack_read(np.asarray(packed), result_type, self.cfg)
+    def _fetch_read(self, packed: jax.Array, result_type, lanes: int,
+                    values: bool):
+        """A batch's one device->host copy (``_pack_read``'s buffer, with
+        ``gather_values``' rows appended when ``values``; inside the
+        caller's ``hc.read.fetch`` span): the result as zero-copy views of
+        it, and the value rows as a [lanes, positions, overflow_words]
+        view (None without values); the meters are folded into
+        CacheStats."""
+        buf = np.asarray(packed)
+        rows = None
+        if values:
+            n = lanes * _field_offsets(result_type, self.cfg)[1] + 3
+            buf, rows = buf[:n], buf[n:].reshape(
+                lanes, _value_positions(result_type, self.cfg),
+                self.cfg.overflow_words)
+        res, meters = _unpack_read(buf, result_type, self.cfg)
         ps = self.pipeline_stats
         ps.read_batches += 1
         ps.read_copies += 1
         self._note_read_meters(meters)
-        return res
+        return res, rows
+
+    def _launch_read(self, jit_read, snap, args, result_type, **kw):
+        """Launch one read program on ``snap``'s node image and, for a
+        snapshot with a value image, ``gather_values`` after it (span
+        ``hc.read.gather``).  Returns the packed buffer and whether it
+        carries value rows."""
+        values = snap.values
+        with span("read.launch"):
+            packed = jit_read(snap._replace(values=None), *args,
+                              cfg=self.cfg, **kw)
+        if values is None:
+            return packed, False
+        with span("read.gather"):
+            return _jit_gather_values(packed, values, result_type=result_type,
+                                      cfg=self.cfg), True
 
     def _note_read_meters(self, meters: np.ndarray):
         """Fold one batch's device meters, views of its one packed copy,
@@ -771,19 +909,20 @@ class StoreShard:
         kernel_ops.record_read_dispatch("get", rb, self.cfg)
         lo, hi = self.tree.epochs.accel_begin_batch(len(keys))
         try:
-            with span("read.launch"):
-                if rb == "fused":
-                    packed = _jit_get_fused(
-                        snap, lanes, lens, cfg=self.cfg,
-                        lb_fraction=self.cfg.lb_fraction)
-                else:
-                    packed = _jit_get(snap, lanes, lens, cfg=self.cfg)
+            if rb == "fused":
+                packed, gathered = self._launch_read(
+                    _jit_get_fused, snap, (lanes, lens), GetResult,
+                    lb_fraction=self.cfg.lb_fraction)
+            else:
+                packed, gathered = self._launch_read(
+                    _jit_get, snap, (lanes, lens), GetResult)
             with span("read.fetch", ps, "fetch_s"):
-                found, vals, vlens = self._fetch_read(packed, GetResult)
+                (found, vals, vlens), rows = self._fetch_read(
+                    packed, GetResult, len(padded), gathered)
         finally:
             self.tree.epochs.accel_complete_batch(lo, hi)
         with span("read.decode", ps, "decode_s"):
-            return [self._decode_value(vals[i], int(vlens[i]))
+            return [self._decode_value(vals[i], int(vlens[i]), rows, (i, 0))
                     if found[i] else None for i in range(len(keys))]
 
     def scan_batch(self, ranges: Sequence[tuple[bytes, bytes]]
@@ -825,16 +964,17 @@ class StoreShard:
         kernel_ops.record_read_dispatch("scan", rb, self.cfg)
         slo, shi = self.tree.epochs.accel_begin_batch(len(ranges))
         try:
-            with span("read.launch"):
-                if rb == "fused":
-                    packed = _jit_scan_fused(
-                        snap, *args, cfg=self.cfg,
-                        lb_fraction=self.cfg.lb_fraction)
-                else:
-                    packed = _jit_scan(snap, *args, cfg=self.cfg)
+            if rb == "fused":
+                packed, gathered = self._launch_read(
+                    _jit_scan_fused, snap, args, ScanResult,
+                    lb_fraction=self.cfg.lb_fraction)
+            else:
+                packed, gathered = self._launch_read(
+                    _jit_scan, snap, args, ScanResult)
             with span("read.fetch", ps, "fetch_s"):
-                count, keys, klens, vals, vlens, trunc = self._fetch_read(
-                    packed, ScanResult)
+                (count, keys, klens, vals, vlens, trunc), rows = \
+                    self._fetch_read(packed, ScanResult, len(padded),
+                                     gathered)
         finally:
             self.tree.epochs.accel_complete_batch(slo, shi)
         out: list = [None] * len(ranges)
@@ -847,8 +987,8 @@ class StoreShard:
                 items = []
                 for j in range(int(count[b])):
                     k = keys[b, j].astype(">u4").tobytes()[: int(klens[b, j])]
-                    items.append((k, self._decode_value(vals[b, j],
-                                                        int(vlens[b, j]))))
+                    items.append((k, self._decode_value(
+                        vals[b, j], int(vlens[b, j]), rows, (b, j))))
                 out[b] = items
         if fallbacks:
             with span("read.host_scan"):
@@ -858,10 +998,21 @@ class StoreShard:
             ps.host_scans += len(fallbacks)
         return out
 
-    def _decode_value(self, lanes: np.ndarray, length: int) -> bytes:
+    def _decode_value(self, lanes: np.ndarray, length: int,
+                      rows: np.ndarray | None, at: tuple[int, int]) -> bytes:
+        """One answer's value: inline from its lanes, or out of node from
+        the batch's gathered value rows (``rows[at]``).  Every snapshot
+        that holds a long value carries the value image; the live host
+        heap is never read here, since its slot may have been reused
+        since the snapshot's epoch."""
         if length <= self.cfg.max_inline_val_bytes:
             return lanes.astype(">u4").tobytes()[:length]
-        return self.tree.overflow.read(int(lanes[0]))
+        if rows is None:
+            raise RuntimeError(
+                f"a {length} B value was read from a snapshot without a "
+                "value image")
+        self.pipeline_stats.device_values += 1
+        return rows[at].view(np.uint8)[:length].tobytes()
 
     # ------------------------------------------------------------- misc
     def collect_garbage(self) -> int:

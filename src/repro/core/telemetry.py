@@ -47,9 +47,13 @@ one front door:
       hc.sync.pack       dirty-row sort, padding, pack       trace only
       hc.sync.put        jnp.asarray uploads of the sync     trace only
       hc.sync.launch     the sync program's launch           trace only
+      hc.sync.values     value-image upload (new value       trace only
+                         slots, or the whole image)
       hc.dispatch        scheduler stage_dispatch, whole     dispatch_s (scheduler)
       hc.read.pack       padding, pack_keys, lane uploads    pack_s (store)
       hc.read.launch     the read program's launch           trace only
+      hc.read.gather     gather_values' launch (a store      trace only
+                         holding out-of-node values)
       hc.read.fetch      the one packed copy of results and  fetch_s (store)
                          meters
       hc.read.decode     the decode loops                    decode_s (store)
@@ -92,6 +96,8 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   sync_image_dma_count            counter    shard      node-image DMA invocations
   sync_image_bytes                counter    shard      node-image payload bytes
   sync_log_replays                counter    shard      follower stagings replayed from the op log
+  sync_value_slots_synced         counter    shard      out-of-node value slots uploaded to the value image
+  sync_value_bytes_synced         counter    shard      their bytes
     (labels src="primary" — the serving path's own sync traffic — and
      src="followers" — the replication amplification on top of it)
   tree_puts/updates/deletes       counter    btree      host write ops applied
@@ -106,6 +112,7 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   pipeline_pack_s                 counter    pipeline   read key packing + upload seconds (hc.read.pack)
   pipeline_fetch_s                counter    pipeline   read result device->host seconds (hc.read.fetch)
   pipeline_decode_s               counter    pipeline   read result decode seconds (hc.read.decode)
+  pipeline_device_values          counter    pipeline   out-of-node values served from the device value image
   pipeline_staged_exports         counter    pipeline   begin_export standby stagings
   pipeline_flips                  counter    pipeline   epoch publishes
   pipeline_dispatched_lanes       counter    pipeline   real requests inside device batches

@@ -14,8 +14,11 @@ SMALL = HoneycombConfig(node_cap=16, log_cap=4, n_shortcuts=4)
 
 
 def snapshots_equal(a, b) -> bool:
-    return all(bool(jnp.array_equal(getattr(a, f), getattr(b, f)))
-               for f in a._fields)
+    def same(x, y):
+        if x is None or y is None:          # e.g. no value image
+            return x is None and y is None
+        return bool(jnp.array_equal(x, y))
+    return all(same(getattr(a, f), getattr(b, f)) for f in a._fields)
 
 
 def apply_random_ops(store, oracle, rng, n):

@@ -27,8 +27,8 @@ N = 120
 
 
 def _value(i: int) -> bytes:
-    """Every fifth value is longer than the 16 inline bytes: the device
-    returns its overflow-heap slot and the host reads it from there."""
+    """Every fifth value is longer than the 16 inline bytes: the batch
+    gathers it from the snapshot's value image into its one copy."""
     return b"L%06d" % i * 4 if i % 5 == 0 else b"v%06d" % i
 
 
@@ -93,12 +93,12 @@ def test_packed_read_batch(op, backend, lanes):
     if op == "get":
         got = s.get_batch(reqs)
         assert got == [s.get(k) for k in reqs]
-        assert got[0] == _value(5) and len(got[0]) > 16   # overflow heap
+        assert got[0] == _value(5) and len(got[0]) > 16   # value image
     else:
         got = s.scan_batch(reqs)
         assert got == [s.tree.scan(lo, hi) for lo, hi in reqs]
         assert len(got[1]) > SMALL.max_scan_items         # host fallback
-        assert (int_key(5), _value(5)) in got[0]          # overflow heap
+        assert (int_key(5), _value(5)) in got[0]          # value image
     args = _args(op, reqs, s.cfg)
     res, meters = _direct(op, backend, snap, args, s.cfg)
     after = (ps.read_batches, ps.read_copies, ps.host_scans,

@@ -104,3 +104,25 @@ def test_log_replay_compiles(shapes):
              sds((HEAP_ROWS, layout.image_words)), sds((DIRTY,), jnp.int32),
              sds((DIRTY,), jnp.int32),
              sds((DIRTY, layout.log_entry_words)))
+
+
+@pytest.mark.parametrize("kind", ["get", "scan"])
+def test_value_gather_compiles(shapes, kind):
+    """``gather_values`` (core/shard.py) after a 256-lane read batch, over
+    the value image of 1M slots of 1,024 B (the YCSB-C configuration): a
+    plain XLA program, no temporary the size of the image."""
+    from repro.core import shard
+    from repro.core.read_path import GetResult, ScanResult
+    _, sds, _, _ = shapes
+    cfg = HoneycombConfig(overflow_words=256)
+    rt = GetResult if kind == "get" else ScanResult
+    width = shard._field_offsets(rt, cfg)[1]
+    compiled = shard._jit_gather_values.lower(
+        sds((BATCH * width + 3,)), sds((1 << 20, cfg.overflow_words)),
+        result_type=rt, cfg=cfg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 20
+    # the answer and its value rows, padded to the chip's tiles
+    want = 4 * (BATCH * width + 3 + BATCH * shard._value_positions(rt, cfg)
+                * cfg.overflow_words)
+    assert want <= mem.output_size_in_bytes < want + 2 ** 16
