@@ -679,11 +679,14 @@ def batched_get(snap, key: jax.Array, klen: jax.Array,
 def get_from_scan(res: ScanResult, key: jax.Array,
                   klen: jax.Array) -> GetResult:
     """The GET equality post-pass over a SCAN(K, K) result (shared with the
-    fused oracle)."""
+    fused oracle).  A key not found answers zero value words and length,
+    not the floor item the scan emitted below it."""
     eq = (jax_key_cmp(res.keys, res.keylens, key[:, None, :],
                       klen[:, None]) == 0) \
         & (jnp.arange(res.keys.shape[1])[None, :] < res.count[:, None])
     found = eq.any(axis=1)
     idx = jnp.argmax(eq, axis=1)
     rows = jnp.arange(key.shape[0])
-    return GetResult(found, res.vals[rows, idx], res.vallens[rows, idx])
+    return GetResult(found,
+                     jnp.where(found[:, None], res.vals[rows, idx], 0),
+                     jnp.where(found, res.vallens[rows, idx], 0))

@@ -204,9 +204,9 @@ def snapshot_multi_scatter(dsts, rows, upd, backend: str | None = None,
 
 def batched_get_fused(snap, key, klen, *, cfg, lb_fraction: float = 0.0,
                       backend: str | None = None):
-    """Fused device-resident GET: the whole batch traversal (descend +
-    leaf resolve + log merge + version resolution) in ONE dispatch, the
-    first ``cfg.cache_levels`` levels served from the snapshot's
+    """Fused device-resident GET: the whole batch traversal (descend, the
+    key's point resolve in its leaf, version resolution) in ONE dispatch,
+    the first ``cfg.cache_levels`` levels served from the snapshot's
     VMEM-pinned cache tier.  ``snap`` is a packed ``TreeSnapshot`` with
     cache fields attached.  Returns (GetResult, meters i32[3] =
     [vmem_hits, heap_gathers, lb_routed])."""

@@ -15,9 +15,11 @@ from repro.core import (Get, HoneycombConfig, HoneycombService,
                         HoneycombStore, ReplicationConfig, Scan,
                         ShardedHoneycombStore, Update,
                         uniform_int_boundaries)
+from repro.core import shard as shard_mod
 from repro.core.keys import int_key, pack_keys
+from repro.core.read_path import GetResult
 from repro.core.shard import StoreShard
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 SMALL = HoneycombConfig(node_cap=16, log_cap=4, n_shortcuts=4,
                         cache_slots=32, max_scan_leaves=2,
@@ -41,22 +43,178 @@ def _packed(keys, cfg):
 
 
 # ------------------------------------------------- interpret ≡ ref parity
-@pytest.mark.parametrize("lb_fraction", [0.0, 0.25])
-def test_fused_get_interpret_matches_ref(lb_fraction):
-    cfg = SMALL
-    snap = _loaded_shard(cfg).export_snapshot()
+def _leaf_of(s, key):
+    """Heap row of the leaf the host tree puts ``key`` in."""
+    return s.tree._traverse(*s.tree._pack(key))[-1].phys
+
+
+def _leaf_log(s, key):
+    """(key, op) of each log entry of ``key``'s leaf, in slot order."""
+    h, phys = s.tree.heap, _leaf_of(s, key)
+    return [(s.tree._key_bytes(h.log_keys[phys, j],
+                               int(h.log_keylen[phys, j])),
+             int(h.log_op[phys, j])) for j in range(int(h.nlog[phys]))]
+
+
+def _flush_log(s, key):
+    """Rewrite ``key`` until its leaf's log has merged, so the writes that
+    follow land in an empty log."""
+    for _ in range(s.cfg.log_cap + 1):
+        if not _leaf_log(s, key):
+            return
+        s.update(key, s.tree.get(key, latest=True))
+    raise AssertionError("the leaf's log never merged")
+
+
+def _even_shard():
+    """Even ids 2..240 loaded in order: odd ids fall between stored keys,
+    0 and 1 below every one, ids above 240 above every one."""
+    s = StoreShard(dataclasses.replace(SMALL, sync_policy="explicit"),
+                   heap_capacity=512)
+    for i in range(1, 121):
+        s.put(int_key(2 * i), b"v%06d" % i)
+    return s
+
+
+ABSENT = [int_key(i) for i in (0, 1, 41, 151, 241, 10 ** 6)]
+
+
+def _relog_tree():
+    """Leaf logs that rewrite one key several times: update, delete and
+    re-insert of a stored key, update then delete of another, and insert,
+    delete, re-insert of a key the sorted block lacks.  The read versions
+    are the current one and one pinned before those log entries."""
+    s = _even_shard()
+    ka, kb, kc = int_key(40), int_key(120), int_key(201)
+    for k in (ka, kb, kc):
+        _flush_log(s, k)
+    s.export_snapshot()
+    pinned = s.serving_version
+    s.update(ka, b"a1")
+    s.delete(ka)
+    s.put(ka, b"a2")
+    s.update(kb, b"b1")
+    s.delete(kb)
+    s.put(kc, b"c1")
+    s.delete(kc)
+    s.put(kc, b"c2")
+    s.export_snapshot()
+    for k, n in ((ka, 3), (kb, 2), (kc, 3)):
+        assert [e for e, _ in _leaf_log(s, k)].count(k) == n
+    keys = [ka, kb, kc, int_key(2), int_key(240)] + ABSENT
+    return s, keys, [s.serving_version, pinned]
+
+
+def _walk_left_tree():
+    """A leaf whose first two sorted items are deleted in its log: the
+    SCAN(K, K) spine finds no visible key <= K there and walks into the
+    left sibling for its floor, and the point resolve answers from the
+    leaf alone.  Pinned before the deletes, both keys are found."""
+    s = _even_shard()
+    h = s.tree.heap
+    phys = _leaf_of(s, int_key(120))
+    first = [s.tree._key_bytes(h.skeys[phys, i], int(h.skeylen[phys, i]))
+             for i in range(3)]
+    assert int.from_bytes(first[0], "big") > 2       # a left sibling exists
+    _flush_log(s, first[2])
+    s.export_snapshot()
+    pinned = s.serving_version
+    s.delete(first[0])
+    s.delete(first[1])
+    s.export_snapshot()
+    between = int_key(int.from_bytes(first[0], "big") + 1)
+    return s, first + [between] + ABSENT, [s.serving_version, pinned]
+
+
+def _long_value_tree():
+    """1,000-byte values, read from the snapshot's value image: loaded,
+    rewritten, deleted, and one cut to an inline value."""
+    cfg = dataclasses.replace(SMALL, sync_policy="explicit",
+                              overflow_words=256)
+    rng = np.random.default_rng(16)
+    s = StoreShard(cfg, heap_capacity=256)
+
+    def kb():
+        return rng.integers(0, 256, 1000, dtype=np.uint8).tobytes()
+
+    for i in range(1, 61):
+        s.put(int_key(2 * i), kb())
+    for i in (3, 17, 40):
+        s.update(int_key(2 * i), kb())
+    s.delete(int_key(2 * 9))
+    s.update(int_key(2 * 21), b"short")
+    s.export_snapshot()
+    keys = [int_key(2 * i) for i in (1, 3, 9, 17, 21, 40, 60)] + ABSENT
+    return s, keys, [s.serving_version]
+
+
+def _loaded_tree():
+    s = _loaded_shard(SMALL)
+    snap = s.export_snapshot()
     keys = [int_key(i) for i in (1, 7, 13, 55, 119, 5000)]
+    return s, keys, [int(snap.read_version)]
+
+
+GET_TREES = {"loaded": _loaded_tree, "relog": _relog_tree,
+             "walk_left": _walk_left_tree, "long_values": _long_value_tree}
+
+
+def _gathered(sn, res, meters, cfg):
+    """A GET batch's one packed buffer with its value rows appended, as
+    the shard copies it back (``_pack_read`` + ``gather_values``)."""
+    return np.asarray(shard_mod._jit_gather_values(
+        shard_mod._pack_read(res, meters), sn.values,
+        result_type=GetResult, cfg=cfg))
+
+
+@pytest.mark.parametrize("lb_fraction,tree", [
+    pytest.param(lb, tree, id=str(lb) if tree == "loaded" else f"{lb}-{tree}")
+    for tree in GET_TREES for lb in (0.0, 0.25)])
+def test_fused_get_interpret_matches_ref(lb_fraction, tree):
+    """The GET kernel's point resolve answers what SCAN(K, K) plus the
+    equality pass answers, at the current and a pinned read version, for
+    stored keys, keys absent below, between and above them, keys whose
+    leaf log rewrites them, keys whose leaf's first items are deleted, and
+    1 KB values gathered from the value image."""
+    s, keys, rvs = GET_TREES[tree]()
+    cfg = s.cfg
+    snap = s._snapshot
     lanes, lens = _packed(keys, cfg)
-    want, wm = ops.batched_get_fused(snap, lanes, lens, cfg=cfg,
-                                     lb_fraction=lb_fraction, backend="ref")
-    got, gm = ops.batched_get_fused(snap, lanes, lens, cfg=cfg,
-                                    lb_fraction=lb_fraction,
-                                    backend="interpret")
-    for f in want._fields:
-        np.testing.assert_array_equal(np.asarray(getattr(want, f)),
-                                      np.asarray(getattr(got, f)),
-                                      err_msg=f)
-    np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+    for rv in rvs:
+        sn = snap._replace(read_version=jnp.int32(rv))
+        want, wm = ops.batched_get_fused(sn, lanes, lens, cfg=cfg,
+                                         lb_fraction=lb_fraction,
+                                         backend="ref")
+        got, gm = ops.batched_get_fused(sn, lanes, lens, cfg=cfg,
+                                        lb_fraction=lb_fraction,
+                                        backend="interpret")
+        for f in want._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(want, f)),
+                                          np.asarray(getattr(got, f)),
+                                          err_msg=f)
+        np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+        if rv != rvs[0]:
+            continue
+        # at the current version, the answers are the host tree's
+        found = np.asarray(got.found)
+        current = found
+        assert [bool(x) for x in found] == [s.get(k) is not None
+                                            for k in keys]
+        if sn.values is not None:
+            buf = _gathered(sn, got, gm, cfg)
+            assert np.array_equal(buf, _gathered(sn, want, wm, cfg))
+            (f, v, n), rows = s._fetch_read(buf, GetResult, len(keys), True)
+            assert [s._decode_value(v[i], int(n[i]), rows, (i, 0))
+                    if f[i] else None for i in range(len(keys))] \
+                == [s.get(k) for k in keys]
+            assert s.pipeline_stats.device_values >= 4
+    if tree == "walk_left":
+        # the SCAN spine found its floor in the left sibling
+        sn = snap._replace(read_version=jnp.int32(rvs[0]))
+        res, _ = ref.batched_scan_fused_ref(sn, lanes, lens, lanes, lens,
+                                            cfg=cfg)
+        assert np.asarray(res.count)[:3].tolist() == [1, 1, 1]
+        assert current[:4].tolist() == [False, False, True, False]
 
 
 @pytest.mark.parametrize("lb_fraction", [0.0, 0.25])
@@ -140,46 +298,58 @@ def test_fused_interpret_matches_ref_under_churn(seed):
     assert int(np.asarray(wm)[1]) > 0                 # heap pipe exercised
 
 
-@pytest.mark.parametrize("tree", ["loaded", "churned"])
-@pytest.mark.parametrize("op", ["get", "scan"])
+@pytest.mark.parametrize("op,tree", [
+    ("get", "loaded"), ("scan", "loaded"), ("get", "churned"),
+    ("scan", "churned"), ("get", "relog"), ("get", "walk_left"),
+    ("get", "long_values")])
 def test_fused_tpu_interpreter_matches_ref(op, tree):
     """The TPU interpreter models DMAs, memory spaces and semaphores, fills
     scratch with garbage and raises on out-of-bounds reads — what a compiled
     kernel would read on the chip and the plain interpreter forgives.  Both
     pipes (lb 0.5), on a freshly loaded tree and on a churned tree at an
-    older read version."""
+    older read version; GET also on the point-resolve trees of
+    ``GET_TREES``, at every read version each gives."""
     from jax.experimental.pallas import tpu as pltpu
-    from repro.kernels import fused_read, ref
+    from repro.kernels import fused_read
     cfg = SMALL
-    if tree == "loaded":
-        sn = _loaded_shard(cfg).export_snapshot()
-    else:
-        snap, rvs = _churned_snapshot(0)
-        sn = snap._replace(read_version=jnp.int32(rvs[1]))
-    args = (sn.image, sn.pagetable, sn.root_lid, sn.read_version,
-            sn.cache_lids, sn.cache_image)
     rng = np.random.default_rng(100)
     los = rng.integers(0, 300, 11)
     lo = _packed([int_key(int(a)) for a in los], cfg)
-    if op == "get":
-        want, wm = ref.batched_get_fused_ref(sn, *lo, cfg=cfg,
-                                             lb_fraction=0.5)
-        got, gm = fused_read.batched_get_fused(
-            *args, *lo, cfg=cfg, lb_fraction=0.5,
-            interpret=pltpu.InterpretParams())
+    if tree == "loaded":
+        sns = [_loaded_shard(cfg).export_snapshot()]
+    elif tree == "churned":
+        snap, rvs = _churned_snapshot(0)
+        sns = [snap._replace(read_version=jnp.int32(rvs[1]))]
     else:
-        spans = rng.choice([0, 3, 40, 400], 11)
-        hi = _packed([int_key(int(a + b)) for a, b in zip(los, spans)], cfg)
-        want, wm = ref.batched_scan_fused_ref(sn, *lo, *hi, cfg=cfg,
-                                              lb_fraction=0.5)
-        got, gm = fused_read.batched_scan_fused(
-            *args, *lo, *hi, cfg=cfg, lb_fraction=0.5,
-            interpret=pltpu.InterpretParams())
-    for f in want._fields:
-        np.testing.assert_array_equal(np.asarray(getattr(want, f)),
-                                      np.asarray(getattr(got, f)), err_msg=f)
-    np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
-    assert int(np.asarray(wm)[0]) > 0 and int(np.asarray(wm)[1]) > 0
+        s, keys, rvs = GET_TREES[tree]()
+        cfg = s.cfg
+        lo = _packed(keys, cfg)
+        sns = [s._snapshot._replace(read_version=jnp.int32(rv))
+               for rv in rvs]
+    for sn in sns:
+        args = (sn.image, sn.pagetable, sn.root_lid, sn.read_version,
+                sn.cache_lids, sn.cache_image)
+        if op == "get":
+            want, wm = ref.batched_get_fused_ref(sn, *lo, cfg=cfg,
+                                                 lb_fraction=0.5)
+            got, gm = fused_read.batched_get_fused(
+                *args, *lo, cfg=cfg, lb_fraction=0.5,
+                interpret=pltpu.InterpretParams())
+        else:
+            spans = rng.choice([0, 3, 40, 400], 11)
+            hi = _packed([int_key(int(a + b)) for a, b in zip(los, spans)],
+                         cfg)
+            want, wm = ref.batched_scan_fused_ref(sn, *lo, *hi, cfg=cfg,
+                                                  lb_fraction=0.5)
+            got, gm = fused_read.batched_scan_fused(
+                *args, *lo, *hi, cfg=cfg, lb_fraction=0.5,
+                interpret=pltpu.InterpretParams())
+        for f in want._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(want, f)),
+                                          np.asarray(getattr(got, f)),
+                                          err_msg=f)
+        np.testing.assert_array_equal(np.asarray(wm), np.asarray(gm))
+        assert int(np.asarray(wm)[0]) > 0 and int(np.asarray(wm)[1]) > 0
 
 
 # -------------------------------------- fused ≡ reference, full service

@@ -1,16 +1,21 @@
 """Compile rehearsal: the store's four device kernels compile for a
 described TPU v5e chip at the chip smoke's geometry (``chip_smoke.py``:
 default ``HoneycombConfig``, a 1M-key heap, 256-request read batches,
-1,024-row delta syncs).  Nothing runs: the TPU compiler refuses here
-what Mosaic would refuse on the chip — unaligned block windows, ops it
-cannot lower, more VMEM than a kernel may use.
+1,024-row delta syncs), the GET kernel also at the YCSB configuration's
+(``bench/configs/ycsb-1kb-1m.json``: 1 KB value slots).  Nothing runs:
+the TPU compiler refuses here what Mosaic would refuse on the chip —
+unaligned block windows, ops it cannot lower, more VMEM than a kernel
+may use.  The SCAN program's lowering is pinned by a hash.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
 this file."""
 from __future__ import annotations
 
+import base64
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,7 @@ from repro.core.schema import NodeImageLayout
 from repro.kernels import delta_scatter, fused_read
 
 CFG = HoneycombConfig()
+YCSB = HoneycombConfig(overflow_words=256)
 HEAP_ROWS = 131072         # node slots of the 1M-key store
 PT_LIDS = 131072           # page-table entries
 BATCH = 256                # read requests per device batch
@@ -76,10 +82,12 @@ def shapes(one_chip):
 
 def test_fused_get_compiles(shapes):
     _, _, snap, keys = shapes
-    compiled = _compile(
-        lambda *a: fused_read.batched_get_fused(*a, cfg=CFG), *snap, *keys)
-    # the snapshot is read in place: no image-sized temporary
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    for cfg in (CFG, YCSB):
+        compiled = _compile(
+            lambda *a: fused_read.batched_get_fused(*a, cfg=cfg), *snap,
+            *keys)
+        # the snapshot is read in place: no image-sized temporary
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_fused_scan_compiles(shapes):
@@ -88,6 +96,50 @@ def test_fused_scan_compiles(shapes):
         lambda *a: fused_read.batched_scan_fused(*a, cfg=CFG),
         *snap, *keys, *keys)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+# sha256 of the fused SCAN program for the cloud configuration (default
+# HoneycombConfig, 1M-key heap) at each read bucket, as ``_scan_lowering``
+# prints it.  Re-pin only with a deliberate change to the SCAN kernel or
+# its launcher, and say so where the change is recorded.
+SCAN_LOWERING = {
+    64: "754c5c15fdcddf5e95d570e636a1305743eb4550bdb2ce22b6ee9bdf4a79e469",
+    128: "e1cc3259489c4c1a40b2c0ca16b46de75960cac46522b2dd011f3950e8fd7693",
+    256: "cc5162396e5e778fff98449f71c593688a635b31bb943f3d502c9d057967e64b",
+}
+
+
+def _scan_lowering(snap, keys) -> str:
+    """The lowered SCAN program's text, with the Mosaic kernel's
+    serialized body replaced by the hash of its text printed without
+    source locations (which name the file and line it was traced from)."""
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+    txt = jax.jit(lambda *a: fused_read.batched_scan_fused(*a, cfg=CFG)) \
+        .lower(*snap, *keys, *keys).as_text()
+
+    def body(m):
+        ctx = jmlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            mod = ir.Module.parse(base64.b64decode(m.group(1)))
+            asm = mod.operation.get_asm(enable_debug_info=False)
+        return "body:" + hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, txt)
+
+
+@pytest.mark.parametrize("lanes", sorted(SCAN_LOWERING))
+def test_fused_scan_lowering_pinned(shapes, lanes):
+    """The GET body has its own point resolve; the SCAN body, its launcher
+    and so its lowering are what they were before GET left the SCAN
+    spine."""
+    _, sds, snap, _ = shapes
+    keys = (sds((lanes, CFG.key_words)), sds((lanes,), jnp.int32))
+    text = _scan_lowering(snap, keys)
+    assert text.count("body:") == 1
+    got = hashlib.sha256(text.encode()).hexdigest()
+    assert got == SCAN_LOWERING[lanes], got
 
 
 def test_image_scatter_compiles(shapes):
