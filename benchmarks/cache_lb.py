@@ -7,8 +7,7 @@ The measured half replays a Zipfian hot-key workload through the REAL
 (``heap_gathers``), while ``cfg.lb_fraction`` deterministically routes a
 slice of cache-hit lanes down the heap pipe anyway — the paper's
 dual-pipe load balancer, with the byte split read straight off the device
-meters.  The same workload on ``read_backend="reference"`` gives the
-fused-vs-reference throughput ratio on identical store contents.
+meters.
 
 The modeled half keeps the original host metadata-table sweep (hit rates
 by cache size, two-pipe completion-time model, NoLB ablation) — the
@@ -61,28 +60,18 @@ def _measured_point(cfg: HoneycombConfig, n_items: int, n_ops: int,
 
 
 def run(n_items: int = 8192, n_ops: int = 4096,
-        read_backend: tuple[str, ...] = ("fused", "reference"),
         lb_fractions: tuple[float, ...] = (0.0, 0.25, 0.5)) -> dict:
     results = {}
-    # ---- measured: the real device read path, both backends ----------
-    tput = {}
-    for rb in read_backend:
-        fracs = lb_fractions if rb == "fused" else (0.0,)
-        for frac in fracs:
-            cfg = HoneycombConfig(read_backend=rb, lb_fraction=frac)
-            r = _measured_point(cfg, n_items, n_ops)
-            name = f"measured_{rb}" + (f"_lb{frac:g}" if frac else "")
-            results[name] = r
-            tput.setdefault(rb, r["ops_per_s"])
-            emit(name, 1e6 / r["ops_per_s"],
-                 f"hit={r['device_hit_rate']:.2f} "
-                 f"vmem_B={r['vmem_bytes']} heap_B={r['heap_bytes']} "
-                 f"lb_routed={r['lb_routed']}")
-    if "fused" in tput and "reference" in tput:
-        ratio = tput["fused"] / tput["reference"]
-        results["measured_fused_vs_reference"] = {"tput_ratio": ratio}
-        emit("cache_lb_fused_vs_reference", 0.0,
-             f"tput_ratio={ratio:.2f}x")
+    # ---- measured: the real device read path --------------------------
+    for frac in lb_fractions:
+        r = _measured_point(HoneycombConfig(lb_fraction=frac), n_items,
+                            n_ops)
+        name = "measured_fused" + (f"_lb{frac:g}" if frac else "")
+        results[name] = r
+        emit(name, 1e6 / r["ops_per_s"],
+             f"hit={r['device_hit_rate']:.2f} "
+             f"vmem_B={r['vmem_bytes']} heap_B={r['heap_bytes']} "
+             f"lb_routed={r['lb_routed']}")
     # ---- modeled: host metadata-table sweep (the Fig. 16 shape) ------
     for cache_slots, lb in ((8, True), (64, True), (256, True),
                             (256, False)):

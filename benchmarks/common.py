@@ -111,8 +111,8 @@ def sync_traffic(store) -> dict:
             "read_version_updates": s.read_version_updates,
             "log_entries": s.log_entries,
             "log_wire_bytes": s.log_wire_bytes,
-            # node-image DMA meters (core/schema.py packed layout: ONE
-            # contiguous image-row DMA per dirty node; legacy: one per field)
+            # node-image DMA meters (core/schema.py: ONE contiguous
+            # image-row DMA per dirty node)
             "image_dma_count": s.image_dma_count,
             "image_bytes": s.image_bytes,
             # replica-amplification traffic (follower feed; 0 for the

@@ -1,14 +1,7 @@
 """Fig. 12: latency-throughput.  Load is swept via device batch size; we
 report median per-op latency at each offered batch (read-only 3-item
-scans, the figure's workload).
-
-``read_backend`` sweeps the device read path: "fused" drives the whole
-per-batch traversal through ONE megakernel dispatch with the interior
-cache tier pinned in VMEM (kernels/fused_read.py); "reference" is the
-staged jnp path kept as the tested oracle.  Alongside the throughput
-ratio we report the per-batch dispatched-kernel counts from the launch
-meter (``kernels/ops.read_dispatch_stats`` — the fused path must stay at
-1 launch/batch where the reference path pays one per traversal stage).
+scans, the figure's workload), each batch one fused traversal dispatch
+with the interior cache tier pinned in VMEM (kernels/fused_read.py).
 
 ``pipeline`` adds a second sweep: the same offered batches driven through
 the scheduler's epoch pipeline with a 10% update mix (so every epoch has a
@@ -22,57 +15,30 @@ import time
 import numpy as np
 
 from .common import build_stores, emit, run_scheduled, uniform_sampler
-from repro.core import HoneycombConfig
 from repro.core.keys import int_key
-from repro.kernels import ops as kernel_ops
 
 BATCHES = (8, 32, 128, 512)
 
 
 def run(n_items: int = 4096, reps: int = 8,
-        pipeline: tuple[str, ...] = (),
-        read_backend: tuple[str, ...] = ("fused", "reference")) -> dict:
+        pipeline: tuple[str, ...] = ()) -> dict:
     results = {}
-    top_tput = {}                 # backend -> ops/s at the largest batch
-    for rb in read_backend:
-        hc, _ = build_stores(n_items, baseline=False,
-                             cfg=HoneycombConfig(read_backend=rb))
-        sampler = uniform_sampler(n_items, seed=9)
-        kernel_ops.reset_read_dispatches()
-        for batch in BATCHES:
-            lats = []
-            for _ in range(reps):
-                ks = sampler(batch)
-                ranges = [(int_key(int(k)),
-                           int_key(min(int(k) + 3, n_items - 1)))
-                          for k in ks]
-                t0 = time.perf_counter()
-                hc.scan_batch(ranges)
-                lats.append((time.perf_counter() - t0) / batch)
-            med = float(np.median(lats)) * 1e6
-            tput = batch / (np.median(lats) * batch)
-            key = batch if rb == "fused" else f"b{batch}/{rb}"
-            results[key] = {"median_us_per_op": med, "ops_per_s": tput,
-                            "read_backend": rb}
-            top_tput[rb] = tput
-            suffix = "" if rb == "fused" else f"_{rb}"
-            emit(f"latency_b{batch}{suffix}", med, f"ops_s={tput:.0f}")
-        # per-op dispatched-kernel counts from the launch meter: the fused
-        # megakernel's whole-traversal claim, measured not asserted
-        ds = kernel_ops.read_dispatch_stats()
-        results[f"dispatch/{rb}"] = ds
-        for op_key, d in sorted(ds.items()):
-            emit(f"latency_dispatch_{op_key}", 0.0,
-                 f"launches/batch={d['per_batch']:.1f} "
-                 f"batches={d['batches']}")
-    if "fused" in top_tput and "reference" in top_tput:
-        ratio = top_tput["fused"] / top_tput["reference"]
-        results["fused_vs_reference"] = {
-            "tput_ratio": ratio,
-            "batch": max(BATCHES),
-            "fused_ops_s": top_tput["fused"],
-            "reference_ops_s": top_tput["reference"]}
-        emit("latency_fused_vs_reference", 0.0, f"tput_ratio={ratio:.2f}x")
+    hc, _ = build_stores(n_items, baseline=False)
+    sampler = uniform_sampler(n_items, seed=9)
+    for batch in BATCHES:
+        lats = []
+        for _ in range(reps):
+            ks = sampler(batch)
+            ranges = [(int_key(int(k)),
+                       int_key(min(int(k) + 3, n_items - 1)))
+                      for k in ks]
+            t0 = time.perf_counter()
+            hc.scan_batch(ranges)
+            lats.append((time.perf_counter() - t0) / batch)
+        med = float(np.median(lats)) * 1e6
+        tput = batch / (np.median(lats) * batch)
+        results[batch] = {"median_us_per_op": med, "ops_per_s": tput}
+        emit(f"latency_b{batch}", med, f"ops_s={tput:.0f}")
     for mode in pipeline:
         for batch in BATCHES:
             hp, _ = build_stores(n_items, baseline=False)

@@ -8,21 +8,16 @@ republish moves the entire store — the log block plus batched page-table
 commands are exactly what make the delta small (the paper's PCIe
 amortization argument, now measurable end to end).
 
-The node-image DMA accounting rides the same curve: on the packed layout
-(core/schema.py) every dirty node crosses as ONE contiguous image-row DMA
-of ``node_image_bytes`` (the paper's whole-node transfer); the legacy
-per-field layout moves the same bytes in one scatter per field per node.
-``layout_compare`` drives BOTH layouts with identical traffic and reports
-bytes-per-dirty-node and DMA-invocation counts side by side — the
-DMA-collapse factor is exactly the per-node field count."""
+The node-image DMA accounting rides the same curve: every dirty node
+crosses as ONE contiguous image-row DMA of ``node_image_bytes``
+(core/schema.py; the paper's whole-node transfer)."""
 from __future__ import annotations
 
 import time
 
 import numpy as np
 
-from repro.core import (HoneycombConfig, HoneycombStore, NodeImageLayout,
-                        FIELD_NAMES)
+from repro.core import HoneycombConfig, HoneycombStore, NodeImageLayout
 from repro.core.keys import int_key
 from .common import emit, uniform_sampler
 
@@ -36,8 +31,8 @@ def sync_traffic_curve(st: HoneycombStore, n_items: int) -> dict:
     a log-structured delta encoding would move; dirty-node deltas transfer
     whole node images and sit between that bound and a full republish.
     Each batch also reports its node-image DMA meters: invocations, dirty
-    nodes, and bytes per dirty node (== node_image_bytes by construction;
-    the layouts differ only in the DMA *count*)."""
+    nodes, and bytes per dirty node (== node_image_bytes by construction,
+    one DMA each)."""
     layout = NodeImageLayout.for_config(st.cfg)
     st.export_snapshot()                      # make the snapshot resident
     curve = {}
@@ -71,87 +66,40 @@ def sync_traffic_curve(st: HoneycombStore, n_items: int) -> dict:
     return curve
 
 
-def layout_compare(n_items: int, writes: int | None = None) -> dict:
-    """Packed vs legacy on IDENTICAL traffic: same seed, same load, same
-    write batch; report DMA invocations and bytes per dirty node for each
-    layout plus the collapse factor (legacy_dmas / packed_dmas == the
-    per-node field count — the counter the packed layout exists to fix)."""
-    writes = writes or min(128, max(16, n_items // 16))
-    out = {}
-    for lt in ("packed", "legacy"):
-        cfg = HoneycombConfig(layout=lt)
-        layout = NodeImageLayout.for_config(cfg)
-        st = HoneycombStore(cfg)
+def run(n_items: int = 2048, n_ops: int = 1024) -> dict:
+    results = {}
+    for log_cap in (2, 8, 16, 32):
+        st = HoneycombStore(HoneycombConfig(log_cap=log_cap))
         rng = np.random.default_rng(0)
         for i in rng.permutation(n_items):
             st.put(int_key(int(i)), b"v" * 16)
+        # insert throughput
+        ks = rng.integers(n_items, 2 * n_items, n_ops)
+        t0 = time.perf_counter()
+        for k in ks:
+            st.put(int_key(int(k)), b"v" * 16)
+        ins = n_ops / (time.perf_counter() - t0)
+        syncs = st.tree.pt.sync_commands
+        # 1-item scan throughput
         st.export_snapshot()
-        d0 = st.sync_stats.image_dma_count
-        i0 = st.sync_stats.image_bytes
-        b0 = st.sync_stats.bytes_synced
-        for k in rng.integers(0, n_items, writes):
-            st.update(int_key(int(k)), b"u" * 16)
-        st.export_snapshot()
-        dmas = st.sync_stats.image_dma_count - d0
-        node_bytes = st.sync_stats.image_bytes - i0
-        dirty = node_bytes // layout.node_image_bytes
-        out[lt] = {"image_dmas": dmas, "dirty_nodes": dirty,
-                   "node_bytes": node_bytes,
-                   "delta_bytes": st.sync_stats.bytes_synced - b0,
-                   "bytes_per_dirty_node": node_bytes / max(dirty, 1),
-                   "dmas_per_dirty_node": dmas / max(dirty, 1)}
-        emit(f"layout_{lt}_w{writes}", dmas,
-             f"dmas={dmas} dirty={dirty} "
-             f"B/node={out[lt]['bytes_per_dirty_node']:.0f} "
-             f"dma/node={out[lt]['dmas_per_dirty_node']:.1f}")
-    out["dma_collapse"] = (out["legacy"]["image_dmas"]
-                           / max(out["packed"]["image_dmas"], 1))
-    emit("layout_dma_collapse", out["dma_collapse"],
-         f"legacy/packed DMA ratio={out['dma_collapse']:.1f} "
-         f"(fields/node={len(FIELD_NAMES)})")
-    return out
-
-
-def run(n_items: int = 2048, n_ops: int = 1024,
-        layout: tuple[str, ...] = ("packed",)) -> dict:
-    results = {}
-    for lt in layout:
-        for log_cap in (2, 8, 16, 32):
-            cfg = HoneycombConfig(log_cap=log_cap, layout=lt)
-            st = HoneycombStore(cfg)
-            rng = np.random.default_rng(0)
-            for i in rng.permutation(n_items):
-                st.put(int_key(int(i)), b"v" * 16)
-            # insert throughput
-            ks = rng.integers(n_items, 2 * n_items, n_ops)
-            t0 = time.perf_counter()
-            for k in ks:
-                st.put(int_key(int(k)), b"v" * 16)
-            ins = n_ops / (time.perf_counter() - t0)
-            syncs = st.tree.pt.sync_commands
-            # 1-item scan throughput
-            st.export_snapshot()
-            sampler = uniform_sampler(n_items, 17)
-            t0 = time.perf_counter()
-            for i in range(0, n_ops, 256):
-                ks2 = [int_key(int(k)) for k in sampler(min(256, n_ops - i))]
-                st.scan_batch([(k, k) for k in ks2])
-            sc = n_ops / (time.perf_counter() - t0)
-            curve = sync_traffic_curve(st, n_items)
-            key = log_cap if len(layout) == 1 else f"{lt}_{log_cap}"
-            results[key] = {"layout": lt, "insert_ops_s": ins,
-                            "scan_ops_s": sc, "pt_syncs": syncs,
-                            "sync_traffic": curve}
-            tag = f"logcap_{log_cap}" + ("" if len(layout) == 1 else f"_{lt}")
-            emit(tag, 1e6 / ins,
-                 f"insert={ins:.0f}/s scan={sc:.0f}/s syncs={syncs}")
-            for w, c in curve.items():
-                emit(f"{tag}_sync_w{w}", c["delta_bytes"],
-                     f"delta={c['delta_bytes']}B full={c['full_bytes']}B "
-                     f"wire={c['wire_bytes']}B ratio={c['ratio']:.4f} "
-                     f"dmas={c['image_dmas']} dirty={c['dirty_nodes']} "
-                     f"B/node={c['bytes_per_dirty_node']:.0f}")
-    results["layout_compare"] = layout_compare(n_items)
+        sampler = uniform_sampler(n_items, 17)
+        t0 = time.perf_counter()
+        for i in range(0, n_ops, 256):
+            ks2 = [int_key(int(k)) for k in sampler(min(256, n_ops - i))]
+            st.scan_batch([(k, k) for k in ks2])
+        sc = n_ops / (time.perf_counter() - t0)
+        curve = sync_traffic_curve(st, n_items)
+        results[log_cap] = {"insert_ops_s": ins, "scan_ops_s": sc,
+                            "pt_syncs": syncs, "sync_traffic": curve}
+        tag = f"logcap_{log_cap}"
+        emit(tag, 1e6 / ins,
+             f"insert={ins:.0f}/s scan={sc:.0f}/s syncs={syncs}")
+        for w, c in curve.items():
+            emit(f"{tag}_sync_w{w}", c["delta_bytes"],
+                 f"delta={c['delta_bytes']}B full={c['full_bytes']}B "
+                 f"wire={c['wire_bytes']}B ratio={c['ratio']:.4f} "
+                 f"dmas={c['image_dmas']} dirty={c['dirty_nodes']} "
+                 f"B/node={c['bytes_per_dirty_node']:.0f}")
     return results
 
 

@@ -15,14 +15,7 @@ read-throughput-vs-replicas and sync-bytes-amplification curves.
 ``--feed log,delta`` sweeps the follower feed (log-shipped wire-stream
 replay vs dirty-image-row delta) and ``--relay-depth 0,2`` the relay-tree
 depth the feed payload fans out through, for the replicated sections.
-``--layout packed,legacy`` sweeps the device-resident snapshot layout for
-the sections that meter node-image DMA traffic (log-block), comparing the
-packed one-DMA-per-dirty-node format against the legacy per-field scatters
-on identical traffic.  ``--read-backend fused,reference`` sweeps the
-device read path for the read-path sections (YCSB, latency, cache-lb):
-fused whole-traversal megakernels with the VMEM-pinned cache tier vs the
-staged jnp reference, with dispatched-launch counts from the new meter.  ``--tiny`` shrinks every section's workload for CI
-smoke runs.  A summary
+``--tiny`` shrinks every section's workload for CI smoke runs.  A summary
 table of every section's sync meters (log entries, wire bytes, sync bytes,
 replica amplification) prints after the sweep; ``--metrics`` adds a second
 table sourced from the telemetry REGISTRY snapshots the scheduled sections
@@ -174,15 +167,6 @@ def main() -> None:
                     help="comma-separated relay-tree depths to sweep for "
                          "the replicated sections (e.g. 0,2); empty uses "
                          "the flat primary-feeds-all topology")
-    ap.add_argument("--read-backend", default="",
-                    help="comma-separated device read backends to sweep for "
-                         "the read-path sections (e.g. fused,reference): "
-                         "fused = whole-traversal megakernels with the "
-                         "VMEM-pinned cache tier, reference = staged jnp "
-                         "oracle; empty uses each section's default")
-    ap.add_argument("--layout", default="packed",
-                    help="comma-separated snapshot layouts to sweep for the "
-                         "layout-aware sections (e.g. packed,legacy)")
     ap.add_argument("--metrics", action="store_true",
                     help="print a registry metrics summary table (hit "
                          "rates, DMA counts, stall fraction, lane "
@@ -202,8 +186,6 @@ def main() -> None:
     replicas = tuple(int(r) for r in args.replicas.split(",") if r)
     feed = tuple(f for f in args.feed.split(",") if f)
     relay_depth = tuple(int(d) for d in args.relay_depth.split(",") if d != "")
-    layout = tuple(m for m in args.layout.split(",") if m)
-    read_backend = tuple(b for b in args.read_backend.split(",") if b)
     only = tuple(t for t in (args.only or "").split(",") if t)
     if args.metrics:
         common.TRACE_SAMPLE_RATE = 1 / 16   # every 16th request traced
@@ -223,10 +205,6 @@ def main() -> None:
             kwargs["feed"] = feed
         if "relay_depth" in params and relay_depth:
             kwargs["relay_depth"] = relay_depth
-        if "layout" in params and layout:
-            kwargs["layout"] = layout
-        if "read_backend" in params and read_backend:
-            kwargs["read_backend"] = read_backend
         if args.tiny:
             kwargs.update({k: v for k, v in TINY.items() if k in params})
         print(f"# --- {name} ---", flush=True)

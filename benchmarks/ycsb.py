@@ -39,9 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.core import HoneycombConfig
 from repro.core.keys import int_key
-from repro.kernels import ops as kernel_ops
 
 from .common import (TDP_BASELINE_W, TDP_HONEYCOMB_W, build_stores, emit,
                      run_mixed, run_scheduled, uniform_sampler, zipf_sampler)
@@ -61,39 +59,8 @@ def run(n_items: int = 4096, n_ops: int = 2048,
         pipeline: tuple[str, ...] = (),
         replicas: tuple[int, ...] = (),
         feed: tuple[str, ...] = (),
-        relay_depth: tuple[int, ...] = (),
-        read_backend: tuple[str, ...] = ()) -> dict:
+        relay_depth: tuple[int, ...] = ()) -> dict:
     results = {}
-    # read-backend axis: the read-heavy workloads through the fused
-    # megakernel path (ONE dispatch per batch, cache tier in VMEM —
-    # kernels/fused_read.py) vs the staged jnp reference, on identical
-    # store contents; dispatched-launch counts come from the launch meter
-    rb_tput = {}
-    for rb in read_backend:
-        hb, _ = build_stores(n_items, baseline=False,
-                             cfg=HoneycombConfig(read_backend=rb))
-        kernel_ops.reset_read_dispatches()
-        for wl in ("C", "B"):
-            r = run_mixed(hb, zipf_sampler(n_items, seed=3), n_ops=n_ops,
-                          n_items=n_items, **WORKLOADS[wl])
-            rb_tput[(wl, rb)] = r["ops_per_s"]
-            cs = hb.cache.stats
-            results[f"{wl}/zipfian/{rb}"] = {
-                "honeycomb_ops_s": r["ops_per_s"], "read_backend": rb,
-                "device_hit_rate": cs.device_hit_rate,
-                "vmem_hits": cs.vmem_hits, "heap_gathers": cs.heap_gathers,
-                "sync": r["sync"]}
-            emit(f"ycsb_{wl}_zipfian_{rb}", 1e6 / r["ops_per_s"],
-                 f"ops_s={r['ops_per_s']:.0f} "
-                 f"hit={cs.device_hit_rate:.2f} "
-                 f"vmem={cs.vmem_hits} heap={cs.heap_gathers}")
-        results[f"dispatch/{rb}"] = kernel_ops.read_dispatch_stats()
-    for wl in ("C", "B"):
-        if (wl, "fused") in rb_tput and (wl, "reference") in rb_tput:
-            ratio = rb_tput[(wl, "fused")] / rb_tput[(wl, "reference")]
-            results[f"{wl}/fused_vs_reference"] = {"tput_ratio": ratio}
-            emit(f"ycsb_{wl}_fused_vs_reference", 0.0,
-                 f"tput_ratio={ratio:.2f}x")
     # feed axis: write-heavy A over log vs delta follower feeds and relay
     # depths — per-follower feed bytes per epoch is the amplification
     # artifact (acceptance: pure log feed <= 10% of the delta feed's,

@@ -27,17 +27,12 @@
 #                              # --replicas 1,2 read-spreading sweep and a
 #                              # --feed log,delta x --relay-depth 0,2
 #                              # follower-feed amplification sweep, the
-#                              # log-block sweep on BOTH snapshot layouts
-#                              # (packed one-DMA-per-dirty-node vs legacy
-#                              # per-field), a --read-backend
-#                              # fused,reference sweep of the device read
-#                              # path (fused megakernels + VMEM cache tier
-#                              # vs the jnp reference), and both
-#                              # store_dryrun LIVE smokes (sharded +
-#                              # replicated with the log-shipped feed
-#                              # engaged and fused-vs-reference equality
-#                              # + vmem_hits asserted) on the packed
-#                              # layout, with telemetry asserts: the
+#                              # log-block sweep (one DMA per dirty node),
+#                              # and both store_dryrun LIVE smokes
+#                              # (sharded + replicated with the
+#                              # log-shipped feed engaged, fused reads
+#                              # equal to the host tree and vmem_hits
+#                              # asserted), with telemetry asserts: the
 #                              # Prometheus export parses, key meters are
 #                              # nonzero, and a sampled replicated trace
 #                              # carries the full submit->resolve span
@@ -69,9 +64,8 @@ if [[ "${1:-}" == "--smoke" ]]; then
         service_api,fig10_ycsb,fig12_latency,fig17_log_block \
         --tiny --pipeline serial,pipelined --replicas 1,2 \
         --feed log,delta --relay-depth 0,2 \
-        --layout packed,legacy --read-backend fused,reference \
         --metrics --strict
-    # live deployment-shape smokes on the packed layout: assert the
+    # live deployment-shape smokes: assert the
     # one-image-DMA-per-dirty-node invariant survives the full stack,
     # and that the replicated store actually shipped (and replayed) the
     # log feed rather than silently regressing to image-row deltas
@@ -79,22 +73,21 @@ if [[ "${1:-}" == "--smoke" ]]; then
 import json
 from repro.launch.store_dryrun import live_replicated_smoke, live_sharded_smoke
 sh = live_sharded_smoke(shards=2, n_items=256, batch=32)
-assert sh["layout"] == "packed" and sh["image_dma_count"] > 0, sh
+assert sh["image_dma_count"] > 0, sh
 # fused read path: the cache tier actually served descend levels from
-# VMEM, and the smoke's in-place fused-vs-reference equality held
-assert sh["read_path"]["backend"] == "fused", sh
+# VMEM, and the fused answers equalled the host tree's
 assert sh["read_path"]["vmem_hits"] > 0, sh
-assert sh["read_path"]["fused_matches_reference"], sh
+assert sh["read_path"]["fused_matches_host_tree"], sh
 rp = live_replicated_smoke(shards=2, replicas=2, n_items=256, batch=32)
-assert rp["layout"] == "packed" and rp["primary_image_dmas"] > 0, rp
+assert rp["primary_image_dmas"] > 0, rp
 feed = rp["feed"]
 assert feed["log_feed_epochs"] > 0 and feed["log_replays"] > 0, feed
 assert feed["log_bytes"] > 0 and feed["wire_bytes"] > 0, feed
 # followers inherited the cache tier over the feeds and their fused
-# reads matched the reference fallback
+# reads matched the host tree
 assert rp["read_path"]["vmem_hits"] > 0, rp
 assert rp["read_path"]["followers_cache_resident"], rp
-assert rp["read_path"]["fused_matches_reference"], rp
+assert rp["read_path"]["fused_matches_host_tree"], rp
 # telemetry (core/telemetry.py): the Prometheus export must PARSE and the
 # key meters of every wired stats surface must be live on the smokes
 from repro.core import parse_prometheus, prom_value
@@ -103,8 +96,11 @@ for label, smoke in (("sharded", sh), ("replicated", rp)):
     pv = parse_prometheus(tele["prometheus"])
     for meter in ("hc_sync_bytes_synced", "hc_sync_image_dma_count",
                   "hc_tree_puts", "hc_cache_vmem_hits",
-                  "hc_pipeline_flips", "hc_read_batches"):
+                  "hc_pipeline_flips"):
         assert prom_value(pv, meter) > 0, (label, meter, tele["prometheus"])
+    # device read batches the shards dispatched (PipelineStats.read_batches)
+    assert prom_value(pv, "hc_pipeline_read_batches", src="store") > 0, \
+        (label, tele["prometheus"])
     # the read-dispatch split: host time blocked on the device's answers
     assert prom_value(pv, "hc_pipeline_fetch_s", src="store") > 0, \
         (label, tele["prometheus"])

@@ -10,10 +10,9 @@ execution, no compilation) and audits the jaxpr:
     dispatch: a callback re-enters Python mid-batch and breaks the
     one-dispatch-per-batch budget the paper's PCIe accounting assumes.
   * ``kernel-inplace-alias`` — every in-place scatter
-    (``snapshot_image_scatter``, ``log_replay_scatter``,
-    ``snapshot_multi_scatter``) must declare ``input_output_aliases`` on
-    its ``pallas_call``: without donation the scatter materializes a
-    second store-sized image per sync.
+    (``snapshot_delta_scatter``, ``log_replay_scatter``) must declare
+    ``input_output_aliases`` on its ``pallas_call``: without donation
+    the scatter materializes a second store-sized image per sync.
   * ``kernel-single-dispatch`` — the fused read megakernels lower to
     EXACTLY one ``pallas_call``: the whole point of PR 8's fusion is one
     launch per batch, and a refactor that splits the traversal back into
@@ -181,22 +180,8 @@ def kernel_entries() -> list[KernelEntry]:
     def delta():
         from repro.kernels.delta_scatter import snapshot_delta_scatter
         return (snapshot_delta_scatter,
-                (_abstract((S, KW), u32), _abstract((E,), i32),
-                 _abstract((E, KW), u32)), {})
-
-    def image():
-        from repro.kernels.delta_scatter import snapshot_image_scatter
-        return (snapshot_image_scatter,
                 (_abstract((S, IW), u32), _abstract((E,), i32),
                  _abstract((E, IW), u32)), {})
-
-    def multi():
-        from repro.kernels.delta_scatter import snapshot_multi_scatter
-        dsts = tuple(_abstract((S, KW), u32) for _ in range(3))
-        upds = tuple(_abstract((E, KW), u32) for _ in range(3))
-        return (lambda rows, *flat: snapshot_multi_scatter(
-                    flat[:3], rows, flat[3:]),
-                (_abstract((E,), i32), *dsts, *upds), {})
 
     def log_replay():
         from repro.kernels.delta_scatter import log_replay_scatter
@@ -256,10 +241,6 @@ def kernel_entries() -> list[KernelEntry]:
     return [
         KernelEntry("delta_scatter.snapshot_delta_scatter",
                     f"{k}/delta_scatter.py", delta, in_place=True),
-        KernelEntry("delta_scatter.snapshot_image_scatter",
-                    f"{k}/delta_scatter.py", image, in_place=True),
-        KernelEntry("delta_scatter.snapshot_multi_scatter",
-                    f"{k}/delta_scatter.py", multi, in_place=True),
         KernelEntry("delta_scatter.log_replay_scatter",
                     f"{k}/delta_scatter.py", log_replay, in_place=True),
         KernelEntry("fused_read.batched_get_fused",

@@ -17,8 +17,7 @@ from .store import HoneycombStore, SyncStats
 from .replica import FeedStats, FollowerReplica, ReplicaGroup
 from .router import (ShardedHoneycombStore, aggregate_stats,
                      uniform_int_boundaries)
-from .read_path import (TreeSnapshot, SnapshotDelta, LegacyTreeSnapshot,
-                        LegacySnapshotDelta, ScanResult, GetResult,
+from .read_path import (TreeSnapshot, SnapshotDelta, ScanResult, GetResult,
                         apply_snapshot_delta, batched_get, batched_scan,
                         descend, log_sort_positions, snapshot_fields)
 from .schema import (FIELD_NAMES, NARROWED_FIELDS, NODE_SCHEMA, FieldSpec,
@@ -37,8 +36,7 @@ __all__ = [
     "Get", "Scan", "Put", "Update", "Delete", "Response", "Ticket",
     "Routing", "HoneycombService", "decode_wire", "decode_wire_stream",
     "wire_entry_nbytes", "WIRE_ENTRY_OVERHEAD", "WireDecodeError",
-    "TreeSnapshot", "SnapshotDelta", "LegacyTreeSnapshot",
-    "LegacySnapshotDelta", "ScanResult", "GetResult",
+    "TreeSnapshot", "SnapshotDelta", "ScanResult", "GetResult",
     "apply_snapshot_delta", "batched_get", "batched_scan",
     "descend", "log_sort_positions", "snapshot_fields",
     "FieldSpec", "NODE_SCHEMA", "FIELD_NAMES", "NARROWED_FIELDS",

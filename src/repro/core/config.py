@@ -25,20 +25,6 @@ from __future__ import annotations
 import dataclasses
 
 
-# device-resident snapshot layouts (HoneycombConfig.layout)
-LAYOUTS = ("packed", "legacy")
-
-# device read-path backends (HoneycombConfig.read_backend):
-#   "fused"     — ONE fused traversal dispatch per read batch: descend +
-#                 leaf resolve + log merge + version resolution execute as a
-#                 single device call against the packed node image, with the
-#                 top interior levels resolved from the snapshot's VMEM-pinned
-#                 cache array (kernels/fused_read.py).
-#   "reference" — today's per-level jnp path (core/read_path.py), kept as the
-#                 tested op-for-op oracle the fused path is checked against.
-READ_BACKENDS = ("fused", "reference")
-
-
 def bucket_pow2(n: int) -> int:
     """Round a batch/delta length up to a power of two (1 for n <= 1).
 
@@ -100,15 +86,10 @@ class HoneycombConfig:
     # dirty-row fraction above which a delta sync would move more bytes than
     # a wholesale republish is worth; fall back to a full publish
     delta_full_threshold: float = 0.5
-    # device-resident snapshot representation (core/schema.py):
-    # "packed": ONE contiguous u32 node image per slot — a dirty node syncs
-    #           as a single image-row DMA (the paper's 8 KB node transfer);
-    # "legacy": per-field arrays — one row scatter per field, kept as the
-    #           packed layout's op-for-op parity reference.
+    # The device snapshot is the packed node image (core/schema.py) and
+    # reads run the fused kernels (kernels/fused_read.py): these two keys
+    # name that one path and take no other value.
     layout: str = "packed"
-    # device read-path backend (see READ_BACKENDS above); "fused" falls back
-    # to the reference path automatically on legacy-layout snapshots, which
-    # carry no packed image for the megakernel to traverse
     read_backend: str = "fused"
 
     def __post_init__(self):
@@ -121,11 +102,14 @@ class HoneycombConfig:
         assert 0.0 < self.delta_full_threshold <= 1.0, (
             "delta_full_threshold is a dirty fraction in (0, 1]")
         assert self.sync_every_k >= 1, "sync_every_k must be >= 1"
-        assert self.layout in LAYOUTS, (
-            f"unknown snapshot layout {self.layout!r} (one of {LAYOUTS})")
-        assert self.read_backend in READ_BACKENDS, (
-            f"unknown read_backend {self.read_backend!r} "
-            f"(one of {READ_BACKENDS})")
+        if self.layout != "packed":
+            raise ValueError(f"layout={self.layout!r}: only the packed node "
+                             "image remains; the per-field layout was "
+                             "removed")
+        if self.read_backend != "fused":
+            raise ValueError(f"read_backend={self.read_backend!r}: only the "
+                             "fused read kernels remain; the per-level "
+                             "device read path was removed")
         assert self.cache_levels >= 1, "cache the root level at least"
         assert 0.0 <= self.lb_fraction <= 1.0, (
             "lb_fraction is a routed fraction in [0, 1]")

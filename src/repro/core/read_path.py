@@ -23,16 +23,16 @@ All shapes are static; versions are int32 on device (the paper uses 64-bit
 with 5-byte log deltas; 32-bit covers any single snapshot's window and the
 host keeps the authoritative 64-bit counters).
 
-Snapshot layouts: the default device-resident representation is the PACKED
-node image (core/schema.py) — one contiguous ``[S, image_words]`` u32 array
-holding every per-node field at a static word offset, the reproduction's
-analogue of the paper's contiguous 8 KB node buffer.  The pre-packing
-per-field representation survives as ``LegacyTreeSnapshot`` /
-``LegacySnapshotDelta`` (selected by ``cfg.layout="legacy"``) and is the
-parity reference the equivalence tests hold the packed layout to.  All
-search/scan code below is layout-agnostic: it reads fields through
-``snapshot_fields()``, which decodes packed images via the layout's static
-offsets and passes legacy snapshots through untouched.
+The device-resident snapshot is the PACKED node image (core/schema.py) —
+one contiguous ``[S, image_words]`` u32 array holding every per-node field
+at a static word offset, the reproduction's analogue of the paper's
+contiguous 8 KB node buffer.  The search/scan code below reads fields
+through ``snapshot_fields()``, which decodes the image via the layout's
+static offsets.  The store serves reads with the fused kernels
+(kernels/fused_read.py); ``batched_get``/``batched_scan`` here are the
+per-level oracle the tests hold them to, and ``descend_fused`` /
+``scan_from_leaf`` / ``get_from_scan`` build the fused kernels' own jnp
+oracle (kernels/ref.py).
 """
 from __future__ import annotations
 
@@ -59,8 +59,7 @@ class TreeSnapshot(NamedTuple):
     feeds (~KB); ``cache_image`` is rebuilt device-side from the resident
     image via ``attach_cache_image`` wherever a snapshot is (re)staged, so
     its rows are bit-identical to the version-resolved heap rows by
-    construction.  ``None`` on legacy-era snapshots (fused reads fall back
-    to the reference path).
+    construction.  Every snapshot carries them.
 
     ``values`` is the device value image: one row of ``overflow_words``
     words per out-of-node value slot (core/heap.py ``OverflowHeap``), the
@@ -72,58 +71,17 @@ class TreeSnapshot(NamedTuple):
     pagetable: jax.Array    # i32 [LIDS]
     root_lid: jax.Array     # i32 []
     read_version: jax.Array  # i32 []
-    cache_lids: jax.Array | None = None   # i32 [C], NULL-padded
-    cache_image: jax.Array | None = None  # u32 [C, image_words]
+    cache_lids: jax.Array   # i32 [C], NULL-padded
+    cache_image: jax.Array  # u32 [C, image_words]
     values: jax.Array | None = None       # u32 [V, overflow_words]
 
 
-class LegacyTreeSnapshot(NamedTuple):
-    """Per-field device image (the pre-packing layout, cfg.layout="legacy"):
-    kept as the packed layout's op-for-op parity reference."""
-    ntype: jax.Array        # i32 [S]
-    nitems: jax.Array       # i32 [S]
-    version: jax.Array      # i32 [S]
-    oldptr: jax.Array       # i32 [S]
-    left_child: jax.Array   # i32 [S]
-    lsib: jax.Array         # i32 [S]
-    rsib: jax.Array         # i32 [S]
-    skeys: jax.Array        # u32 [S, N, KW]
-    skeylen: jax.Array      # i32 [S, N]
-    svals: jax.Array        # u32 [S, N, VW]
-    svallen: jax.Array      # i32 [S, N]
-    n_shortcuts: jax.Array  # i32 [S]
-    sc_keys: jax.Array      # u32 [S, NSC, KW]
-    sc_keylen: jax.Array    # i32 [S, NSC]
-    sc_pos: jax.Array       # i32 [S, NSC]
-    nlog: jax.Array         # i32 [S]
-    log_keys: jax.Array     # u32 [S, L, KW]
-    log_keylen: jax.Array   # i32 [S, L]
-    log_vals: jax.Array     # u32 [S, L, VW]
-    log_vallen: jax.Array   # i32 [S, L]
-    log_op: jax.Array       # i32 [S, L]
-    log_backptr: jax.Array  # i32 [S, L]
-    log_hint: jax.Array     # i32 [S, L]
-    log_vdelta: jax.Array   # i32 [S, L]
-    pagetable: jax.Array    # i32 [LIDS]
-    root_lid: jax.Array     # i32 []
-    read_version: jax.Array  # i32 []
-    values: jax.Array | None = None   # u32 [V, overflow_words], as packed
-
-
-# per-node-row snapshot fields, in layout order — derived from the ONE
-# schema (core/schema.py), not re-enumerated
-NODE_FIELDS = FIELD_NAMES
-
-
 class SnapshotFields:
-    """Layout-agnostic per-field view of a snapshot.
-
-    For a packed ``TreeSnapshot`` each attribute is a static column slice
-    of the image decoded to the field's device dtype (bitcast for signed
-    fields, so NULL = -1 survives the u32 transit); XLA folds the slices
-    into the downstream gathers, so the search engines read exactly the
-    bytes they always did.  Legacy snapshots already expose the attributes
-    and pass through ``snapshot_fields`` untouched.
+    """Per-field view of a snapshot: each attribute is a static column
+    slice of the packed image decoded to the field's device dtype (bitcast
+    for signed fields, so NULL = -1 survives the u32 transit); XLA folds
+    the slices into the downstream gathers, so the search engines read
+    exactly the bytes they always did.
     """
     __slots__ = FIELD_NAMES + ("pagetable", "root_lid", "read_version")
 
@@ -132,16 +90,13 @@ class SnapshotFields:
             object.__setattr__(self, k, v)
 
 
-def snapshot_fields(snap, cfg: HoneycombConfig):
-    """Adapt any snapshot (packed, legacy, or an existing view) to
-    per-field attribute access."""
-    if isinstance(snap, TreeSnapshot):
-        layout = NodeImageLayout.for_config(cfg)
-        return SnapshotFields(pagetable=snap.pagetable,
-                              root_lid=snap.root_lid,
-                              read_version=snap.read_version,
-                              **layout.field_views(snap.image))
-    return snap
+def snapshot_fields(snap: TreeSnapshot,
+                    cfg: HoneycombConfig) -> SnapshotFields:
+    """The per-field view of a packed snapshot."""
+    layout = NodeImageLayout.for_config(cfg)
+    return SnapshotFields(pagetable=snap.pagetable, root_lid=snap.root_lid,
+                          read_version=snap.read_version,
+                          **layout.field_views(snap.image))
 
 
 def attach_cache_image(snap, cfg: HoneycombConfig):
@@ -152,10 +107,8 @@ def attach_cache_image(snap, cfg: HoneycombConfig):
     Called wherever a snapshot is staged — primary export, delta apply,
     follower log replay — so only the ~KB ``cache_lids`` vector ever
     travels on a feed while every serving copy's ``cache_image`` rows stay
-    bit-identical to the heap rows the reference path would resolve (the
-    invariant the fused≡reference equivalence rests on)."""
-    if not isinstance(snap, TreeSnapshot) or snap.cache_lids is None:
-        return snap
+    bit-identical to the heap rows the per-level oracle would resolve (the
+    invariant the fused ≡ per-level equivalence rests on)."""
     view = snapshot_fields(snap, cfg)
     lids = snap.cache_lids
     phys = snap.pagetable[jnp.maximum(lids, 0)]
@@ -183,7 +136,7 @@ class SnapshotDelta(NamedTuple):
     pt_phys: jax.Array       # i32 [P] new mappings (may repeat, identical)
     root_lid: jax.Array      # i32 []
     read_version: jax.Array  # i32 []
-    cache_lids: jax.Array | None = None  # i32 [C] next epoch's cache tier
+    cache_lids: jax.Array    # i32 [C] next epoch's cache tier
 
 
 class ValueDelta(NamedTuple):
@@ -196,99 +149,34 @@ class ValueDelta(NamedTuple):
     rows: jax.Array          # u32 [D or V, overflow_words]
 
 
-class LegacySnapshotDelta(NamedTuple):
-    """Per-field delta (cfg.layout="legacy"): one [D, ...] update block per
-    node field — ~24 row scatters per sync, the traffic shape the packed
-    layout collapses to one."""
-    rows: jax.Array          # i32 [D] dirty physical slots
-    ntype: jax.Array         # i32 [D]
-    nitems: jax.Array        # i32 [D]
-    version: jax.Array       # i32 [D]
-    oldptr: jax.Array        # i32 [D]
-    left_child: jax.Array    # i32 [D]
-    lsib: jax.Array          # i32 [D]
-    rsib: jax.Array          # i32 [D]
-    skeys: jax.Array         # u32 [D, N, KW]
-    skeylen: jax.Array       # i32 [D, N]
-    svals: jax.Array         # u32 [D, N, VW]
-    svallen: jax.Array       # i32 [D, N]
-    n_shortcuts: jax.Array   # i32 [D]
-    sc_keys: jax.Array       # u32 [D, NSC, KW]
-    sc_keylen: jax.Array     # i32 [D, NSC]
-    sc_pos: jax.Array        # i32 [D, NSC]
-    nlog: jax.Array          # i32 [D]
-    log_keys: jax.Array      # u32 [D, L, KW]
-    log_keylen: jax.Array    # i32 [D, L]
-    log_vals: jax.Array      # u32 [D, L, VW]
-    log_vallen: jax.Array    # i32 [D, L]
-    log_op: jax.Array        # i32 [D, L]
-    log_backptr: jax.Array   # i32 [D, L]
-    log_hint: jax.Array      # i32 [D, L]
-    log_vdelta: jax.Array    # i32 [D, L]
-    pt_lids: jax.Array       # i32 [P] page-table command targets
-    pt_phys: jax.Array       # i32 [P] new mappings (may repeat, identical)
-    root_lid: jax.Array      # i32 []
-    read_version: jax.Array  # i32 []
-
-
-def apply_snapshot_delta(snap, delta, *, backend: str | None = None,
-                         cfg: HoneycombConfig | None = None):
+def apply_snapshot_delta(snap: TreeSnapshot, delta: SnapshotDelta, *,
+                         cfg: HoneycombConfig, backend: str | None = None
+                         ) -> TreeSnapshot:
     """Scatter one sync's dirty rows + page-table commands into a resident
     device snapshot, yielding the next snapshot.
 
     Functional on purpose: the input snapshot's buffers are never donated,
     so old snapshots held by in-flight batches keep answering at their read
-    version (wait-free MVCC).  Dispatches on the delta's layout:
-
-      * packed ``SnapshotDelta`` — ONE image-row scatter patches every
-        field of a dirty node in a single contiguous DMA
-        (``repro.kernels.delta_scatter.snapshot_image_scatter`` on
-        ``"pallas"``/``"interpret"``; ``backend=None`` is the jnp oracle
-        XLA:CPU lowers, kept as the parity reference);
-      * ``LegacySnapshotDelta`` — the per-field path: ``backend=None``
-        scatters field by field, the kernel backends fuse all fields into
-        one multi-field Pallas call (``snapshot_multi_scatter``).
-
-    For packed deltas ``cfg`` enables the cache tier: the delta's
-    ``cache_lids`` replace the snapshot's and the contiguous cache image is
-    rebuilt from the patched heap image (``attach_cache_image``) inside the
-    same jitted apply.  Without ``cfg`` the cache image is dropped (fused
-    reads then fall back to the reference path) rather than served stale.
+    version (wait-free MVCC).  ONE image-row scatter patches every field of
+    a dirty node in a single contiguous DMA
+    (``repro.kernels.delta_scatter.snapshot_delta_scatter`` on
+    ``"pallas"``/``"interpret"``; ``backend=None`` is the jnp scatter
+    XLA:CPU lowers).  The delta's ``cache_lids`` replace the snapshot's
+    and the contiguous cache image is rebuilt from the patched heap image
+    (``attach_cache_image``) inside the same jitted apply, so every
+    applied delta carries its cache tier.
     """
-    if isinstance(delta, SnapshotDelta):
-        if backend is None:
-            image = snap.image.at[delta.rows].set(delta.image)
-        else:
-            from repro.kernels import ops  # deferred: kernels.ref imports us
-            image = ops.snapshot_image_scatter(snap.image, delta.rows,
-                                               delta.image, backend=backend)
-        cache_lids = snap.cache_lids if delta.cache_lids is None \
-            else delta.cache_lids
-        nxt = snap._replace(
-            image=image,
-            pagetable=snap.pagetable.at[delta.pt_lids].set(delta.pt_phys),
-            root_lid=delta.root_lid, read_version=delta.read_version,
-            cache_lids=cache_lids)
-        if cfg is not None:
-            return attach_cache_image(nxt, cfg)
-        return nxt._replace(cache_image=None)
     if backend is None:
-        upd = {f: getattr(snap, f).at[delta.rows].set(getattr(delta, f))
-               for f in NODE_FIELDS}
+        image = snap.image.at[delta.rows].set(delta.image)
     else:
         from repro.kernels import ops  # deferred: kernels.ref imports us
-        shapes = [getattr(snap, f).shape for f in NODE_FIELDS]
-        dsts = [getattr(snap, f).reshape(s[0], -1)
-                for f, s in zip(NODE_FIELDS, shapes)]
-        upds = [getattr(delta, f).reshape(getattr(delta, f).shape[0], -1)
-                for f in NODE_FIELDS]
-        outs = ops.snapshot_multi_scatter(dsts, delta.rows, upds,
-                                          backend=backend)
-        upd = {f: o.reshape(s)
-               for f, o, s in zip(NODE_FIELDS, outs, shapes)}
-    return snap._replace(
+        image = ops.snapshot_delta_scatter(snap.image, delta.rows,
+                                           delta.image, backend=backend)
+    return attach_cache_image(snap._replace(
+        image=image,
         pagetable=snap.pagetable.at[delta.pt_lids].set(delta.pt_phys),
-        root_lid=delta.root_lid, read_version=delta.read_version, **upd)
+        root_lid=delta.root_lid, read_version=delta.read_version,
+        cache_lids=delta.cache_lids), cfg)
 
 
 class ScanResult(NamedTuple):
@@ -354,12 +242,11 @@ def _segment_floor(snap: SnapshotFields, phys: jax.Array, seg: jax.Array,
     return jnp.where(local >= 0, base + local, -1)
 
 
-def descend(snap, key: jax.Array, klen: jax.Array,
+def descend(snap: SnapshotFields, key: jax.Array, klen: jax.Array,
             cfg: HoneycombConfig) -> jax.Array:
-    """Traverse interior nodes root->leaf for a batch.  Returns the resolved
-    physical slot of the leaf each request lands in.  Accepts any snapshot
-    layout (fields resolved via the static layout offsets when packed)."""
-    snap = snapshot_fields(snap, cfg)
+    """Traverse interior nodes root->leaf for a batch, over a field view
+    (``snapshot_fields``).  Returns the resolved physical slot of the leaf
+    each request lands in."""
     B = key.shape[0]
     rv = snap.read_version
     lid = jnp.broadcast_to(snap.root_lid, (B,))
@@ -390,7 +277,7 @@ def fused_view(snap: TreeSnapshot, cfg: HoneycombConfig) -> SnapshotFields:
     cache rows are bit-identical to their version-resolved heap rows
     (``attach_cache_image``), any search code running on this view yields
     the same results whether a level resolved from the cache or the heap —
-    THE structural argument behind fused ≡ reference."""
+    THE structural argument behind fused ≡ per-level."""
     layout = NodeImageLayout.for_config(cfg)
     combined = jnp.concatenate([snap.image, snap.cache_image], axis=0)
     return SnapshotFields(pagetable=snap.pagetable, root_lid=snap.root_lid,
@@ -560,15 +447,15 @@ def _resolve_leaf(snap: SnapshotFields, phys: jax.Array,
     return keys, klens, vals, vlens, live
 
 
-def batched_scan(snap, lo: jax.Array, lolen: jax.Array,
+def batched_scan(snap: TreeSnapshot, lo: jax.Array, lolen: jax.Array,
                  hi: jax.Array, hilen: jax.Array,
                  cfg: HoneycombConfig) -> ScanResult:
     """SCAN(K_l, K_u) for a batch: floor-start semantics, forward across
-    sibling leaves with bounded budget (Section 3.3).  Layout-agnostic:
-    packed snapshots are read through static image offsets."""
-    snap = snapshot_fields(snap, cfg)
-    leaf0 = descend(snap, lo, lolen, cfg)
-    return scan_from_leaf(snap, leaf0, lo, lolen, hi, hilen, cfg)
+    sibling leaves with bounded budget (Section 3.3), level by level — the
+    per-level oracle of the fused SCAN."""
+    view = snapshot_fields(snap, cfg)
+    leaf0 = descend(view, lo, lolen, cfg)
+    return scan_from_leaf(view, leaf0, lo, lolen, hi, hilen, cfg)
 
 
 def scan_from_leaf(snap: SnapshotFields, leaf0: jax.Array,
@@ -576,7 +463,7 @@ def scan_from_leaf(snap: SnapshotFields, leaf0: jax.Array,
                    hi: jax.Array, hilen: jax.Array,
                    cfg: HoneycombConfig) -> ScanResult:
     """The scan engine proper, starting from pre-descended leaf slots —
-    shared verbatim between the reference path (heap-view ``snap``, heap
+    shared verbatim between the per-level oracle (heap-view ``snap``, heap
     ``leaf0``) and the fused oracle (combined cache+heap view,
     ``descend_fused`` leaf slots), so the two paths cannot drift."""
     c = cfg
@@ -669,9 +556,10 @@ def scan_from_leaf(snap: SnapshotFields, leaf0: jax.Array,
     return ScanResult(count, out_keys, out_klens, out_vals, out_vlens, trunc)
 
 
-def batched_get(snap, key: jax.Array, klen: jax.Array,
+def batched_get(snap: TreeSnapshot, key: jax.Array, klen: jax.Array,
                 cfg: HoneycombConfig) -> GetResult:
-    """GET(K) implemented as SCAN(K, K) + post-processing (Section 3.3)."""
+    """GET(K) implemented as SCAN(K, K) + post-processing (Section 3.3) —
+    the per-level oracle of the fused GET."""
     res = batched_scan(snap, key, klen, key, klen, cfg)
     return get_from_scan(res, key, klen)
 

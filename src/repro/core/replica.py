@@ -100,9 +100,9 @@ import numpy as np
 
 from .api import Routing, decode_wire_stream
 from ..analysis import epochsan as _epochsan
-from .config import ReplicationConfig, bucket_pow2
+from .config import HoneycombConfig, ReplicationConfig, bucket_pow2
 from .heap import LOG_DELETE, LOG_INSERT, LOG_UPDATE
-from .read_path import NODE_FIELDS, TreeSnapshot, attach_cache_image
+from .read_path import TreeSnapshot, attach_cache_image
 from .schema import NodeImageLayout
 from .shard import (LogPayload, StagedSync, StoreShard, SyncStats,
                     _jit_apply_delta, apply_value_delta, sync_backend)
@@ -146,7 +146,7 @@ class FeedStats:
     log_fallback_epochs: int = 0  # log-feed stagings that had to ship the
     #   image delta (tree shape changed / GC / overflow value)
     delta_feed_epochs: int = 0    # stagings shipped as deltas by choice
-    #   (feed="delta", or legacy layout with no packed image to replay into)
+    #   (feed="delta")
     full_feed_epochs: int = 0     # full-publish stagings
     full_catchups: int = 0        # out-of-sync followers refed a full copy
     catchup_bytes: int = 0        # bytes those full catch-ups moved
@@ -161,16 +161,11 @@ def _snapshot_nbytes(snap) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(snap))
 
 
-def _image_feed_cost(snap, cfg) -> tuple[int, int]:
-    """(DMA invocations, node-image payload bytes) of device-copying a
-    whole snapshot into a follower: the packed layout moves ONE contiguous
-    image (core/schema.py); legacy moves one array per field — same
-    bytes."""
-    if isinstance(snap, TreeSnapshot):
-        layout = NodeImageLayout.for_config(cfg)
-        return 1, snap.image.shape[0] * layout.node_image_bytes
-    return len(NODE_FIELDS), sum(getattr(snap, f).nbytes
-                                 for f in NODE_FIELDS)
+def _image_feed_bytes(snap: TreeSnapshot, cfg) -> int:
+    """Node-image payload bytes of device-copying a whole snapshot into a
+    follower, which is ONE contiguous image DMA (core/schema.py)."""
+    layout = NodeImageLayout.for_config(cfg)
+    return snap.image.shape[0] * layout.node_image_bytes
 
 
 class FollowerReplica:
@@ -178,7 +173,8 @@ class FollowerReplica:
     buffers, SyncStats, and epoch/read-version watermark.  Fed only by the
     primary's ``StagedSync`` payloads; never written directly."""
 
-    def __init__(self, replica_id: int, in_sync: bool = True, cfg=None):
+    def __init__(self, replica_id: int, cfg: HoneycombConfig,
+                 in_sync: bool = True):
         self.replica_id = replica_id
         self.cfg = cfg                 # layout schema for cache re-attach
         self.sync_stats = SyncStats()
@@ -204,9 +200,8 @@ class FollowerReplica:
         stats.snapshots += 1
         if payload.kind == "delta" and self.in_sync and base is not None:
             # independent device scatter per replica: O(dirty_rows) traffic
-            # (one image-row DMA per dirty node on the packed layout — the
-            # delta type carries the layout, so the replay is layout-free),
-            # plus the epoch's new value slots into our own value image
+            # (one image-row DMA per dirty node), plus the epoch's new value
+            # slots into our own value image
             self._standby = _jit_apply_delta(
                 base._replace(values=None), payload.delta,
                 backend=sync_backend(), cfg=self.cfg)._replace(
@@ -225,9 +220,8 @@ class FollowerReplica:
             nbytes = (payload.nbytes if payload.kind == "full"
                       else _snapshot_nbytes(payload.snapshot))
             stats.bytes_synced += nbytes
-            dmas, ibytes = _image_feed_cost(payload.snapshot, self.cfg)
-            stats.image_dma_count += dmas
-            stats.image_bytes += ibytes
+            stats.image_dma_count += 1
+            stats.image_bytes += _image_feed_bytes(payload.snapshot, self.cfg)
             self.in_sync = True
             was_full = True
         self._standby_rv = payload.read_version
@@ -270,9 +264,8 @@ class FollowerReplica:
         # a replayable epoch wrote no value slot: the value image stays
         snap = base._replace(
             image=image, read_version=jnp.int32(lp.read_version), values=None)
-        if self.cfg is not None:
-            snap = _jit_attach_cache(snap, cfg=self.cfg)
-        snap = snap._replace(values=base.values)
+        snap = _jit_attach_cache(snap, cfg=self.cfg)._replace(
+            values=base.values)
         self._standby = snap
         self._standby_rv = payload.read_version
         stats.log_replays += 1
@@ -311,8 +304,7 @@ class ReplicaGroup:
         self.primary = primary
         self.replication = replication or ReplicationConfig()
         fresh = (primary._snapshot is None and primary._standby is None)
-        self.followers = [FollowerReplica(i + 1, in_sync=fresh,
-                                          cfg=primary.cfg)
+        self.followers = [FollowerReplica(i + 1, primary.cfg, in_sync=fresh)
                           for i in range(self.replication.replicas - 1)]
         self.lagging_skips = 0         # batches redirected off a stale follower
         self.replication_s = 0.0       # wall time spent feeding followers
@@ -321,13 +313,10 @@ class ReplicaGroup:
         # ascend level by level, so walking followers in order always
         # visits a parent before its children
         self._parents = self.replication.topology.parents(len(self.followers))
-        # the log feed needs the packed image (the replay kernel's one
-        # destination buffer); the legacy per-field layout keeps the delta
-        # feed.  Capture costs the unreplicated store nothing: the flag
-        # stays False with no followers.
+        # capture costs the unreplicated store nothing: the flag stays
+        # False with no followers
         self._log_enabled = (self.replication.feed == "log"
-                             and bool(self.followers)
-                             and primary.cfg.layout == "packed")
+                             and bool(self.followers))
         primary.log_capture = self._log_enabled
         self._primary_served = 0       # device requests the primary served
         # read-spreading policy state (the pick lives HERE; the router
@@ -468,9 +457,8 @@ class ReplicaGroup:
         f.sync_stats.full_syncs += 1
         nbytes = _snapshot_nbytes(snap)
         f.sync_stats.bytes_synced += nbytes
-        dmas, ibytes = _image_feed_cost(snap, self.primary.cfg)
-        f.sync_stats.image_dma_count += dmas
-        f.sync_stats.image_bytes += ibytes
+        f.sync_stats.image_dma_count += 1
+        f.sync_stats.image_bytes += _image_feed_bytes(snap, self.primary.cfg)
         # an admin resync is a primary-direct full catch-up on the feed
         self.feed_stats.full_catchups += 1
         self.feed_stats.catchup_bytes += nbytes

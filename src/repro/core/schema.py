@@ -16,8 +16,7 @@ Layout contract (pinned by tests/test_layout.py golden offsets):
   * fields are laid out in ``NODE_SCHEMA`` order, no padding, 4-byte words;
   * every device field is exactly one u32 word per element.  Wider host
     types (the 64-bit version counters, the byte-wide log op/hint codes)
-    narrow to int32 on the way in — the same narrowing the per-field
-    legacy snapshot always performed (the host keeps 64-bit authority);
+    narrow to int32 on the way in (the host keeps 64-bit authority);
   * signed fields cross as their int32 bit pattern and are decoded with a
     bitcast (NULL = -1 survives), unsigned key/value lanes pass through.
 
@@ -165,9 +164,9 @@ class NodeImageLayout:
     def pack(self, heap, rows: np.ndarray | None = None) -> np.ndarray:
         """Marshal heap rows into contiguous node images: [D, image_words]
         u32 (D = all rows when ``rows`` is None).  Narrows wide host dtypes
-        to int32 and bit-preserves signedness, exactly like the per-field
-        legacy publish; the result is a fresh buffer, so later host
-        mutations can never reach a staged snapshot.  Pad words are 0."""
+        to int32 and bit-preserves signedness; the result is a fresh
+        buffer, so later host mutations can never reach a staged snapshot.
+        Pad words are 0."""
         n = heap.capacity if rows is None else len(rows)
         img = np.zeros((n, self.image_words), np.uint32)
         for name, slot in self.slots.items():

@@ -55,11 +55,9 @@ from .cache import InteriorCache
 from .config import HoneycombConfig, bucket_pow2
 from .keys import pack_keys
 from .pipeline import PipelineStats
-from .read_path import (NODE_FIELDS, GetResult, LegacySnapshotDelta,
-                        LegacyTreeSnapshot, ScanResult, SnapshotDelta,
-                        TreeSnapshot, ValueDelta, apply_snapshot_delta,
-                        attach_cache_image, batched_get, batched_scan)
-from .schema import NARROWED_FIELDS, NodeImageLayout
+from .read_path import (GetResult, ScanResult, SnapshotDelta, TreeSnapshot,
+                        ValueDelta, apply_snapshot_delta, attach_cache_image)
+from .schema import NodeImageLayout
 from .telemetry import CLOCK, samples_from, span
 from repro.kernels import ops as kernel_ops
 # EpochSan seams (repro/analysis/epochsan.py): get() is None unless the
@@ -88,16 +86,14 @@ def _pack_read(res, meters: jax.Array) -> jax.Array:
         meters.astype(jnp.int32), jnp.uint32)])
 
 
-def _packed(read: Callable, metered: bool) -> Callable:
-    """``read`` with its answer packed by ``_pack_read`` in the same
-    program (the reference path has no meters: zeros).  The wrapper keeps
-    ``read``'s name, so the program is still ``jit_<read>``: the
-    benchmark's read-kernel metrics find its device time by that name."""
+def _packed(read: Callable) -> Callable:
+    """``read`` with its answer and meters packed by ``_pack_read`` in the
+    same program.  The wrapper keeps ``read``'s name, so the program is
+    still ``jit_<read>``: the benchmark's read-kernel metrics find its
+    device time by that name."""
     @functools.wraps(read)
     def run(*args, **kw):
-        out = read(*args, **kw)
-        res, meters = out if metered else (out, jnp.zeros(3, jnp.int32))
-        return _pack_read(res, meters)
+        return _pack_read(*read(*args, **kw))
     return run
 
 
@@ -175,18 +171,16 @@ def _unpack_read(buf: np.ndarray, result_type, cfg: HoneycombConfig):
 
 # jit the accelerator entry points once per (config, snapshot-shape): the
 # eager op-by-op dispatch otherwise accumulates thousands of tiny LLVM JIT
-# dylibs across a benchmark run (vm.max_map_count exhaustion).  Each
-# returns ``_pack_read``'s one buffer.
-_jit_get = jax.jit(_packed(batched_get, False), static_argnames="cfg")
-_jit_scan = jax.jit(_packed(batched_scan, False), static_argnames="cfg")
-# the fused read path (ONE traversal dispatch per batch, cache tier pinned
-# in VMEM — kernels/fused_read.py): compiled Pallas on TPU, the jnp oracle
-# everywhere else (XLA:CPU lowers it; interpret-mode parity is tested).
-# The kernel backend is resolved when the first batch traces
-# (kernels/ops.py), never at import.
-_jit_get_fused = jax.jit(_packed(kernel_ops.batched_get_fused, True),
+# dylibs across a benchmark run (vm.max_map_count exhaustion).  The read
+# programs are the fused read path (ONE traversal dispatch per batch,
+# cache tier pinned in VMEM — kernels/fused_read.py): compiled Pallas on
+# TPU, the jnp oracle everywhere else (XLA:CPU lowers it; interpret-mode
+# parity is tested).  The kernel backend is resolved when the first batch
+# traces (kernels/ops.py), never at import.  Each returns ``_pack_read``'s
+# one buffer.
+_jit_get_fused = jax.jit(_packed(kernel_ops.batched_get_fused),
                          static_argnames=("cfg", "lb_fraction", "backend"))
-_jit_scan_fused = jax.jit(_packed(kernel_ops.batched_scan_fused, True),
+_jit_scan_fused = jax.jit(_packed(kernel_ops.batched_scan_fused),
                           static_argnames=("cfg", "lb_fraction", "backend"))
 # the delta-sync scatter; NOT donated — old snapshots held by in-flight
 # batches must keep answering at their read version.
@@ -213,9 +207,6 @@ def sync_backend() -> str | None:
     the jnp scatter XLA lowers (``apply_snapshot_delta``)."""
     return "pallas" if kernel_ops.default_backend() == "pallas" else None
 
-# snapshot fields narrowed to int32 on device (host keeps 64-bit authority)
-# — derived from the one layout schema, not hand-kept
-_I32_FIELDS = NARROWED_FIELDS
 
 _now = CLOCK            # THE injectable monotonic clock (core/telemetry.py)
 
@@ -234,12 +225,10 @@ class SyncStats:
     log_wire_bytes: int = 0       # append-only wire-format estimate
     #   (key+value+WIRE_ENTRY_OVERHEAD per write) — the paper's log-block
     #   byte accounting, alongside the dirty-row accounting above
-    image_dma_count: int = 0      # node-image DMA invocations: the packed
-    #   layout issues exactly ONE per dirty node (one per whole image on a
-    #   full publish); legacy issues one per field per node — the counter
-    #   the layout refactor exists to collapse
-    image_bytes: int = 0          # node-image payload bytes (both layouts
-    #   carry image_words * 4 per node; the DMA *count* is what differs)
+    image_dma_count: int = 0      # node-image DMA invocations: exactly ONE
+    #   per dirty node (one per whole image on a full publish)
+    image_bytes: int = 0          # node-image payload bytes (the layout's
+    #   node_image_bytes per node)
     log_replays: int = 0          # follower stagings applied by replaying
     #   the epoch's op wire stream on device (log_replay_scatter) instead
     #   of re-issuing the primary's image-row DMAs — the log-shipped feed
@@ -270,11 +259,10 @@ class StagedSync:
     follower replica replays (core/replica.py).
 
     ``kind`` is "full" or "delta"; ``delta`` carries the dirty-row +
-    page-table scatter for delta stagings (None for full publishes) — a
-    packed ``SnapshotDelta`` (one image row per dirty node) or a
-    ``LegacySnapshotDelta`` (per-field blocks), matching ``cfg.layout``;
-    ``snapshot`` is the staged standby itself, which doubles as the catch-up
-    source for followers that fell out of sync; ``nbytes`` is the traffic
+    page-table scatter for delta stagings (None for full publishes), one
+    image row per dirty node; ``snapshot`` is the staged standby itself,
+    which doubles as the catch-up source for followers that fell out of
+    sync; ``nbytes`` is the traffic
     this staging metered and ``delta_rows`` the unpadded dirty-row count, so
     per-replica feeding costs O(replicas x dirty_rows) can be accounted
     exactly; ``image_dmas``/``image_bytes`` are the staging's node-image
@@ -285,8 +273,8 @@ class StagedSync:
     value image).
     """
     kind: str
-    snapshot: TreeSnapshot | LegacyTreeSnapshot
-    delta: SnapshotDelta | LegacySnapshotDelta | None
+    snapshot: TreeSnapshot
+    delta: SnapshotDelta | None
     nbytes: int
     delta_rows: int
     read_version: int
@@ -626,60 +614,42 @@ class StoreShard:
         self.begin_export(force=force, full=full)
         return self.flip()   # no-op returning the active snapshot if clean
 
-    def _publish_full(self):
-        """Wholesale republish: the whole store crosses the bus — ONE
-        contiguous [S, image_words] image DMA on the packed layout, one
-        array per field on legacy (same bytes, ~24x the DMA invocations).
+    def _publish_full(self) -> TreeSnapshot:
+        """Wholesale republish: the whole store crosses the bus as ONE
+        contiguous [S, image_words] image DMA (plus the page table).
         Spans ``hc.sync.pack`` (host images), ``hc.sync.put`` (uploads),
         ``hc.sync.launch`` (the cache-tier program)."""
         t = self.tree
         h = t.heap
         stats = self.sync_stats
         layout = NodeImageLayout.for_config(self.cfg)
-        packed = self.cfg.layout == "packed"
         with span("sync.pack"):
             pt_image = t.pt.flush_to_device()
             # pack() marshals every field into contiguous node images —
             # the whole publish is one image transfer (plus the page table)
-            img = layout.pack(h) if packed else None
+            img = layout.pack(h)
         stats.image_bytes += h.capacity * layout.node_image_bytes
-
-        def dev(a, dtype=None):
-            # ALWAYS copy: jnp.asarray is typically zero-copy on the CPU
-            # backend, and an aliased snapshot would see in-place host
-            # mutations (log appends, GC wipes) — the snapshot must be the
-            # immutable device image the paper's DMA produces
-            arr = np.asarray(a)
-            arr = arr.astype(dtype) if dtype is not None else arr.copy()
-            stats.bytes_synced += arr.nbytes
-            return jnp.asarray(arr)
-
         values, _ = self._stage_values(None)
-        if packed:
-            stats.bytes_synced += h.capacity * layout.node_image_bytes
-            stats.image_dma_count += 1
-            with span("sync.put"):
-                snap = TreeSnapshot(
-                    image=jnp.asarray(img),
-                    pagetable=dev(pt_image),
-                    root_lid=jnp.int32(t.root_lid),
-                    read_version=jnp.int32(t.versions.read_version()),
-                    cache_lids=jnp.asarray(self.cache.device_lids()))
-            # materialize the VMEM cache tier device-side from the image
-            # just shipped — only the ~KB LID vector crossed the bus
-            with span("sync.launch"):
-                return attach_cache_image(snap, self.cfg)._replace(
-                    values=values)
-        stats.image_dma_count += len(NODE_FIELDS)
+        stats.bytes_synced += h.capacity * layout.node_image_bytes
+        stats.image_dma_count += 1
         with span("sync.put"):
-            fields = {f: dev(getattr(h, f),
-                             np.int32 if f in _I32_FIELDS else None)
-                      for f in NODE_FIELDS}
-            return LegacyTreeSnapshot(
-                pagetable=dev(pt_image),
+            # ALWAYS copy the page table: jnp.asarray is typically zero-copy
+            # on the CPU backend, and an aliased snapshot would see in-place
+            # host mutations — the snapshot must be the immutable device
+            # image the paper's DMA produces (pack() returns a fresh image)
+            pt_image = np.array(pt_image)
+            stats.bytes_synced += pt_image.nbytes
+            snap = TreeSnapshot(
+                image=jnp.asarray(img),
+                pagetable=jnp.asarray(pt_image),
                 root_lid=jnp.int32(t.root_lid),
                 read_version=jnp.int32(t.versions.read_version()),
-                values=values, **fields)
+                cache_lids=jnp.asarray(self.cache.device_lids()),
+                cache_image=None)
+        # materialize the VMEM cache tier device-side from the image just
+        # shipped — only the ~KB LID vector crossed the bus
+        with span("sync.launch"):
+            return attach_cache_image(snap, self.cfg)._replace(values=values)
 
     def _stage_values(self, base: jax.Array | None):
         """The value image a staged snapshot carries over ``base`` (the
@@ -719,7 +689,8 @@ class StoreShard:
         self._values_gen = ovf.generation
         return values, vd
 
-    def _publish_delta(self, base, dirty: set[int]):
+    def _publish_delta(self, base: TreeSnapshot,
+                       dirty: set[int]) -> TreeSnapshot:
         """Incremental sync: scatter the ``dirty`` node rows and pending
         page-table commands over ``base`` (the standby-in-progress, or the
         active snapshot when none is staged).  Transfers (and meters)
@@ -727,17 +698,15 @@ class StoreShard:
         copy out of the heap eagerly, so later host mutations/GC wipes can
         never reach a staged standby.
 
-        Packed layout: each dirty node is marshalled into ONE contiguous
-        image row and issued as a single DMA (``image_dma_count`` grows by
-        exactly len(dirty) — the acceptance invariant); legacy ships the
-        same bytes as one row block per field (~24 DMAs per node).  Spans
-        ``hc.sync.pack`` (sort, padding, host images), ``hc.sync.put``
-        (uploads), ``hc.sync.launch`` (the scatter program)."""
+        Each dirty node is marshalled into ONE contiguous image row and
+        issued as a single DMA (``image_dma_count`` grows by exactly
+        len(dirty) — the acceptance invariant).  Spans ``hc.sync.pack``
+        (sort, padding, host images), ``hc.sync.put`` (uploads),
+        ``hc.sync.launch`` (the scatter program)."""
         t = self.tree
         h = t.heap
         stats = self.sync_stats
         layout = NodeImageLayout.for_config(self.cfg)
-        packed = self.cfg.layout == "packed"
         with span("sync.pack"):
             rows = np.fromiter(sorted(dirty), np.int32, len(dirty))
             pt_lids, pt_phys = t.pt.take_pending()
@@ -752,39 +721,20 @@ class StoreShard:
             rows_p = self._pad_index(rows, bucket_pow2(len(rows)))
             lids_p = self._pad_index(pt_lids, bucket_pow2(len(pt_lids)))
             phys_p = t.pt.device_image[lids_p]
-            if packed:
-                img = layout.pack(h, rows_p)
-                cache_lids = self.cache.device_lids()
-            else:
-                host_fields = {}
-                for f in NODE_FIELDS:
-                    arr = getattr(h, f)[rows_p]
-                    if f in _I32_FIELDS:
-                        arr = arr.astype(np.int32)
-                    host_fields[f] = arr
-        # both layouts move image_words * 4 bytes per UNPADDED dirty node
-        # (every device field is one u32 word per element); the accounting
-        # is identical by construction — only the DMA count differs
+            img = layout.pack(h, rows_p)
+            cache_lids = self.cache.device_lids()
+        # image_words * 4 bytes per UNPADDED dirty node
         node_bytes = len(rows) * layout.node_image_bytes
         nbytes = pt_lids.nbytes + pt_phys.nbytes + node_bytes
         stats.image_bytes += node_bytes
         with span("sync.put"):
-            if packed:
-                stats.image_dma_count += len(rows)   # ONE DMA per dirty node
-                delta = SnapshotDelta(
-                    rows=jnp.asarray(rows_p), image=jnp.asarray(img),
-                    pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
-                    root_lid=jnp.int32(t.root_lid),
-                    read_version=jnp.int32(t.versions.read_version()),
-                    cache_lids=jnp.asarray(cache_lids))
-            else:
-                stats.image_dma_count += len(rows) * len(NODE_FIELDS)
-                delta = LegacySnapshotDelta(
-                    rows=jnp.asarray(rows_p),
-                    pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
-                    root_lid=jnp.int32(t.root_lid),
-                    read_version=jnp.int32(t.versions.read_version()),
-                    **{f: jnp.asarray(a) for f, a in host_fields.items()})
+            stats.image_dma_count += len(rows)   # ONE DMA per dirty node
+            delta = SnapshotDelta(
+                rows=jnp.asarray(rows_p), image=jnp.asarray(img),
+                pt_lids=jnp.asarray(lids_p), pt_phys=jnp.asarray(phys_p),
+                root_lid=jnp.int32(t.root_lid),
+                read_version=jnp.int32(t.versions.read_version()),
+                cache_lids=jnp.asarray(cache_lids))
         stats.bytes_synced += nbytes
         self._staged_delta = delta   # replayable by follower replicas
         values, self._staged_values = self._stage_values(base.values)
@@ -802,19 +752,6 @@ class StoreShard:
             [idx, np.full(size - len(idx), idx[-1], np.int32)])
 
     # ------------------------------------------------- accelerated reads
-    def _read_backend_for(self, snap) -> str:
-        """Effective backend for one device dispatch.  The fused megakernel
-        path needs a packed snapshot with the cache tier attached; legacy
-        layouts, cache-less snapshots (e.g. a delta applied without cfg) and
-        ``cfg.read_backend="reference"`` all serve through the staged jnp
-        reference path."""
-        if (self.cfg.read_backend == "fused"
-                and isinstance(snap, TreeSnapshot)
-                and snap.cache_lids is not None
-                and snap.cache_image is not None):
-            return "fused"
-        return "reference"
-
     def _fetch_read(self, packed: jax.Array, result_type, lanes: int,
                     values: bool):
         """A batch's one device->host copy (``_pack_read``'s buffer, with
@@ -837,15 +774,19 @@ class StoreShard:
         self._note_read_meters(meters)
         return res, rows
 
-    def _launch_read(self, jit_read, snap, args, result_type, **kw):
-        """Launch one read program on ``snap``'s node image and, for a
-        snapshot with a value image, ``gather_values`` after it (span
+    def _launch_read(self, jit_read, snap, args, result_type):
+        """Launch one fused read program on ``snap``'s node image and, for
+        a snapshot with a value image, ``gather_values`` after it (span
         ``hc.read.gather``).  Returns the packed buffer and whether it
-        carries value rows."""
+        carries value rows.  A snapshot without its cache tier cannot be
+        read: every staging attaches one (``attach_cache_image``)."""
+        if snap.cache_lids is None or snap.cache_image is None:
+            raise RuntimeError("a read was dispatched on a snapshot without "
+                               "its cache tier")
         values = snap.values
         with span("read.launch"):
             packed = jit_read(snap._replace(values=None), *args,
-                              cfg=self.cfg, **kw)
+                              cfg=self.cfg, lb_fraction=self.cfg.lb_fraction)
         if values is None:
             return packed, False
         with span("read.gather"):
@@ -855,7 +796,7 @@ class StoreShard:
     def _note_read_meters(self, meters: np.ndarray):
         """Fold one batch's device meters, views of its one packed copy,
         into CacheStats (the dispatching shard accounts follower-served
-        batches too; the reference path's meters are zeros)."""
+        batches too)."""
         s = self.cache.stats
         s.vmem_hits += int(meters[0])
         s.heap_gathers += int(meters[1])
@@ -905,17 +846,10 @@ class StoreShard:
             ps.padded_lanes += len(padded)
             lanes, lens = pack_keys(padded, self.cfg.key_words)
             lanes, lens = jnp.asarray(lanes), jnp.asarray(lens)
-        rb = self._read_backend_for(snap)
-        kernel_ops.record_read_dispatch("get", rb, self.cfg)
         lo, hi = self.tree.epochs.accel_begin_batch(len(keys))
         try:
-            if rb == "fused":
-                packed, gathered = self._launch_read(
-                    _jit_get_fused, snap, (lanes, lens), GetResult,
-                    lb_fraction=self.cfg.lb_fraction)
-            else:
-                packed, gathered = self._launch_read(
-                    _jit_get, snap, (lanes, lens), GetResult)
+            packed, gathered = self._launch_read(
+                _jit_get_fused, snap, (lanes, lens), GetResult)
             with span("read.fetch", ps, "fetch_s"):
                 (found, vals, vlens), rows = self._fetch_read(
                     packed, GetResult, len(padded), gathered)
@@ -960,17 +894,10 @@ class StoreShard:
             hi_l, hi_n = pack_keys([r[1] for r in padded], self.cfg.key_words)
             args = (jnp.asarray(lo_l), jnp.asarray(lo_n),
                     jnp.asarray(hi_l), jnp.asarray(hi_n))
-        rb = self._read_backend_for(snap)
-        kernel_ops.record_read_dispatch("scan", rb, self.cfg)
         slo, shi = self.tree.epochs.accel_begin_batch(len(ranges))
         try:
-            if rb == "fused":
-                packed, gathered = self._launch_read(
-                    _jit_scan_fused, snap, args, ScanResult,
-                    lb_fraction=self.cfg.lb_fraction)
-            else:
-                packed, gathered = self._launch_read(
-                    _jit_scan, snap, args, ScanResult)
+            packed, gathered = self._launch_read(
+                _jit_scan_fused, snap, args, ScanResult)
             with span("read.fetch", ps, "fetch_s"):
                 (count, keys, klens, vals, vlens, trunc), rows = \
                     self._fetch_read(packed, ScanResult, len(padded),

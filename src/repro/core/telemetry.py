@@ -5,8 +5,8 @@ docstring IS the reference).
 Honeycomb's evaluation lives on per-component breakdowns — PCIe traffic
 split, cache hit rate, sync stall, tail latency (paper Figs. 13-16) — and
 the serving stack already meters every layer (``SyncStats``,
-``CacheStats``, ``PipelineStats``, ``FeedStats``, ``TreeStats``, the
-kernel-dispatch counter).  This module gives those scattered dataclasses
+``CacheStats``, ``PipelineStats``, ``FeedStats``, ``TreeStats``).  This
+module gives those scattered dataclasses
 one front door:
 
   * ``MetricsRegistry`` — counters, gauges, and log-bucketed latency
@@ -115,6 +115,7 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   pipeline_device_values          counter    pipeline   out-of-node values served from the device value image
   pipeline_staged_exports         counter    pipeline   begin_export standby stagings
   pipeline_flips                  counter    pipeline   epoch publishes
+  pipeline_read_batches           counter    pipeline   device read batches dispatched
   pipeline_dispatched_lanes       counter    pipeline   real requests inside device batches
   pipeline_padded_lanes           counter    pipeline   bucket_pow2 lanes those occupied
   pipeline_lane_occupancy         gauge      pipeline   dispatched/padded (1.0 = no waste)
@@ -142,8 +143,6 @@ implementations; Prometheus names carry the ``hc_`` prefix):
   replication_full_feed_epochs    counter    replica    full-publish stagings
   replication_full_catchups       counter    replica    out-of-sync followers refed a full copy
   replication_catchup_bytes       counter    replica    bytes those catch-ups moved
-  read_dispatches                 counter    kernel     device launches (labels op=, backend=)
-  read_batches                    counter    kernel     read batches dispatched (same labels)
   scheduler_dispatched_batches    counter    scheduler  device batches composed
   scheduler_dispatched_requests   counter    scheduler  read requests inside them
   scheduler_applied_writes        counter    scheduler  writes admitted host-side
@@ -478,9 +477,9 @@ class MetricsRegistry:
     histograms (get-or-create by (name, labels)) and any number of
     registered SOURCES — zero-arg callables returning either an object
     with ``collect()`` or an iterable of samples (``MetricSample`` or
-    ``(name, kind, value[, labels])`` tuples, the dependency-free form
-    kernels/ops.py uses).  Sources are re-invoked on every ``collect()``,
-    so exports always reflect live meter state."""
+    ``(name, kind, value[, labels])`` tuples, the plain form
+    ``Telemetry.wire_scheduler`` uses).  Sources are re-invoked on every
+    ``collect()``, so exports always reflect live meter state."""
 
     def __init__(self):
         self._own: dict[tuple, tuple[str, Any]] = {}
@@ -791,7 +790,6 @@ class Telemetry:
         san = _epochsan.get()
         if san is not None:
             reg.register(lambda: san.stats)
-        self.wire_kernel_meter()
         return self
 
     def wire_scheduler(self, sched) -> "Telemetry":
@@ -810,15 +808,6 @@ class Telemetry:
             ]
         self.registry.register(_sched_meters)
         return self
-
-    def wire_kernel_meter(self) -> None:
-        """The READ_DISPATCHES launch counter (kernels/ops.py).  Lazy
-        import at collect time: kernels may not import repro.core, and a
-        registry must stay constructible without jax on the path."""
-        def _kernel_samples():
-            from repro.kernels import ops as kernel_ops
-            return kernel_ops.collect()
-        self.registry.register(_kernel_samples)
 
     # --------------------------------------------------------- exporters
     def collect(self) -> list[MetricSample]:

@@ -17,21 +17,15 @@ window compose exactly like sequential row writes.  Row widths must be a
 multiple of 128 lanes on the chip (core/schema.py pads the node image);
 ``interpret=True`` takes any width.
 
-Three kernels, tracking the sync path's evolution toward the paper's
-one-contiguous-DMA-per-node transfer:
-  * ``snapshot_delta_scatter`` — one [S, W] array per call: the window
-    read-modify-write above, done with vector selects in VMEM.
-  * ``snapshot_multi_scatter`` — ALL fields of a dirty row in ONE
-    ``pallas_call``: each field is its own aliased operand/output pair and
-    the grid body DMAs every field's row in the same iteration (the
-    ``cfg.layout="legacy"`` parity path; its one-word fields fall below
-    the TPU tile, so it runs in interpret mode only).
-  * ``snapshot_image_scatter`` — the packed-layout endgame
-    (``cfg.layout="packed"``, the default): the snapshot is ONE
-    ``[S, image_words]`` u32 image (core/schema.py), a dirty node's entire
-    contents are one contiguous ``[image_words]`` row, and the scatter is
-    one window read-modify-write per dirty node, with no per-field
-    addressing anywhere on the device side.
+Two kernels:
+  * ``snapshot_delta_scatter`` — the image-row scatter: the snapshot is
+    ONE ``[S, image_words]`` u32 image (core/schema.py), a dirty node's
+    entire contents are one contiguous ``[image_words]`` row (the paper's
+    whole-node transfer), and the scatter is the window read-modify-write
+    above per dirty node, done with vector selects in VMEM, with no
+    per-field addressing anywhere on the device side.
+  * ``log_replay_scatter`` — the log-shipped follower feed: an epoch's
+    marshalled log entries written into the image at static offsets.
 
 Shared caveat: duplicate rows must carry identical data (the store pads
 deltas with repeats), which keeps the scatters order-free.
@@ -92,10 +86,12 @@ def _scatter_row_kernel(rows_ref, upd_ref, dst_ref, out_ref, win_ref, sem):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def snapshot_delta_scatter(dst, rows, upd, *, interpret: bool = False):
-    """dst[rows[i], :] = upd[i, :] for i in range(D), in place.
+    """dst[rows[i], :] = upd[i, :] for i in range(D), in place — ONE
+    image-row write per dirty node, the delta sync's whole device side.
 
-    dst:  [S, W] resident device array (flattened trailing dims)
-    rows: [D] int32 target rows (repeats allowed with identical data)
+    dst:  [S, image_words] resident packed node images (u32; any [S, W]
+          array with W a multiple of 128 lanes on the chip)
+    rows: [D] int32 dirty physical slots (repeats carry identical data)
     upd:  [D, W] replacement rows
     """
     S, W = dst.shape
@@ -124,22 +120,6 @@ def snapshot_delta_scatter(dst, rows, upd, *, interpret: bool = False):
         interpret=interpret,
     )(rows, upd, dst_p)
     return out if out.shape[0] == S else out[:S]
-
-
-def snapshot_image_scatter(image, rows, upd, *, interpret: bool = False):
-    """image[rows[i], :] = upd[i, :] — ONE image-row write per dirty node
-    (the packed layout's whole sync).
-
-    image: [S, image_words] resident packed node images (u32)
-    rows:  [D] int32 dirty physical slots (repeats carry identical data)
-    upd:   [D, image_words] replacement node images
-
-    The node image IS the transfer unit: every field of the node rides in
-    this one row (static offsets, core/schema.py), so the sync needs no
-    per-field operands — same aliased row-scatter machinery as
-    ``snapshot_delta_scatter``, applied to whole node images.
-    """
-    return snapshot_delta_scatter(image, rows, upd, interpret=interpret)
 
 
 def _log_replay_kernel(offs):
@@ -236,57 +216,3 @@ def log_replay_scatter(image, rows, slots, entries, *, offs,
     return out if out.shape[0] == S else out[:S]
 
 
-def _multi_scatter_kernel(nf: int):
-    """Kernel body for ``nf`` fused fields: refs arrive as
-    (rows, upd_0..upd_{nf-1}, dst_0..dst_{nf-1}, out_0..out_{nf-1});
-    every field's update row DMAs over its aliased output row."""
-    def kernel(rows_ref, *refs):
-        del rows_ref  # drives the out index maps; dsts are aliased
-        upd = refs[:nf]
-        out = refs[2 * nf:]
-        for f in range(nf):
-            out[f][...] = upd[f][...]
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def snapshot_multi_scatter(dsts, rows, upd, *, interpret: bool = False):
-    """Fused dirty-row scatter: dsts[f][rows[i], :] = upd[f][i, :] for every
-    field f, in ONE kernel invocation (the paper's whole-node DMA).
-
-    dsts: sequence of [S, W_f] resident device arrays (trailing dims
-          flattened by the caller; dtypes may differ per field)
-    rows: [D] int32 target rows (repeats allowed with identical data)
-    upd:  matching sequence of [D, W_f] replacement rows
-
-    Returns the new field arrays in input order.  The grid iterates over
-    update rows with ``rows`` scalar-prefetched; each destination is
-    aliased to its output, so untouched rows keep their contents without
-    any copy and the whole sync costs one kernel launch.
-    """
-    dsts, upd = tuple(dsts), tuple(upd)
-    nf = len(dsts)
-    D = upd[0].shape[0]
-
-    def upd_spec(w):
-        return pl.BlockSpec((1, w), lambda i, rows: (i, 0))
-
-    def out_spec(w):
-        return pl.BlockSpec((1, w), lambda i, rows: (rows[i], 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(D,),
-        in_specs=[upd_spec(u.shape[1]) for u in upd]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * nf,
-        out_specs=[out_spec(d.shape[1]) for d in dsts],
-    )
-    return pl.pallas_call(
-        _multi_scatter_kernel(nf),
-        name="multi_field_scatter",
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(d.shape, d.dtype) for d in dsts],
-        # dst f is argument 1 + nf + f (after rows and the nf update blocks)
-        input_output_aliases={1 + nf + f: f for f in range(nf)},
-        interpret=interpret,
-    )(rows, *upd, *dsts)

@@ -90,7 +90,7 @@ def key_search_image(q, qlen, node_img, *, keys_off: int, lens_off: int,
                      block_b: int = DEFAULT_BLOCK_B,
                      interpret: bool = False):
     """Floor search over a candidate block addressed INSIDE packed node
-    images (cfg.layout="packed"; e.g. the shortcut block at the layout's
+    images (e.g. the shortcut block at the layout's
     sc_keys/sc_keylen/n_shortcuts offsets).
 
     q:        [B, KW] uint32 packed big-endian query keys

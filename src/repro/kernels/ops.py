@@ -8,14 +8,11 @@
 The default follows the runtime: TPU -> pallas, else ref.  It is decided
 at dispatch, never at import: importing the store must not initialise a
 JAX backend (that claims the chip for the importing process).  On a TPU
-the store's read and sync kernels (delta/image/log-replay scatters, fused
-GET/SCAN) run compiled Pallas only — asking them for "ref" or "interpret"
-there is an error, and a lowering error surfaces as is.
+the store's read and sync kernels (image-row and log-replay scatters,
+fused GET/SCAN) run compiled Pallas only — asking them for "ref" or
+"interpret" there is an error, and a lowering error surfaces as is.
 """
 from __future__ import annotations
-
-import collections
-import functools
 
 import jax
 
@@ -41,69 +38,6 @@ def _store_backend(backend: str | None) -> str:
     return backend
 
 
-# ---------------------------------------------------------------- dispatch
-# counter: device launches per read batch, recorded at the NON-jitted shard
-# dispatch site (core/shard.py) so trace-caching can't hide repeats.  The
-# counts are the analytic launch model the latency benchmark pins (like
-# PR 6's dma/node 24 -> 1): the fused megakernels execute the whole
-# traversal in ONE pallas_call, where the reference path issues one
-# gather/merge stage per descend level plus one per scan-leaf visit (floor
-# pre-pass + forward pass) and GET adds its equality post-pass.
-READ_DISPATCHES: collections.Counter = collections.Counter()
-
-
-def read_dispatch_count(op: str, read_backend: str, cfg) -> int:
-    """Device dispatches one ``op`` ("get"/"scan") batch costs under
-    ``read_backend`` ("fused"/"reference") at this config's static
-    traversal bounds."""
-    if read_backend == "fused":
-        return 1
-    n = cfg.max_height + 2 * cfg.max_scan_leaves
-    return n + 1 if op == "get" else n
-
-
-def record_read_dispatch(op: str, read_backend: str, cfg, batches: int = 1):
-    """Meter ``batches`` read-batch dispatches (called per device call by
-    the shard layer)."""
-    READ_DISPATCHES[(op, read_backend)] += \
-        batches * read_dispatch_count(op, read_backend, cfg)
-    READ_DISPATCHES[("batches", op, read_backend)] += batches
-
-
-def reset_read_dispatches():
-    READ_DISPATCHES.clear()
-
-
-def read_dispatch_stats() -> dict:
-    """Per-(op, backend) dispatched-launch totals and per-batch averages."""
-    out = {}
-    for op in ("get", "scan"):
-        for rb in ("fused", "reference"):
-            b = READ_DISPATCHES.get(("batches", op, rb), 0)
-            d = READ_DISPATCHES.get((op, rb), 0)
-            if b:
-                out[f"{op}_{rb}"] = {"batches": b, "dispatches": d,
-                                     "per_batch": d / b}
-    return out
-
-
-def collect() -> list:
-    """Telemetry source for the launch meter (core/telemetry.py collect
-    protocol).  Returns plain ``(name, kind, value, labels)`` tuples —
-    kernels must not import repro.core (core imports kernels), so the
-    registry normalizes the dependency-free form."""
-    out = []
-    for op in ("get", "scan"):
-        for rb in ("fused", "reference"):
-            b = READ_DISPATCHES.get(("batches", op, rb), 0)
-            d = READ_DISPATCHES.get((op, rb), 0)
-            if b or d:
-                labels = {"layer": "kernel", "op": op, "backend": rb}
-                out.append(("read_dispatches", "counter", d, labels))
-                out.append(("read_batches", "counter", b, labels))
-    return out
-
-
 def key_search(q, qlen, keys, klens, valid, backend: str | None = None,
                **kw):
     backend = backend or default_backend()
@@ -115,10 +49,10 @@ def key_search(q, qlen, keys, klens, valid, backend: str | None = None,
 
 def key_search_image(q, qlen, node_img, *, keys_off, lens_off, count_off,
                      n_keys, key_words, backend: str | None = None, **kw):
-    """Floor search addressed INSIDE packed node images (cfg.layout=
-    "packed"): the candidate block is sliced from each request's image row
-    at static layout offsets (core/schema.py) instead of arriving as
-    separate key/length/valid operands."""
+    """Floor search addressed INSIDE packed node images: the candidate
+    block is sliced from each request's image row at static layout
+    offsets (core/schema.py) instead of arriving as separate
+    key/length/valid operands."""
     backend = backend or default_backend()
     if backend == "ref":
         return _ref.key_search_image_ref(
@@ -142,29 +76,15 @@ def leaf_merge(nitems, nlog, backptr, hints, *, node_cap, log_cap,
 
 
 def snapshot_delta_scatter(dst, rows, upd, backend: str | None = None, **kw):
-    """Apply one delta sync's dirty rows to a resident device array
-    (host->device snapshot patch).  ``dst``/``upd`` are [S, W]/[D, W] with
-    trailing dims flattened; see ``repro.core.read_path.apply_snapshot_delta``
-    for the whole-snapshot jnp path the store uses off-TPU."""
+    """Apply one delta sync to the packed snapshot image: one contiguous
+    [image_words] row DMA per dirty node (the paper's whole-node
+    transfer).  ``dst``/``upd`` are [S, IW]/[D, IW] u32; see
+    ``repro.core.read_path.apply_snapshot_delta`` for the store wiring and
+    the jnp scatter the store uses off-TPU."""
     backend = _store_backend(backend)
     if backend == "ref":
         return _ref.snapshot_delta_scatter_ref(dst, rows, upd)
     return _ds.snapshot_delta_scatter(dst, rows, upd,
-                                      interpret=(backend == "interpret"),
-                                      **kw)
-
-
-def snapshot_image_scatter(image, rows, upd, backend: str | None = None,
-                           **kw):
-    """Apply one delta sync to the PACKED snapshot image: one contiguous
-    [image_words] row DMA per dirty node (the paper's whole-node transfer,
-    cfg.layout="packed").  ``image``/``upd`` are [S, IW]/[D, IW] u32; see
-    ``repro.core.read_path.apply_snapshot_delta`` for the store wiring and
-    the jnp oracle kept as the parity reference."""
-    backend = _store_backend(backend)
-    if backend == "ref":
-        return _ref.snapshot_image_scatter_ref(image, rows, upd)
-    return _ds.snapshot_image_scatter(image, rows, upd,
                                       interpret=(backend == "interpret"),
                                       **kw)
 
@@ -184,22 +104,6 @@ def log_replay_scatter(image, rows, slots, entries, *, offs,
                                            offs=offs)
     return _ds.log_replay_scatter(image, rows, slots, entries, offs=offs,
                                   interpret=(backend == "interpret"), **kw)
-
-
-def snapshot_multi_scatter(dsts, rows, upd, backend: str | None = None,
-                           **kw):
-    """Apply one delta sync's dirty rows to EVERY per-node field of the
-    resident snapshot in a single fused kernel invocation (the paper's
-    whole-node DMA).  ``dsts``/``upd`` are matching sequences of
-    [S, W_f]/[D, W_f] arrays with trailing dims flattened; see
-    ``repro.core.read_path.apply_snapshot_delta`` for the store wiring and
-    the jnp oracle kept as the parity reference."""
-    backend = _store_backend(backend)
-    if backend == "ref":
-        return _ref.snapshot_multi_scatter_ref(dsts, rows, upd)
-    return _ds.snapshot_multi_scatter(dsts, rows, upd,
-                                      interpret=(backend == "interpret"),
-                                      **kw)
 
 
 def batched_get_fused(snap, key, klen, *, cfg, lb_fraction: float = 0.0,

@@ -54,24 +54,12 @@ def leaf_merge_ref(nitems, nlog, backptr, hints, *, node_cap: int,
 
 
 def snapshot_delta_scatter_ref(dst, rows, upd):
-    """Delta-sync row scatter oracle: dst[rows[i]] = upd[i].
+    """Delta-sync image-row scatter oracle: dst[rows[i]] = upd[i], one
+    whole node image per dirty row.
 
     Duplicate rows must carry identical data (the store pads deltas with
     repeats), so application order is immaterial."""
     return dst.at[rows].set(upd)
-
-
-def snapshot_image_scatter_ref(image, rows, upd):
-    """Packed node-image row scatter oracle: image[rows[i]] = upd[i] — one
-    whole node image per dirty row (same idempotent-duplicates contract)."""
-    return image.at[rows].set(upd)
-
-
-def snapshot_multi_scatter_ref(dsts, rows, upd):
-    """Fused multi-field scatter oracle: one row-scatter per field, same
-    contract as ``delta_scatter.snapshot_multi_scatter`` (the parity
-    reference for the one-invocation-per-sync fused kernel)."""
-    return tuple(d.at[rows].set(u) for d, u in zip(dsts, upd))
 
 
 def log_replay_scatter_ref(image, rows, slots, entries, *, offs):

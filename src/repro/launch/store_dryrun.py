@@ -93,8 +93,7 @@ def abstract_delta(cfg: HoneycombConfig, snap: TreeSnapshot, dirty_rows: int,
         image=sds((dirty_rows, snap.image.shape[1]), jnp.uint32),
         pt_lids=sds((pt_commands,), i32), pt_phys=sds((pt_commands,), i32),
         root_lid=sds((), i32), read_version=sds((), i32),
-        cache_lids=(None if snap.cache_lids is None
-                    else sds(snap.cache_lids.shape, i32)))
+        cache_lids=sds(snap.cache_lids.shape, i32))
 
 
 def delta_sync_analysis(cfg: HoneycombConfig, snap_abs: TreeSnapshot,
@@ -103,7 +102,8 @@ def delta_sync_analysis(cfg: HoneycombConfig, snap_abs: TreeSnapshot,
     """Compile the per-shard delta application and report the PCIe-analogue
     traffic: delta argument bytes vs the wholesale snapshot size."""
     delta_abs = abstract_delta(cfg, snap_abs, dirty_rows, pt_commands)
-    lowered = jax.jit(apply_snapshot_delta).lower(snap_abs, delta_abs)
+    lowered = jax.jit(apply_snapshot_delta, static_argnames="cfg").lower(
+        snap_abs, delta_abs, cfg=cfg)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     size = lambda tree: sum(
@@ -134,8 +134,8 @@ def pipeline_occupancy_model(cfg: HoneycombConfig, snap_abs: TreeSnapshot,
     occupancy is each stage's share of the bottleneck stage."""
     delta_abs = abstract_delta(cfg, snap_abs, dirty_rows, pt_commands)
     no_coll = {"total_bytes": 0}
-    c_exp = jax.jit(apply_snapshot_delta) \
-        .lower(snap_abs, delta_abs).compile()
+    c_exp = jax.jit(apply_snapshot_delta, static_argnames="cfg") \
+        .lower(snap_abs, delta_abs, cfg=cfg).compile()
     export_rl = hla.roofline(c_exp.cost_analysis(), no_coll, 0.0)
     sds = jax.ShapeDtypeStruct
     keys = sds((batch_per_shard, cfg.key_words), jnp.uint32)
@@ -215,29 +215,28 @@ def live_sharded_smoke(shards: int = 4, n_items: int = 1024,
         for op in (Update(int_key(int(rng.integers(0, n_items))), b"p" * 12),
                    Get(int_key(int(rng.integers(0, n_items))))))
     svc.drain()
-    # fused read-path invariants: the default backend served through the
-    # megakernels with the cache tier resolving levels from VMEM, and is
-    # result-identical to the reference path (a cache-less snapshot is the
-    # documented reference fallback — same shard, same dispatch machinery)
+    # fused read-path invariants: the megakernels served with the cache
+    # tier resolving levels from VMEM, and answer what the shard's host
+    # tree answers (the snapshot was synced just now, so both are at the
+    # same read version)
     span_per_shard = n_items // shards
     for i, sh in enumerate(st.shards):
         pk = [int_key(int(k)) for k in
               rng.integers(i * span_per_shard, (i + 1) * span_per_shard, 16)]
         snap = sh._snapshot_for_read()
-        assert sh._device_get(snap, pk) == \
-            sh._device_get(snap._replace(cache_image=None), pk), \
-            f"fused GET diverged from reference on shard {i}"
+        assert sh._device_get(snap, pk) == [sh.get(k) for k in pk], \
+            f"fused GET diverged from the host tree on shard {i}"
         pr = [(pk[0], pk[1])]
         assert sh._device_scan(snap, pr, None) == \
-            sh._device_scan(snap._replace(cache_image=None), pr, None), \
-            f"fused SCAN diverged from reference on shard {i}"
+            [sh.scan(lo, hi) for lo, hi in pr], \
+            f"fused SCAN diverged from the host tree on shard {i}"
     vmem_hits = sum(sh.cache.stats.vmem_hits for sh in st.shards)
     heap_gathers = sum(sh.cache.stats.heap_gathers for sh in st.shards)
     assert vmem_hits > 0, "cache tier never served a descend level"
     agg = st.sync_stats
     ps = st.pipeline_stats
     return {
-        "shards": shards, "items": n_items, "layout": cfg.layout,
+        "shards": shards, "items": n_items,
         "cross_shard_scan_items": len(span),
         "image_dma_count": agg.image_dma_count,
         "image_bytes": agg.image_bytes,
@@ -249,10 +248,9 @@ def live_sharded_smoke(shards: int = 4, n_items: int = 1024,
         "log_wire_bytes": agg.log_wire_bytes,
         "load_imbalance": st.load_imbalance,
         "read_path": {
-            "backend": cfg.read_backend,
             "vmem_hits": vmem_hits,
             "heap_gathers": heap_gathers,
-            "fused_matches_reference": True,     # asserted above
+            "fused_matches_host_tree": True,     # asserted above
         },
         "pipelined_epoch": {
             "per_shard_epochs": st.per_shard_epochs,
@@ -314,8 +312,8 @@ def live_replicated_smoke(shards: int = 2, replicas: int = 2,
                       for sh in st.shards for f in sh.followers)
     assert log_replays > 0, "no follower replayed a log payload on device"
     # followers inherit the cache tier through the feeds (delta applies
-    # re-attach it with cfg; log replays rebuild it from the replayed
-    # image) and their fused reads match the reference fallback
+    # re-attach it; log replays rebuild it from the replayed image) and
+    # their fused reads match the primary's host tree
     vmem_hits = 0
     for sh in st.shards:
         for f in sh.followers:
@@ -324,13 +322,12 @@ def live_replicated_smoke(shards: int = 2, replicas: int = 2,
                 "follower lost the cache tier over the feed"
             pk = [int_key(int(k)) for k in rng.integers(0, n_items, 8)]
             got = sh.primary._device_get(snap, pk)
-            ref = sh.primary._device_get(snap._replace(cache_image=None), pk)
-            assert got == ref, "follower fused GET diverged from reference"
+            assert got == [sh.primary.get(k) for k in pk], \
+                "follower fused GET diverged from the host tree"
         vmem_hits += sh.cache.stats.vmem_hits
     assert vmem_hits > 0, "cache tier never served a descend level"
     return {
         "shards": shards, "replicas": replicas, "items": n_items,
-        "layout": cfg.layout,
         "primary_image_dmas": st.sync_stats.image_dma_count,
         "served_replica_lanes": sorted({r.replica for r in reads}),
         "serving_versions": sorted({r.serving_version for r in reads}),
@@ -350,10 +347,9 @@ def live_replicated_smoke(shards: int = 2, replicas: int = 2,
         },
         "primary_sync_bytes": st.sync_stats.bytes_synced,
         "read_path": {
-            "backend": cfg.read_backend,
             "vmem_hits": vmem_hits,
             "followers_cache_resident": True,    # asserted above
-            "fused_matches_reference": True,     # asserted above
+            "fused_matches_host_tree": True,     # asserted above
         },
         "replica_lag_epochs": st.replica_lag_epochs,
         "replica_staleness": st.replica_staleness,

@@ -292,7 +292,12 @@ def test_kernel_registry_traces_clean():
     """Every real Pallas entry point traces and passes all kernel rules
     at the default geometry and VMEM budget."""
     entries = kernel_check.kernel_entries()
-    assert len(entries) >= 10
+    assert {e.name for e in entries} == {
+        "delta_scatter.snapshot_delta_scatter",
+        "delta_scatter.log_replay_scatter",
+        "fused_read.batched_get_fused", "fused_read.batched_scan_fused",
+        "key_search.key_search", "key_search.key_search_image",
+        "leaf_merge.leaf_merge", "paged_attention.paged_attention"}
     fs = kernel_check.run_kernel_checks()
     assert fs == [], "\n".join(map(str, fs))
 
@@ -471,4 +476,5 @@ def test_runner_writes_report(tmp_path):
     report = json.loads(out.read_text())
     assert report["ok"] and report["lint"] == [] \
         and report["kernel_check"] == []
-    assert report["entry_points"] >= 10 and report["baselined"] <= 2
+    assert report["entry_points"] == len(kernel_check.kernel_entries()) \
+        == 8 and report["baselined"] <= 2

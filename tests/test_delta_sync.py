@@ -235,17 +235,20 @@ def test_old_snapshots_survive_delta_syncs():
         assert vals[i].astype(">u4").tobytes()[:3] == b"old"
 
 
-def test_delta_scatter_kernel_matches_ref():
-    """Pallas interpret-mode scatter == jnp oracle (duplicate-row padding
-    included)."""
+@pytest.mark.parametrize("width,rows", [
+    (12, [3, 17, 40, 40]),        # narrow rows
+    (345, [2, 31, 63, 63]),       # one whole node image of the test geometry
+], ids=["narrow", "image"])
+def test_delta_scatter_kernel_matches_ref(width, rows):
+    """Pallas interpret-mode image-row scatter == jnp oracle, duplicate
+    (bucket-padded) rows included."""
     from repro.kernels import ops
     rng = np.random.default_rng(0)
-    dst = jnp.asarray(rng.integers(0, 2**31, (64, 12)).astype(np.uint32))
-    rows = np.array([3, 17, 40, 40], np.int32)    # padded repeat
-    upd = rng.integers(0, 2**31, (3, 12)).astype(np.uint32)
+    dst = jnp.asarray(rng.integers(0, 2**32, (64, width), np.int64)
+                      .astype(np.uint32))
+    rows = jnp.asarray(np.array(rows, np.int32))  # last: padded repeat
+    upd = rng.integers(0, 2**32, (3, width), np.int64).astype(np.uint32)
     upd = jnp.asarray(np.concatenate([upd, upd[-1:]]))
-    want = ops.snapshot_delta_scatter(dst, jnp.asarray(rows), upd,
-                                      backend="ref")
-    got = ops.snapshot_delta_scatter(dst, jnp.asarray(rows), upd,
-                                     backend="interpret")
+    want = ops.snapshot_delta_scatter(dst, rows, upd, backend="ref")
+    got = ops.snapshot_delta_scatter(dst, rows, upd, backend="interpret")
     assert bool(jnp.array_equal(want, got))

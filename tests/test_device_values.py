@@ -6,7 +6,7 @@ those slots as its value image (``TreeSnapshot.values``), each sync uploads
 only the slots allocated since the last one, and every read batch gathers
 its long values on the device (``gather_values``) into its one packed
 copy.  Checked here: answers through ``HoneycombService`` against the CPU
-baseline on both read backends, the counters, one copy per batch, a
+baseline in both pipeline modes, the counters, one copy per batch, a
 follower's own value image, slot reuse after the epoch window, the slot
 size limit, that a read never falls back to the live host heap, and that
 a store of inline values never builds a value image."""
@@ -37,10 +37,9 @@ def _value(rng, long_share: float = 0.8) -> bytes:
     return rng.integers(0, 256, n, dtype=np.uint8).tobytes()
 
 
-def _loaded(backend: str = "fused", **kw):
+def _loaded(**kw):
     rng = np.random.default_rng(15)
-    st = HoneycombStore(dataclasses.replace(CFG, read_backend=backend, **kw),
-                        heap_capacity=256)
+    st = HoneycombStore(dataclasses.replace(CFG, **kw), heap_capacity=256)
     ref = CpuOrderedStore(node_cap=16)
     for i in range(0, KEYS, 2):
         v = _value(rng)
@@ -72,15 +71,15 @@ def _long(v) -> int:
     return int(v is not None and len(v) > CFG.max_inline_val_bytes)
 
 
-@pytest.mark.parametrize("backend", ["fused", "reference"])
-def test_service_answers_match_cpu_baseline(backend):
+@pytest.mark.parametrize("pipeline", ["serial", "pipelined"])
+def test_service_answers_match_cpu_baseline(pipeline):
     """Mixed epochs through ``HoneycombService``: every answer equals the
     CPU baseline's (each epoch's writes in submission order, then its
     reads); every long value came from the device image
     (``device_values`` counting them), one copy per read batch."""
-    st, ref, rng = _loaded(backend)
+    st, ref, rng = _loaded()
     st.export_snapshot()
-    svc = HoneycombService(st, batch_size=8)
+    svc = HoneycombService(st, batch_size=8, pipeline=pipeline)
     ps = st.pipeline_stats
     served_long = 0
     for _ in range(6):

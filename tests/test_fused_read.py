@@ -1,8 +1,9 @@
 """Fused device-resident read path (kernels/fused_read.py + the VMEM
-cache tier): interpret-mode kernel parity, fused ≡ reference equivalence
-through the whole service stack (results AND serving-version stamps),
-cache-frontier edge cases, the stale-cache-after-remap regression, and
-the dispatched-launch meter pins."""
+cache tier): interpret-mode kernel parity, answers through the whole
+service stack equal to the CPU baseline store (with the sync invariants
+those runs must keep), cache-frontier edge cases, the
+stale-cache-after-remap regression, and the loud failure of a snapshot
+without its cache tier."""
 from __future__ import annotations
 
 import dataclasses
@@ -11,10 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Get, HoneycombConfig, HoneycombService,
-                        HoneycombStore, ReplicationConfig, Scan,
-                        ShardedHoneycombStore, Update,
-                        uniform_int_boundaries)
+from repro.baselines.cpu_store import CpuOrderedStore
+from repro.core import (Delete, Get, HoneycombConfig, HoneycombService, Put,
+                        ReplicationConfig, Scan, ShardedHoneycombStore,
+                        Update, uniform_int_boundaries)
+from repro.core.api import NOT_FOUND, OK
 from repro.core import shard as shard_mod
 from repro.core.keys import int_key, pack_keys
 from repro.core.read_path import GetResult
@@ -352,58 +354,90 @@ def test_fused_tpu_interpreter_matches_ref(op, tree):
         assert int(np.asarray(wm)[0]) > 0 and int(np.asarray(wm)[1]) > 0
 
 
-# -------------------------------------- fused ≡ reference, full service
+# ------------------------------------ fused answers ≡ CPU baseline, service
+def _mixed_ops(rng, n, key_space, value):
+    """A seeded put/update/delete/get/scan mix over ``key_space`` ids."""
+    ops = []
+    for _ in range(n):
+        k = int(rng.integers(0, key_space))
+        draw = rng.random()
+        if draw < 0.2:
+            ops.append(Put(int_key(k), value(k, "p")))
+        elif draw < 0.3:
+            ops.append(Update(int_key(k), value(k, "u")))
+        elif draw < 0.4:
+            ops.append(Delete(int_key(k)))
+        elif draw < 0.75:
+            ops.append(Get(int_key(k)))
+        else:
+            ops.append(Scan(int_key(k), int_key(min(k + 5, key_space - 1)),
+                            expected_items=6))
+    return ops
+
+
+@pytest.mark.parametrize("values", ["inline", "long"])
 @pytest.mark.parametrize("shards", [1, 3])
 @pytest.mark.parametrize("replicas", [1, 2])
 @pytest.mark.parametrize("pipeline", ["serial", "pipelined"])
-def test_fused_matches_reference_end_to_end(shards, replicas, pipeline):
-    """Randomized op stream through the typed service on two identically
-    loaded stores — fused vs reference backends must agree op-for-op on
-    results AND serving-version/replica-visible stamps."""
+def test_fused_matches_reference_end_to_end(shards, replicas, pipeline,
+                                            values):
+    """Randomized epochs through ``HoneycombService`` across shards x
+    replicas x pipeline modes, with values inline or longer than the 16
+    inline bytes: every response's status, value and items equal the CPU
+    baseline store's (each epoch's writes in submission order, then its
+    reads).  The syncs keep their invariants: one image DMA per dirty node
+    (a full publish is one whole-image DMA), the delta path taken, and
+    follower feed bytes moved when there are followers."""
     n_items = 200
-    rng = np.random.default_rng(shards * 10 + replicas + len(pipeline))
-    order = rng.permutation(n_items)
+    rng = np.random.default_rng(shards * 10 + replicas + len(pipeline)
+                                + len(values))
 
-    def build(rb):
-        st = ShardedHoneycombStore(
-            dataclasses.replace(SMALL, read_backend=rb),
-            heap_capacity=256, shards=shards,
-            boundaries=uniform_int_boundaries(n_items, shards),
-            replication=ReplicationConfig(replicas=replicas,
-                                          policy="round_robin"))
-        for i in order:
-            st.put(int_key(int(i)), b"w%06d" % int(i))
-        st.export_snapshot()
-        return st
+    def value(k, tag):
+        v = b"%s%06d" % (tag.encode(), k)
+        return v * 6 if values == "long" else v
 
-    opstream = []
-    for k in rng.integers(0, n_items, 60):
-        k = int(k)
-        draw = rng.random()
-        if draw < 0.15:
-            opstream.append(Update(int_key(k), b"z%06d" % k))
-        elif draw < 0.6:
-            opstream.append(Get(int_key(k)))
-        else:
-            opstream.append(Scan(int_key(k),
-                                 int_key(min(k + 5, n_items - 1)),
-                                 expected_items=6))
-    stamps = {}
-    for rb in ("fused", "reference"):
-        svc = HoneycombService(build(rb), batch_size=16, pipeline=pipeline)
-        tickets = svc.submit_many(opstream)
+    st = ShardedHoneycombStore(
+        SMALL, heap_capacity=256, shards=shards,
+        boundaries=uniform_int_boundaries(n_items, shards),
+        replication=ReplicationConfig(replicas=replicas,
+                                      policy="round_robin"))
+    ref = CpuOrderedStore(node_cap=16)
+    for i in rng.permutation(n_items):
+        st.put(int_key(int(i)), value(int(i), "w"))
+        ref.put(int_key(int(i)), value(int(i), "w"))
+    st.export_snapshot()
+    svc = HoneycombService(st, batch_size=16, pipeline=pipeline)
+    for round_ in range(3):
+        ops = _mixed_ops(rng, 60, n_items, value)
+        tickets = svc.submit_many(ops)
         svc.drain()
-        rs = [t.result() for t in tickets]
-        stamps[rb] = [(r.status, r.value, r.items, r.serving_version)
-                      for r in rs]
-    assert stamps["fused"] == stamps["reference"]
+        for op in ops:
+            if isinstance(op, Delete):
+                ref.delete(op.key)
+            elif op.IS_WRITE:
+                ref.put(op.key, op.value)
+        for op, t in zip(ops, tickets):
+            r = t.result()
+            if isinstance(op, Get):
+                v = ref.get(op.key)
+                want = (OK if v is not None else NOT_FOUND, v, None)
+            elif isinstance(op, Scan):
+                want = (OK, None, ref.scan(op.lo, op.hi))
+            else:
+                want = (OK, None, None)
+            assert (r.status, r.value, r.items) == want, (round_, op)
+    s = st.sync_stats
+    assert s.delta_syncs > 0                      # delta path exercised
+    assert s.image_dma_count == s.full_syncs + s.delta_rows
+    if replicas > 1:
+        assert st.replication_bytes > 0
 
 
 @pytest.mark.parametrize("feed", ["log", "delta"])
 def test_followers_serve_fused_from_shipped_cache(feed):
     """Followers inherit the cache tier through BOTH feeds (delta applies
     re-attach it; log replays rebuild it from the replayed image) and
-    their fused reads match the cache-less reference fallback."""
+    their fused reads answer what the primary's host tree does."""
     n_items = 160
     st = ShardedHoneycombStore(
         SMALL, heap_capacity=256, shards=1,
@@ -434,9 +468,7 @@ def test_followers_serve_fused_from_shipped_cache(feed):
         v0 = grp.primary.cache.stats.vmem_hits
         got = grp.primary._device_get(f.snapshot, probe)
         assert grp.primary.cache.stats.vmem_hits > v0
-        ref = grp.primary._device_get(
-            f.snapshot._replace(cache_image=None), probe)
-        assert got == ref
+        assert got == [grp.primary.get(k) for k in probe]
 
 
 # --------------------------------------------- cache-frontier edge cases
@@ -523,46 +555,17 @@ def test_reads_stay_correct_across_structural_churn():
         assert got == [live[k] for k in probe]
 
 
-# ------------------------------------------------ dispatch-launch meter
-def test_read_dispatch_counts():
-    cfg = SMALL
-    assert ops.read_dispatch_count("get", "fused", cfg) == 1
-    assert ops.read_dispatch_count("scan", "fused", cfg) == 1
-    ref_scan = cfg.max_height + 2 * cfg.max_scan_leaves
-    assert ops.read_dispatch_count("scan", "reference", cfg) == ref_scan
-    assert ops.read_dispatch_count("get", "reference", cfg) == ref_scan + 1
-
-
-def test_shard_meters_fused_dispatches():
-    """Acceptance: the fused path issues <= 2 device dispatches per read
-    batch, measured by the launch meter at the shard dispatch site."""
-    ops.reset_read_dispatches()
+# ------------------------------------------- a snapshot without its tier
+@pytest.mark.parametrize("op", ["get", "scan"])
+def test_snapshot_without_cache_tier_fails_loudly(op):
+    """Every staging attaches the cache tier; a snapshot that lacks it is a
+    bug, and the read dispatch raises rather than serve it some other
+    way."""
     s = _loaded_shard(SMALL)
-    s.get_batch([int_key(1), int_key(2)])
-    s.scan_batch([(int_key(1), int_key(9))])
-    st = ops.read_dispatch_stats()
-    assert st["get_fused"]["per_batch"] <= 2
-    assert st["scan_fused"]["per_batch"] <= 2
-    # the reference path pays per-stage launches
-    ops.reset_read_dispatches()
-    r = StoreShard(dataclasses.replace(SMALL, read_backend="reference"),
-                   heap_capacity=256)
-    for i in range(40):
-        r.put(int_key(i), b"v" * 8)
-    r.get_batch([int_key(1)])
-    st = ops.read_dispatch_stats()
-    assert st["get_reference"]["per_batch"] > 2
-    ops.reset_read_dispatches()
-
-
-def test_legacy_layout_falls_back_to_reference():
-    """cfg.layout="legacy" snapshots carry no cache tier: the shard must
-    dispatch reads through the reference path (and still answer right)."""
-    cfg = dataclasses.replace(SMALL, layout="legacy")
-    ops.reset_read_dispatches()
-    s = _loaded_shard(cfg)
-    out = s.get_batch([int_key(1), int_key(118)])
-    assert out == [s.get(int_key(1)), s.get(int_key(118))]
-    st = ops.read_dispatch_stats()
-    assert "get_reference" in st and "get_fused" not in st
-    ops.reset_read_dispatches()
+    snap = s.export_snapshot()._replace(cache_image=None)
+    keys = [int_key(i) for i in range(1, 9)]
+    with pytest.raises(RuntimeError, match="without its cache tier"):
+        if op == "get":
+            s._device_get(snap, keys)
+        else:
+            s._device_scan(snap, [(keys[0], keys[3])], None)

@@ -1,28 +1,19 @@
 """Packed node-image layout (core/schema.py): golden word offsets pinning
 the image format, pack/unpack/device-view roundtrips, the schema-derived
 field lists (no re-enumeration anywhere), the one-image-DMA-per-dirty-node
-accounting invariant, randomized packed==legacy op-for-op equivalence
-(results AND sync byte counts) across shards x replicas x pipeline modes,
-and image-scatter / in-image key-search kernel parity."""
-import dataclasses
-
+accounting invariant, the direct store against the CPU baseline, and
+in-image key-search kernel parity."""
 import numpy as np
-import pytest
 
 import jax.numpy as jnp
 
+from repro.baselines.cpu_store import CpuOrderedStore
 from repro.core import (FIELD_NAMES, NARROWED_FIELDS, NODE_SCHEMA,
                         DEFAULT_CONFIG, HoneycombConfig, HoneycombStore,
-                        NodeImageLayout, OutOfOrderScheduler,
-                        ReplicationConfig, ShardedHoneycombStore,
-                        uniform_int_boundaries)
+                        NodeImageLayout)
 from repro.core.keys import int_key
 
 SMALL = HoneycombConfig(node_cap=16, log_cap=4, n_shortcuts=4)
-
-
-def small(layout):
-    return dataclasses.replace(SMALL, layout=layout)
 
 
 # ------------------------------------------------------------ golden layout
@@ -63,14 +54,12 @@ def test_golden_offsets_pinned():
 
 
 def test_field_lists_derive_from_schema():
-    """Heap allocation, snapshot publishing and the device-narrowing table
-    all share the ONE schema — no hand-kept field list survives."""
+    """Heap allocation, the snapshot's field view and the device-narrowing
+    table all share the ONE schema — no hand-kept field list survives."""
     from repro.core.heap import NodeHeap
-    from repro.core.read_path import NODE_FIELDS
-    from repro.core.shard import _I32_FIELDS
+    from repro.core.read_path import SnapshotFields
     assert NodeHeap.ARRAY_FIELDS == FIELD_NAMES
-    assert NODE_FIELDS == FIELD_NAMES
-    assert _I32_FIELDS is NARROWED_FIELDS
+    assert SnapshotFields.__slots__[:len(FIELD_NAMES)] == FIELD_NAMES
     assert NARROWED_FIELDS == {"version", "log_op", "log_hint", "log_vdelta"}
     assert all(f.device in ("uint32", "int32") for f in NODE_SCHEMA)
 
@@ -79,7 +68,7 @@ def test_pack_unpack_view_roundtrip():
     """pack() -> unpack() is the identity (in device dtypes) on a live
     heap, and the device view() decodes every field identically —
     including NULL = -1 surviving the u32 transit of signed fields."""
-    st = HoneycombStore(small("packed"), heap_capacity=256)
+    st = HoneycombStore(SMALL, heap_capacity=256)
     rng = np.random.default_rng(3)
     for i in range(120):
         st.put(int_key(i), bytes(rng.integers(65, 91, 8)))
@@ -107,7 +96,7 @@ def test_delta_sync_is_one_image_dma_per_dirty_node():
     """THE acceptance invariant: on the packed layout a delta sync issues
     exactly ONE contiguous image-row DMA per dirty node (a full publish is
     one whole-image DMA), metered end to end by SyncStats."""
-    st = HoneycombStore(small("packed"), heap_capacity=256)
+    st = HoneycombStore(SMALL, heap_capacity=256)
     layout = NodeImageLayout.for_config(st.cfg)
     for i in range(100):
         st.put(int_key(i), b"v")
@@ -125,102 +114,36 @@ def test_delta_sync_is_one_image_dma_per_dirty_node():
         dirty = (st.sync_stats.image_bytes - b0) // layout.node_image_bytes
         assert st.sync_stats.delta_syncs == rnd + 1
         assert dmas == dirty > 0, (dmas, dirty)   # one DMA per dirty node
-    # legacy on the same traffic: one DMA per FIELD per node
-    lg = HoneycombStore(small("legacy"), heap_capacity=256)
-    for i in range(100):
-        lg.put(int_key(i), b"v")
-    lg.export_snapshot()
-    assert lg.sync_stats.image_dma_count == len(FIELD_NAMES)
-    assert lg.sync_stats.image_bytes == st.sync_stats.image_bytes - \
-        (st.sync_stats.image_dma_count - 1) * layout.node_image_bytes
 
 
-# ------------------------------------------------- packed == legacy, op for op
-@pytest.mark.parametrize("shards", [1, 3])
-@pytest.mark.parametrize("replicas", [1, 2])
-@pytest.mark.parametrize("pipeline", ["serial", "pipelined"])
-def test_packed_equals_legacy_randomized(shards, replicas, pipeline):
-    """Randomized mixed workloads: the packed layout returns the same
-    responses AND the same sync accounting as the legacy per-field layout
-    across shards x replicas x pipeline modes.  Every SyncStats counter
-    matches except image_dma_count — the counter the refactor collapses
-    (one per dirty node instead of one per field per node)."""
-    bnd = uniform_int_boundaries(200, shards) if shards > 1 else None
-    repl = ReplicationConfig(replicas=replicas,
-                             policy="round_robin" if replicas > 1
-                             else "primary_only")
-    stores, scheds = [], []
-    for layout in ("packed", "legacy"):
-        s = ShardedHoneycombStore(small(layout), heap_capacity=256,
-                                  shards=shards, boundaries=bnd,
-                                  replication=repl)
-        stores.append(s)
-        scheds.append(OutOfOrderScheduler(batch_size=8, routing=s.routing(),
-                                          pipeline=pipeline))
-    pk, lg = stores
-    rng = np.random.default_rng(42)
-    from test_pipeline_engine import submit_random_mixed
-    for round_ in range(3):
-        submit_random_mixed(scheds, rng, 60)
-        out_p = scheds[0].run(pk)
-        out_l = scheds[1].run(lg)
-        assert out_p == out_l, round_
-        sp = dataclasses.asdict(pk.sync_stats)
-        sl = dataclasses.asdict(lg.sync_stats)
-        # the DMA count is the one deliberate difference
-        assert sp.pop("image_dma_count") < sl.pop("image_dma_count")
-        assert sp == sl, round_
-        assert pk.replication_bytes == lg.replication_bytes, round_
-    assert pk.sync_stats.delta_syncs > 0          # delta path exercised
-    assert pk.sync_stats.image_bytes == lg.sync_stats.image_bytes > 0
-    if replicas > 1:
-        assert pk.replication_bytes > 0
-
-
-def test_direct_store_packed_equals_legacy():
-    """No scheduler in the way: direct put/get/scan/delete + export on both
-    layouts, same results, same bytes_synced."""
-    pk = HoneycombStore(small("packed"), heap_capacity=256)
-    lg = HoneycombStore(small("legacy"), heap_capacity=256)
-    oracle = {}
+# ----------------------------------------------- direct store vs baseline
+def test_direct_store_matches_cpu_baseline():
+    """No scheduler in the way: direct put/get/scan/delete + export answer
+    what the CPU baseline store answers, and every sync issues one image
+    DMA per dirty node."""
+    st = HoneycombStore(SMALL, heap_capacity=256)
+    ref = CpuOrderedStore(node_cap=16)
     rng = np.random.default_rng(11)
     for round_ in range(4):
         for _ in range(50):
             k = int_key(int(rng.integers(0, 150)))
-            r = rng.random()
-            if r < 0.6:
+            if rng.random() < 0.6:
                 v = bytes(rng.integers(65, 91, 8))
-                pk.put(k, v), lg.put(k, v)
-                oracle[k] = v
+                st.put(k, v), ref.put(k, v)
             else:
-                pk.delete(k), lg.delete(k)
-                oracle.pop(k, None)
+                st.delete(k), ref.delete(k)
         keys = [int_key(i) for i in range(0, 150, 7)]
-        assert pk.get_batch(keys) == lg.get_batch(keys) \
-            == [oracle.get(k) for k in keys]
+        assert st.get_batch(keys) == [ref.get(k) for k in keys], round_
         ranges = [(int_key(a), int_key(a + 9)) for a in range(0, 140, 23)]
-        assert pk.scan_batch(ranges) == lg.scan_batch(ranges)
-        pk.export_snapshot()
-        lg.export_snapshot()
-        assert pk.sync_stats.bytes_synced == lg.sync_stats.bytes_synced
+        assert st.scan_batch(ranges) == \
+            [ref.scan(lo, hi) for lo, hi in ranges], round_
+        st.export_snapshot()
+    s = st.sync_stats
+    assert s.delta_syncs > 0
+    assert s.image_dma_count == s.full_syncs + s.delta_rows
 
 
 # ------------------------------------------------------------ kernel parity
-def test_image_scatter_kernel_matches_ref():
-    """snapshot_image_scatter interpret-mode == jnp oracle, duplicate
-    (bucket-padded) rows included."""
-    from repro.kernels import ops
-    rng = np.random.default_rng(0)
-    image = jnp.asarray(rng.integers(0, 2**32, (64, 345), np.int64)
-                        .astype(np.uint32))
-    rows = jnp.asarray(np.array([2, 31, 63, 63], np.int32))  # padded repeat
-    upd = rng.integers(0, 2**32, (3, 345), np.int64).astype(np.uint32)
-    upd = jnp.asarray(np.concatenate([upd, upd[-1:]]))
-    want = ops.snapshot_image_scatter(image, rows, upd, backend="ref")
-    got = ops.snapshot_image_scatter(image, rows, upd, backend="interpret")
-    assert bool(jnp.array_equal(want, got))
-
-
 def test_key_search_image_kernel_matches_ref():
     """In-image floor search (candidate block sliced from packed image rows
     at static layout offsets) interpret-mode == jnp oracle."""

@@ -1,8 +1,9 @@
 """Epoch-pipelined execution engine (core/pipeline.py): double-buffered
 snapshot staging/flip (incl. survival under GC churn), pipelined-vs-serial
 scheduler equivalence (results AND sync byte counts), the serial mode's
-op-for-op match with the legacy inline sequence, the fused multi-field
-delta scatter, and the shared power-of-two bucket schedule."""
+op-for-op match with the legacy inline sequence, the image-row delta
+scatter applied to a whole snapshot, and the shared power-of-two bucket
+schedule."""
 import numpy as np
 import pytest
 
@@ -234,11 +235,12 @@ def test_pipeline_stage_meters():
         OutOfOrderScheduler(pipeline="warp")
 
 
-# ------------------------------------------------------ fused delta scatter
-def test_fused_multi_field_scatter_matches_oracle():
-    """apply_snapshot_delta(backend="interpret") on the packed image layout
-    — ONE contiguous image-row scatter per dirty node — is bit-identical to
-    the jnp oracle on a materialized snapshot/delta pair."""
+# ------------------------------------------------------ image-row scatter
+def test_packed_image_scatter_matches_oracle():
+    """apply_snapshot_delta(backend="interpret") — ONE contiguous image-row
+    scatter per dirty node, then the cache tier re-attached — is
+    bit-identical to the jnp oracle on a materialized snapshot/delta
+    pair."""
     from repro.launch.store_dryrun import abstract_delta, abstract_snapshot
     cfg = SMALL
     snap_abs, S = abstract_snapshot(cfg, n_items=64, shards=1)
@@ -250,32 +252,15 @@ def test_fused_multi_field_scatter_matches_oracle():
         rows=jnp.asarray(np.array([1, 4, S - 1], np.int32)),
         pt_lids=jnp.asarray(np.array([0, 2], np.int32)),
         pt_phys=jnp.asarray(np.array([5, 6], np.int32)))
-    want = apply_snapshot_delta(snap, delta)
-    got = apply_snapshot_delta(snap, delta, backend="interpret")
+    want = apply_snapshot_delta(snap, delta, cfg=cfg)
+    got = apply_snapshot_delta(snap, delta, cfg=cfg, backend="interpret")
+    assert got.cache_image is not None
     for f in want._fields:
         w, g = getattr(want, f), getattr(got, f)
-        if w is None or g is None:     # unattached cache tier (no cfg given)
-            assert w is None and g is None, f
+        if f == "values":              # no value image in this snapshot
+            assert w is None and g is None
         else:
             assert bool(jnp.array_equal(w, g)), f
-
-
-def test_multi_scatter_kernel_duplicate_rows():
-    """The raw fused kernel handles bucket-padded duplicate rows (identical
-    data) across fields with distinct widths/dtypes."""
-    from repro.kernels import ops
-    rng = np.random.default_rng(1)
-    dsts = [jnp.asarray(rng.integers(0, 2**31, (32, 12)).astype(np.uint32)),
-            jnp.asarray(rng.integers(0, 99, (32, 1)).astype(np.int32))]
-    rows = jnp.asarray(np.array([3, 9, 9], np.int32))     # padded repeat
-    u0 = rng.integers(0, 2**31, (2, 12)).astype(np.uint32)
-    u1 = rng.integers(0, 99, (2, 1)).astype(np.int32)
-    upd = [jnp.asarray(np.concatenate([u0, u0[-1:]])),
-           jnp.asarray(np.concatenate([u1, u1[-1:]]))]
-    want = ops.snapshot_multi_scatter(dsts, rows, upd, backend="ref")
-    got = ops.snapshot_multi_scatter(dsts, rows, upd, backend="interpret")
-    for w, g in zip(want, got):
-        assert bool(jnp.array_equal(w, g))
 
 
 # ------------------------------------------------------- bucket schedule

@@ -1,11 +1,10 @@
 """The read batch's one device->host copy: each GET/SCAN batch comes back
 as one packed u32 buffer (``_pack_read``), split on the host into
 zero-copy views (``_unpack_read``).  Answers, fallbacks, overflow values,
-cache meters and the copy counters are checked through the shard, on the
-fused and the reference path, for a full and a padded batch."""
+cache meters and the copy counters are checked through the shard, for a
+store with and without a value image, for a full and a padded batch; and
+the device programs keep the module names the benchmark's metrics read."""
 from __future__ import annotations
-
-import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +14,7 @@ from repro.core import HoneycombConfig
 from repro.core import shard as shard_mod
 from repro.core.config import bucket_pow2
 from repro.core.keys import int_key, pack_keys
-from repro.core.read_path import (GetResult, ScanResult, batched_get,
-                                  batched_scan)
+from repro.core.read_path import GetResult, ScanResult
 from repro.core.shard import StoreShard
 from repro.kernels import ops as kernel_ops
 
@@ -26,17 +24,17 @@ SMALL = HoneycombConfig(node_cap=16, log_cap=4, n_shortcuts=4,
 N = 120
 
 
-def _value(i: int) -> bytes:
-    """Every fifth value is longer than the 16 inline bytes: the batch
-    gathers it from the snapshot's value image into its one copy."""
-    return b"L%06d" % i * 4 if i % 5 == 0 else b"v%06d" % i
+def _value(i: int, long: bool = True) -> bytes:
+    """With ``long``, every fifth value is longer than the 16 inline bytes:
+    the batch gathers it from the snapshot's value image into its one
+    copy.  Otherwise every value is inline and there is no value image."""
+    return b"L%06d" % i * 4 if long and i % 5 == 0 else b"v%06d" % i
 
 
-def _shard(backend: str) -> StoreShard:
-    s = StoreShard(dataclasses.replace(SMALL, read_backend=backend),
-                   heap_capacity=256)
+def _shard(values: str = "present") -> StoreShard:
+    s = StoreShard(SMALL, heap_capacity=256)
     for i in range(N):
-        s.put(int_key(i), _value(i))
+        s.put(int_key(i), _value(i, values == "present"))
     for i in range(0, N, 13):
         s.delete(int_key(i))
     return s
@@ -68,24 +66,22 @@ def _args(op: str, reqs, cfg):
                                      lanes[n:], lens[n:])]
 
 
-def _direct(op: str, backend: str, snap, args, cfg):
+def _direct(op: str, snap, args, cfg):
     """The same batch through the kernel entry point itself, unpacked:
     (result, meters)."""
-    if backend == "fused":
-        fn = (kernel_ops.batched_get_fused if op == "get"
-              else kernel_ops.batched_scan_fused)
-        return fn(snap, *args, cfg=cfg, lb_fraction=cfg.lb_fraction)
-    fn = batched_get if op == "get" else batched_scan
-    return fn(snap, *args, cfg=cfg), np.zeros(3, np.int32)
+    fn = (kernel_ops.batched_get_fused if op == "get"
+          else kernel_ops.batched_scan_fused)
+    return fn(snap, *args, cfg=cfg, lb_fraction=cfg.lb_fraction)
 
 
 @pytest.mark.parametrize("lanes", [8, 5], ids=["full", "ragged"])
-@pytest.mark.parametrize("backend", ["fused", "reference"])
+@pytest.mark.parametrize("values", ["none", "present"])
 @pytest.mark.parametrize("op", ["get", "scan"])
-def test_packed_read_batch(op, backend, lanes):
-    s = _shard(backend)
+def test_packed_read_batch(op, values, lanes):
+    s = _shard(values)
     snap = s.export_snapshot()
-    assert s._read_backend_for(snap) == backend
+    long = values == "present"
+    assert (snap.values is not None) == long      # the value image
     reqs = _requests(op, lanes)
     ps, cs = s.pipeline_stats, s.cache_stats
     before = (ps.read_batches, ps.read_copies, ps.host_scans,
@@ -93,27 +89,27 @@ def test_packed_read_batch(op, backend, lanes):
     if op == "get":
         got = s.get_batch(reqs)
         assert got == [s.get(k) for k in reqs]
-        assert got[0] == _value(5) and len(got[0]) > 16   # value image
+        assert got[0] == _value(5, long)
+        assert (len(got[0]) > 16) == long                 # value image
     else:
         got = s.scan_batch(reqs)
         assert got == [s.tree.scan(lo, hi) for lo, hi in reqs]
         assert len(got[1]) > SMALL.max_scan_items         # host fallback
-        assert (int_key(5), _value(5)) in got[0]          # value image
+        assert (int_key(5), _value(5, long)) in got[0]
+    assert (s.pipeline_stats.device_values > 0) == long
     args = _args(op, reqs, s.cfg)
-    res, meters = _direct(op, backend, snap, args, s.cfg)
+    res, meters = _direct(op, snap, args, s.cfg)
     after = (ps.read_batches, ps.read_copies, ps.host_scans,
              cs.vmem_hits, cs.heap_gathers, cs.lb_routed)
     step = [a - b for a, b in zip(after, before)]
     assert step[:3] == [1, 1, 1 if op == "scan" else 0]
     assert step[3:] == np.asarray(meters).tolist()
     # the shard's entry point answers bit for bit what the kernel does
-    jit_fn = {("get", "fused"): shard_mod._jit_get_fused,
-              ("scan", "fused"): shard_mod._jit_scan_fused,
-              ("get", "reference"): shard_mod._jit_get,
-              ("scan", "reference"): shard_mod._jit_scan}[op, backend]
+    jit_fn = (shard_mod._jit_get_fused if op == "get"
+              else shard_mod._jit_scan_fused)
     rtype = GetResult if op == "get" else ScanResult
-    kw = {"lb_fraction": s.cfg.lb_fraction} if backend == "fused" else {}
-    buf = np.asarray(jit_fn(snap, *args, cfg=s.cfg, **kw))
+    buf = np.asarray(jit_fn(snap, *args, cfg=s.cfg,
+                            lb_fraction=s.cfg.lb_fraction))
     views, got_meters = shard_mod._unpack_read(buf, rtype, s.cfg)
     assert np.shares_memory(views[1], buf)                # views, no copy
     for name, v, d in zip(rtype._fields, views, res):
@@ -126,15 +122,29 @@ def test_packed_read_batch(op, backend, lanes):
 @pytest.mark.parametrize("jit_name,module", [
     ("_jit_get_fused", "jit_batched_get_fused"),
     ("_jit_scan_fused", "jit_batched_scan_fused"),
-    ("_jit_get", "jit_batched_get"),
-    ("_jit_scan", "jit_batched_scan")])
+    ("_jit_gather_values", "jit_gather_values"),
+    ("_jit_apply_delta", "jit_apply_snapshot_delta")])
 def test_read_programs_keep_their_names(jit_name, module):
-    """bench/metrics/read_kernel_us_per_req.py finds the read kernel's
-    device time by these module names (``READ_MODULES``)."""
-    s = _shard("fused")
+    """The benchmark's device metrics find each program's time by these
+    module names: the read kernels (``read_kernel_us_per_req``), the value
+    gather (``value_gather_us_per_req``) and the sync
+    (``sync_kernel_ms_per_epoch``)."""
+    s = _shard()
     snap = s.export_snapshot()
-    op = "get" if "get" in jit_name else "scan"
-    kw = {"lb_fraction": 0.0} if "fused" in jit_name else {}
-    lowered = getattr(shard_mod, jit_name).lower(
-        snap, *_args(op, _requests(op, 8), s.cfg), cfg=s.cfg, **kw)
+    fn = getattr(shard_mod, jit_name)
+    if jit_name == "_jit_apply_delta":
+        s.update(int_key(3), b"u")
+        s.begin_export()
+        lowered = fn.lower(snap._replace(values=None), s.last_staged.delta,
+                           backend=None, cfg=s.cfg)
+    elif jit_name == "_jit_gather_values":
+        buf = shard_mod._jit_get_fused(
+            snap, *_args("get", _requests("get", 8), s.cfg), cfg=s.cfg,
+            lb_fraction=0.0)
+        lowered = fn.lower(buf, snap.values, result_type=GetResult,
+                           cfg=s.cfg)
+    else:
+        op = "get" if "get" in jit_name else "scan"
+        lowered = fn.lower(snap, *_args(op, _requests(op, 8), s.cfg),
+                           cfg=s.cfg, lb_fraction=0.0)
     assert f"module @{module} " in lowered.as_text()

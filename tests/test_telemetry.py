@@ -218,14 +218,15 @@ def test_merge_stats_matches_manual_field_sums():
 
 
 def test_six_surfaces_in_one_snapshot(replicated_service):
-    _, svc, _, _, _ = replicated_service
+    st, svc, _, _, _ = replicated_service
     snap = svc.metrics_snapshot()
     prefixes = {k.split("{")[0].split("_")[0] for k in snap}
     for want in ("sync", "tree", "pipeline", "cache", "replication",
-                 "read", "scheduler"):
+                 "scheduler"):
         assert want in prefixes, (want, sorted(prefixes))
-    # the kernel meter rode in as plain tuples with op/backend labels
-    assert any(k.startswith("read_batches{") for k in snap), sorted(snap)[:8]
+    # device read batches are counted once, by the store's PipelineStats
+    assert svc.telemetry.value("pipeline_read_batches", src="store") == \
+        st.pipeline_stats.read_batches > 0
 
 
 # ---------------------------------------------------------------- exporters

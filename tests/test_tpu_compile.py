@@ -144,7 +144,7 @@ def test_fused_scan_lowering_pinned(shapes, lanes):
 
 def test_image_scatter_compiles(shapes):
     layout, sds, _, _ = shapes
-    _compile(delta_scatter.snapshot_image_scatter,
+    _compile(delta_scatter.snapshot_delta_scatter,
              sds((HEAP_ROWS, layout.image_words)), sds((DIRTY,), jnp.int32),
              sds((DIRTY, layout.image_words)))
 
